@@ -111,6 +111,18 @@ class FleetBuildReport(Dict[str, str]):
         self.failed: Dict[str, str] = {}
         self.group_retries: int = 0
         self.gang_width: int = 1  # small-group scheduler width used
+        # one row per trained fleet bucket: what the trainer resolved and
+        # where it ran (model family, member count, sequence layout,
+        # device block) — provenance a parent process that never touches
+        # JAX can read back from the manifest
+        self.buckets: List[Dict[str, Any]] = []
+
+    @property
+    def device(self) -> Optional[Dict[str, Any]]:
+        """``{"platform", "kind", "count"}`` the fleet buckets trained on
+        (the widest bucket's block; None when nothing fleet-trained)."""
+        blocks = [b["device"] for b in self.buckets if b.get("device")]
+        return max(blocks, key=lambda d: d["count"]) if blocks else None
 
     def manifest(self) -> Dict[str, Any]:
         return {
@@ -121,6 +133,8 @@ class FleetBuildReport(Dict[str, str]):
             "n_failed": len(self.failed),
             "group_retries": self.group_retries,
             "gang_width": self.gang_width,
+            "buckets": list(self.buckets),
+            "device": self.device,
         }
 
 
@@ -726,7 +740,7 @@ def _build_fleet_group(
     output_dir: str,
     model_register_dir: Optional[str],
     replace_cache: bool,
-    results: Dict[str, str],
+    results: FleetBuildReport,
     checkpoint_dir: Optional[str] = None,
     checkpoint_every: int = 1,
     mesh=None,
@@ -847,6 +861,17 @@ def _build_fleet_group(
         )
     train_elapsed = time.time() - t1
     trainer.last_stats["device_memory"] = device_memory_stats()
+    results.buckets.extend(
+        {
+            "model_type": trainer.model_type,
+            "kind": trainer.kind,
+            "n_features": b["n_features"],
+            "n_members": b["n_members"],
+            "layout": b["layout"],
+            "device": b["device"],
+        }
+        for b in trainer.last_stats["buckets"]
+    )
     if fold_data:
         trainer.last_stats["cv_fold_members"] = len(fold_data)
 

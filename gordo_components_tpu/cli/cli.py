@@ -31,9 +31,7 @@ EXIT_PARTIAL_BUILD = 84
 @click.option("--log-level", default="INFO", envvar="LOG_LEVEL")
 @click.option("--platform", default=None, envvar="JAX_PLATFORMS",
               help="Pin the JAX backend (e.g. 'cpu', 'tpu'). Applied "
-                   "in-process BEFORE any device use: an env var alone "
-                   "cannot override a site-installed platform pin, and a "
-                   "wedged accelerator plugin hangs rather than errors")
+                   "in-process BEFORE any device use")
 @click.option("--profile-dir", default=None, envvar="GORDO_PROFILE_DIR",
               help="Write jax.profiler traces of train/build hot sections "
                    "here (TensorBoard/Perfetto-viewable)")
@@ -41,8 +39,9 @@ EXIT_PARTIAL_BUILD = 84
               envvar="GORDO_COMPILE_CACHE_DIR",
               help="Persistent XLA compilation cache (a shared volume in "
                    "pods): restarted/preempted builders and rolling server "
-                   "deploys reuse compiled programs instead of paying the "
-                   "~tens-of-seconds-per-shape XLA compile again")
+                   "deploys reuse compiled programs instead of compiling "
+                   "again. Ignored when JAX_COMPILATION_CACHE_DIR is set "
+                   "(JAX then uses that); default <checkout>/.jax_cache")
 def gordo(log_level, platform, profile_dir, compile_cache_dir):
     """TPU-native gordo: build, serve, and orchestrate fleets of
     time-series anomaly-detection models."""
@@ -54,10 +53,9 @@ def gordo(log_level, platform, profile_dir, compile_cache_dir):
         import jax
 
         jax.config.update("jax_platforms", platform)
-    if compile_cache_dir:
-        from gordo_components_tpu.utils import enable_compile_cache
+    from gordo_components_tpu.utils import resolve_compile_cache
 
-        enable_compile_cache(compile_cache_dir)
+    resolve_compile_cache(compile_cache_dir)
     if profile_dir:
         os.environ["GORDO_PROFILE_DIR"] = profile_dir
     if os.environ.get("GORDO_FAULTS"):

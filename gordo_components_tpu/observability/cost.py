@@ -55,12 +55,6 @@ __all__ = [
     "merge_cost_snapshots",
 ]
 
-# MFU denominator when neither GORDO_DEVICE_PEAK_FLOPS nor the device
-# spec table knows the chip (CPU dev loops): 1 TFLOP/s, stamped
-# "assumed". Keeps the MFU plumbing live everywhere without pretending
-# the number is a utilization measurement.
-_ASSUMED_PEAK_FLOPS = 1e12
-
 # Dense bf16 peak FLOP/s per chip (public spec sheets) — same table the
 # bench uses; duplicated here so the serving path never imports bench.py.
 PEAK_BF16_FLOPS = {
@@ -191,26 +185,23 @@ def estimate_flops_per_row(
 # ---------------------------------------------------------------------- #
 
 
-def resolve_peak_flops() -> Tuple[float, str]:
+def resolve_peak_flops() -> Tuple[Optional[float], str]:
     """(per-device peak FLOP/s, provenance) for the MFU denominator.
 
     Order: ``GORDO_DEVICE_PEAK_FLOPS`` (operator knows their chip) ->
-    the public spec table keyed by jax device_kind -> the assumed
-    1 TFLOP/s fallback. Provenance rides every snapshot; only ``env``
-    and ``device`` MFU numbers are utilization claims."""
+    the public spec table keyed by jax device_kind. A device in neither
+    (a CPU dev loop, an unlisted chip) has NO peak: ``(None,
+    "unknown")``, and every MFU field derived from it is null — a
+    utilization against an assumed peak is not a measurement."""
     raw = os.environ.get("GORDO_DEVICE_PEAK_FLOPS")
     if raw:
         return float(raw), "env"
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = ""
-    peak = PEAK_BF16_FLOPS.get(kind or "")
+    peak = PEAK_BF16_FLOPS.get(jax.devices()[0].device_kind)
     if peak:
         return peak, "device"
-    return _ASSUMED_PEAK_FLOPS, "assumed"
+    return None, "unknown"
 
 
 # ---------------------------------------------------------------------- #
@@ -227,7 +218,7 @@ def bucket_cost_row(
     useful_s: float,
     padded_s: float,
     failed_s: float,
-    peak_flops: float,
+    peak_flops: Optional[float],
     members: Optional[int] = None,
     kind: Optional[str] = None,
 ) -> Dict[str, Any]:
@@ -266,9 +257,10 @@ def bucket_cost_row(
         "achieved_flops_per_sec": round(achieved, 3),
         # mfu counts only ROUTED (real) rows against peak; mfu_dispatched
         # includes pad rows — the gap between them IS the pad tax
-        "mfu": round(achieved / peak_flops, 9) if peak_flops > 0 else None,
+        # (null when the device's peak is unknown)
+        "mfu": round(achieved / peak_flops, 9) if peak_flops else None,
         "mfu_dispatched": round(achieved_disp / peak_flops, 9)
-        if peak_flops > 0
+        if peak_flops
         else None,
         # fraction of this bucket's device time spent on padding — the
         # per-bucket half of the ranking key
@@ -332,7 +324,7 @@ class CostModel:
             peak_flops, peak_source = resolve_peak_flops()
         else:
             peak_source = "explicit"
-        self.peak_flops = float(peak_flops)
+        self.peak_flops = None if peak_flops is None else float(peak_flops)
         self.peak_source = peak_source
         self._clock = clock
         self._lock = threading.Lock()
@@ -483,8 +475,8 @@ def merge_cost_snapshots(
         if not body or not body.get("enabled", True):
             continue
         scraped += 1
-        if peak_flops is None:
-            peak_flops = float(body.get("peak_flops") or _ASSUMED_PEAK_FLOPS)
+        if peak_flops is None and body.get("peak_flops"):
+            peak_flops = float(body["peak_flops"])
         src = body.get("peak_source")
         if src and src not in peak_sources:
             peak_sources.append(src)
@@ -512,7 +504,6 @@ def merge_cost_snapshots(
                 },
             )
             info["live"] = bool(info["live"] or row.get("live"))
-    peak_flops = _ASSUMED_PEAK_FLOPS if peak_flops is None else peak_flops
     buckets: Dict[str, Dict[str, Any]] = {}
     total_device_s = 0.0
     for label in sorted(acc):

@@ -41,14 +41,11 @@ multiple: observed ≤2 ULP, asserted ≤4 ULP.
 """
 
 import functools
-import logging
 import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-logger = logging.getLogger(__name__)
 
 ROW_TILE = 256  # rows per grid step (multiple of the 8-sublane f32 tile)
 LANE = 128
@@ -56,7 +53,7 @@ LANE = 128
 
 @jax.jit
 def _jnp_score(target, output, shift, scale):
-    """Reference implementation (also the non-TPU fallback)."""
+    """Reference implementation (also the non-TPU path)."""
     diff = jnp.abs(target - output)
     scaled = (diff - shift) * scale
     tot_u = jnp.sqrt(jnp.sum(diff * diff, axis=-1))
@@ -132,15 +129,7 @@ def _pallas_score(target, output, shift, scale, interpret=False):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
-
-_pallas_disabled = False  # sticky only when the kernel NEVER worked (compile)
-_pallas_ever_worked = False
-_transient_warned = False
+    return jax.default_backend() == "tpu"
 
 
 def fused_anomaly_score(
@@ -153,50 +142,17 @@ def fused_anomaly_score(
     """``(diff, scaled, total_unscaled, total_scaled)`` for a (rows, F)
     reconstruction — one fused pass on TPU, identical jnp math elsewhere.
 
-    ``force``: "auto" (TPU -> kernel, else jnp), "pallas" (compiled
-    kernel, errors propagate), "interpret" (kernel in interpreter mode,
-    any backend), "jnp" (pure fallback). In "auto" mode a failure before
-    the kernel has ever worked on this backend (a compile problem)
-    disables it for the process; a failure after it has worked (e.g. a
-    transient allocation error on one oversized request) falls back for
-    that call only.
+    ``force``: "auto" (a pure function of the backend: the compiled
+    kernel on TPU, jnp elsewhere), "pallas" (compiled kernel), "jnp"
+    (XLA path), "interpret" (kernel in interpreter mode — tests only,
+    never selected by auto). A kernel that fails to compile or run is an
+    error that reaches the caller: nothing degrades to jnp on exception.
     """
-    global _pallas_disabled, _pallas_ever_worked, _transient_warned
-    if force == "jnp" or (
-        force == "auto" and (_pallas_disabled or not _on_tpu())
-    ):
+    if force == "jnp" or (force == "auto" and not _on_tpu()):
         return _jnp_score(target, output, shift, scale)
-    if force == "interpret":
-        return _pallas_score(target, output, shift, scale, interpret=True)
-    try:
-        out = _pallas_score(target, output, shift, scale)
-        # async dispatch: execution errors surface at result consumption,
-        # which would be outside this try — block here so runtime failures
-        # (e.g. allocation) are caught and can fall back per call
-        jax.block_until_ready(out)
-        _pallas_ever_worked = True
-        return out
-    except Exception:
-        if force != "auto":
-            raise
-        if not _pallas_ever_worked:
-            _pallas_disabled = True
-            logger.warning(
-                "Pallas scoring kernel failed to compile on backend %r; "
-                "using XLA for the rest of this process",
-                jax.default_backend(),
-                exc_info=True,
-            )
-        elif not _transient_warned:
-            _transient_warned = True
-            logger.warning(
-                "Pallas scoring kernel failed transiently; falling back to "
-                "XLA for this call (further occurrences logged at DEBUG)",
-                exc_info=True,
-            )
-        else:
-            logger.debug("Pallas scoring kernel transient failure", exc_info=True)
-        return _jnp_score(target, output, shift, scale)
+    return _pallas_score(
+        target, output, shift, scale, interpret=force == "interpret"
+    )
 
 
 # --------------------------------------------------------------------- #
@@ -207,51 +163,14 @@ BANK_KERNEL_ENV = "GORDO_BANK_KERNEL"
 _BANK_KERNEL_MODES = ("auto", "pallas", "interpret", "jnp")
 
 
-# auto-mode probe result: None = not probed yet, True/False = the banked
-# kernel compiled (or not) on this process's backend. An explicit
-# GORDO_BANK_KERNEL=pallas bypasses the probe and propagates errors.
-_banked_probe_ok = None
-
-
-def _probe_banked_kernel() -> bool:
-    """One tiny compile of the banked kernel, cached per process: auto
-    mode must never bake a kernel that cannot compile into every bucket
-    program (the banked analogue of ``fused_anomaly_score``'s
-    compile-failure degrade — there the fallback is per call; here the
-    mode is frozen into jit'd programs at build time, so the degrade has
-    to happen BEFORE resolution)."""
-    global _banked_probe_ok
-    if _banked_probe_ok is None:
-        try:
-            out = _pallas_banked_score(
-                jnp.zeros((1, 8, 4), jnp.float32),
-                jnp.zeros((1, 8, 4), jnp.float32),
-                jnp.zeros((1, 4), jnp.float32),
-                jnp.ones((1, 4), jnp.float32),
-                jnp.zeros((1,), jnp.int32),
-            )
-            jax.block_until_ready(out)
-            _banked_probe_ok = True
-        except Exception:
-            _banked_probe_ok = False
-            logger.warning(
-                "Banked Pallas scoring kernel failed to compile on backend "
-                "%r; banks built in auto mode use the XLA epilogue for the "
-                "rest of this process (GORDO_BANK_KERNEL=pallas to surface "
-                "the error)",
-                jax.default_backend(),
-                exc_info=True,
-            )
-    return _banked_probe_ok
-
-
 def resolve_bank_kernel_mode(mode: str = None) -> str:
     """Concrete dispatch mode for the banked epilogue: ``mode`` (or env
     ``GORDO_BANK_KERNEL``, default ``auto``) resolved against the
     backend. Resolved ONCE per bank build — the choice is baked into the
-    bucket's compiled program, not re-decided per request. ``auto`` on a
-    TPU probe-compiles the kernel first and degrades to the XLA path if
-    the probe fails; an explicit ``pallas`` never degrades."""
+    bucket's compiled program, not re-decided per request. ``auto`` is a
+    pure function of the backend (``pallas`` on TPU, ``jnp`` elsewhere):
+    nothing is probe-compiled and nothing degrades — a kernel the chip's
+    compiler refuses fails the bucket's warm-up compile, loudly."""
     raw = (mode or os.environ.get(BANK_KERNEL_ENV) or "auto").strip().lower()
     if raw not in _BANK_KERNEL_MODES:
         raise ValueError(
@@ -259,7 +178,7 @@ def resolve_bank_kernel_mode(mode: str = None) -> str:
             f"got {raw!r}"
         )
     if raw == "auto":
-        return "pallas" if _on_tpu() and _probe_banked_kernel() else "jnp"
+        return "pallas" if _on_tpu() else "jnp"
     return raw
 
 
@@ -279,7 +198,7 @@ def _jnp_banked_score(target, output, shift_bank, scale_bank, idx):
 def _banked_kernel(n_features: int, idx_ref, t_ref, o_ref, shift_ref,
                    scale_ref, diff_ref, scaled_ref, tu_ref, ts_ref):
     # one (member, row-tile) grid step: refs are (1, row_tile, Fp) batch
-    # tiles and (1, Fp) scaler rows already gathered by the scalar-
+    # tiles and (1, 1, Fp) scaler rows already gathered by the scalar-
     # prefetched index map (idx_ref is consumed there, not here)
     t = t_ref[0]
     o = o_ref[0]
@@ -311,7 +230,13 @@ def _pallas_banked_score(target, output, shift_bank, scale_bank, idx,
     )
     t = pad3(target)
     o = pad3(output)
-    pad_bank = lambda a: jnp.pad(a.astype(jnp.float32), ((0, 0), (0, Fp - F)))
+    # banks ride as (M, 1, Fp): the TPU lowering wants a block's last two
+    # dims to be (8, 128)-multiples or the array's own, and a one-row block
+    # of an (M, Fp) bank is neither once M > 1 — with the member axis
+    # leading, the (1, 1, Fp) block's last two dims ARE the array's
+    pad_bank = lambda a: jnp.pad(
+        a.astype(jnp.float32), ((0, 0), (0, Fp - F))
+    )[:, None, :]
     sh, sc = pad_bank(shift_bank), pad_bank(scale_bank)
 
     # index maps receive (grid indices..., scalar-prefetch refs): the
@@ -322,7 +247,7 @@ def _pallas_banked_score(target, output, shift_bank, scale_bank, idx,
         (1, row_tile, Fp), lambda b, r, i: (b, r, 0), memory_space=pltpu.VMEM
     )
     gathered = lambda: pl.BlockSpec(
-        (1, Fp), lambda b, r, i: (i[b], 0), memory_space=pltpu.VMEM
+        (1, 1, Fp), lambda b, r, i: (i[b], 0, 0), memory_space=pltpu.VMEM
     )
     norm = lambda: pl.BlockSpec(
         (1, row_tile, 1), lambda b, r, i: (b, r, 0), memory_space=pltpu.VMEM

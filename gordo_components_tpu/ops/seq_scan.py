@@ -4,9 +4,9 @@ innermost.
 The fleet engine's original recurrent layout nests ``vmap`` (member axis)
 OUTSIDE ``flax.linen.RNN`` (``lax.scan`` inside): every scan step issues M
 interleaved small matmuls whose lane dimension is one member's hidden
-width. The TPU bench (BENCH_TPU_20260731) measured that layout at 0.5x the
-per-model throughput of training members one at a time — vmap-over-members
-is a *pessimization* for recurrent architectures.
+width. Neither layout has a rate on the chip yet (PERF.md): the premise
+that vmap-over-members loses for recurrent architectures rests on CPU
+runs only.
 
 This module inverts the nesting. One ``lax.scan`` over time; the carry and
 activations keep members as the INNERMOST (lane-friendly) axis:
@@ -39,21 +39,19 @@ Two env knobs, resolved ONCE per compiled program (never per call):
   CPU test suite pins (tests opt in explicitly).
 - ``GORDO_SEQ_KERNEL`` = ``auto|pallas|interpret|jnp``: the fused
   recurrent-step kernel below (gate matmul + nonlinearities + carry update
-  in one VMEM pass per step), ``GORDO_BANK_KERNEL``-style resolution with
-  interpret mode as CI's parity vehicle. The kernel is FORWARD-ONLY: it
+  in one VMEM pass per step), ``GORDO_BANK_KERNEL``-style resolution
+  (``auto`` = ``pallas`` on TPU, ``jnp`` elsewhere) with interpret mode
+  as the tests' parity vehicle. The kernel is FORWARD-ONLY: it
   serves the bank's compiled scoring programs; training keeps the jnp step
   (its backward comes from autodiff through the scan — a custom VJP for
   the fused step is future work, see docs/architecture.md).
 """
 
 import functools
-import logging
 import os
 
 import jax
 import jax.numpy as jnp
-
-logger = logging.getLogger(__name__)
 
 SEQ_LAYOUT_ENV = "GORDO_SEQ_LAYOUT"
 SEQ_KERNEL_ENV = "GORDO_SEQ_KERNEL"
@@ -85,43 +83,12 @@ def resolve_seq_layout(mode: str = None) -> str:
     return raw
 
 
-_step_probe_ok = None
-
-
-def _probe_step_kernel() -> bool:
-    """One tiny compile of the fused step, cached per process — the
-    recurrent analogue of pallas_score's banked probe: auto mode must
-    never bake a kernel that cannot compile into a scoring program."""
-    global _step_probe_ok
-    if _step_probe_ok is None:
-        try:
-            out = fused_lstm_step(
-                jnp.zeros((8, 1, 4 * LANE), jnp.float32),
-                jnp.zeros((8, 1, LANE), jnp.float32),
-                jnp.zeros((8, 1, LANE), jnp.float32),
-                jnp.zeros((1, LANE, 4 * LANE), jnp.float32),
-                jnp.zeros((1, 4 * LANE), jnp.float32),
-            )
-            jax.block_until_ready(out)
-            _step_probe_ok = True
-        except Exception:
-            _step_probe_ok = False
-            logger.warning(
-                "Fused LSTM-step Pallas kernel failed to compile on backend "
-                "%r; scoring programs built in auto mode use the jnp step "
-                "for the rest of this process (GORDO_SEQ_KERNEL=pallas to "
-                "surface the error)",
-                jax.default_backend(),
-                exc_info=True,
-            )
-    return _step_probe_ok
-
-
 def resolve_seq_kernel_mode(mode: str = None) -> str:
     """Dispatch mode for the fused recurrent-step kernel (scoring path):
     ``mode`` (or env ``GORDO_SEQ_KERNEL``, default ``auto``) resolved once
-    per program build. ``auto`` on TPU probe-compiles first and degrades
-    to jnp if the probe fails; an explicit ``pallas`` never degrades."""
+    per program build. ``auto`` is a pure function of the backend
+    (``pallas`` on TPU, ``jnp`` elsewhere): no probe compile, no degrade —
+    a kernel the chip refuses fails the program that uses it."""
     raw = (mode or os.environ.get(SEQ_KERNEL_ENV) or "auto").strip().lower()
     if raw not in _SEQ_KERNEL_MODES:
         raise ValueError(
@@ -129,11 +96,7 @@ def resolve_seq_kernel_mode(mode: str = None) -> str:
             f"got {raw!r}"
         )
     if raw == "auto":
-        return (
-            "pallas"
-            if jax.default_backend() == "tpu" and _probe_step_kernel()
-            else "jnp"
-        )
+        return "pallas" if jax.default_backend() == "tpu" else "jnp"
     return raw
 
 
@@ -198,51 +161,64 @@ def lstm_step_jnp(xz_t, h, c, Wh, b):
 
 
 def _step_kernel(xz_ref, h_ref, c_ref, wh_ref, b_ref, c2_ref, h2_ref):
-    """Grid step = one member: gate matmul + nonlinearities + carry update
-    in a single VMEM pass — the recurrent analogue of pallas_score's
-    banked grid. Blocks carry a singleton member axis (B, 1, ·)."""
+    """Grid step = one (member, batch-tile): gate matmul + nonlinearities
+    + carry update in a single VMEM pass — the recurrent analogue of
+    pallas_score's banked grid. Blocks carry a singleton LEADING member
+    axis (1, B_tile, ·), so their last two dims are tile-aligned."""
     z = (
-        xz_ref[:, 0, :]
-        + jnp.dot(h_ref[:, 0, :], wh_ref[0], preferred_element_type=jnp.float32)
-        + b_ref[0][None, :]
+        xz_ref[0]
+        + jnp.dot(h_ref[0], wh_ref[0], preferred_element_type=jnp.float32)
+        + b_ref[0]
     )
     i, f, g, o = jnp.split(z, 4, axis=-1)
-    c = c_ref[:, 0, :]
-    c2 = jax.nn.sigmoid(f) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
-    h2 = jax.nn.sigmoid(o) * jnp.tanh(c2)
-    c2_ref[:, 0, :] = c2
-    h2_ref[:, 0, :] = h2
+    c2 = jax.nn.sigmoid(f) * c_ref[0] + jax.nn.sigmoid(i) * jnp.tanh(g)
+    c2_ref[0] = c2
+    h2_ref[0] = jax.nn.sigmoid(o) * jnp.tanh(c2)
+
+
+STEP_BATCH_TILE = 256  # batch rows per grid step (bounds the VMEM block)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_lstm_step(xz_t, h, c, Wh, b, interpret: bool = False):
-    """Pallas fused step with the same signature/layout as
-    :func:`lstm_step_jnp` (member axis innermost, H already padded to the
-    lane tile by :func:`pad_gate_lanes`). Returns (c', h')."""
+    """Pallas fused step, MEMBER-MAJOR: xz_t (M, B, 4H) precomputed input
+    projection; h/c (M, B, H); Wh (M, H, 4H); b (M, 4H) — the math of
+    :func:`lstm_step_jnp` with the member axis leading instead of
+    second-last (the TPU lowering refuses a size-1 block on a second-last
+    axis of size M > 1). H is already padded to the lane tile
+    (:func:`pad_gate_lanes`) and B to the sublane tile, or to a
+    ``STEP_BATCH_TILE`` multiple when longer. Returns (c', h')."""
     from jax.experimental import pallas as pl
 
-    B, M, H4 = xz_t.shape
+    M, B, H4 = xz_t.shape
     H = H4 // 4
-    grid = (M,)
-    blk_h = pl.BlockSpec((B, 1, H), lambda m: (0, m, 0))
-    blk_z = pl.BlockSpec((B, 1, H4), lambda m: (0, m, 0))
+    tb = min(B, STEP_BATCH_TILE)
+    if B % tb:
+        raise ValueError(
+            f"batch axis {B} must be a multiple of {STEP_BATCH_TILE} once "
+            "it exceeds it (the grid would drop the remainder)"
+        )
+    # the member's Wh/b block index does not change along the inner batch
+    # axis, so the pipeline DMAs them once per member
+    blk_h = pl.BlockSpec((1, tb, H), lambda m, j: (m, j, 0))
+    blk_z = pl.BlockSpec((1, tb, H4), lambda m, j: (m, j, 0))
     return pl.pallas_call(
         _step_kernel,
-        grid=grid,
+        grid=(M, B // tb),
         in_specs=[
             blk_z,
             blk_h,
             blk_h,
-            pl.BlockSpec((1, H, H4), lambda m: (m, 0, 0)),
-            pl.BlockSpec((1, H4), lambda m: (m, 0)),
+            pl.BlockSpec((1, H, H4), lambda m, j: (m, 0, 0)),
+            pl.BlockSpec((1, 1, H4), lambda m, j: (m, 0, 0)),
         ],
         out_specs=[blk_h, blk_h],
         out_shape=[
-            jax.ShapeDtypeStruct((B, M, H), xz_t.dtype),
-            jax.ShapeDtypeStruct((B, M, H), xz_t.dtype),
+            jax.ShapeDtypeStruct((M, B, H), xz_t.dtype),
+            jax.ShapeDtypeStruct((M, B, H), xz_t.dtype),
         ],
         interpret=interpret,
-    )(xz_t, h, c, Wh, b)
+    )(xz_t, h, c, Wh, b[:, None, :])
 
 
 def _round_up(n: int, k: int) -> int:
@@ -288,11 +264,16 @@ def _lstm_layer(x, Wi, Wh, b, kernel: str):
     """
     T, B, M, _ = x.shape
     H = Wh.shape[-2]
-    xz = jnp.einsum("tbmf,mfg->tbmg", x, Wi)
     if kernel in ("pallas", "interpret"):
+        # the fused step walks members on its grid, so its operands are
+        # member-major (M, B, ·): the projection einsum emits that order
+        # directly and the scan output is swapped back once per layer
+        xz = jnp.einsum("tbmf,mfg->tmbg", x, Wi)
         Hp = _round_up(H, LANE)
         Whp, bp = pad_gate_lanes(Wh, b, H, Hp)
         Bp = _round_up(B, SUBLANE)
+        if Bp > STEP_BATCH_TILE:
+            Bp = _round_up(B, STEP_BATCH_TILE)
         if Hp != H:
             parts = jnp.split(xz, 4, axis=-1)
             parts = [
@@ -301,7 +282,7 @@ def _lstm_layer(x, Wi, Wh, b, kernel: str):
             ]
             xz = jnp.concatenate(parts, axis=-1)
         if Bp != B:
-            xz = jnp.pad(xz, ((0, 0), (0, Bp - B), (0, 0), (0, 0)))
+            xz = jnp.pad(xz, ((0, 0), (0, 0), (0, Bp - B), (0, 0)))
         interpret = kernel == "interpret"
 
         def step(carry, xz_t):
@@ -309,9 +290,11 @@ def _lstm_layer(x, Wi, Wh, b, kernel: str):
             c2, h2 = fused_lstm_step(xz_t, h, c, Whp, bp, interpret=interpret)
             return (c2, h2), h2
 
-        zeros = jnp.zeros((Bp, M, Hp), x.dtype)
+        zeros = jnp.zeros((M, Bp, Hp), x.dtype)
         _, ys = jax.lax.scan(step, (zeros, zeros), xz)
-        return ys[:, :B, :, :H]
+        return jnp.swapaxes(ys[:, :, :B, :H], 1, 2)
+
+    xz = jnp.einsum("tbmf,mfg->tbmg", x, Wi)
 
     def step(carry, xz_t):
         c, h = carry

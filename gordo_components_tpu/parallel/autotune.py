@@ -1,11 +1,12 @@
 """Fleet vmap-width autotuning (``GORDO_FLEET_WIDTH``).
 
-The TPU width sweep (BENCH_TPU_20260731) put the models/sec knee at 4096
-members per dispatch: narrower gangs underfill the device, wider ones gain
-nothing while inflating the epoch program's working set (and the quantile
-histogram transient, which scales with the vmap width — parallel/fleet.py
+The one width sweep that ran on a chip (a pre-round record since deleted,
+not a ledger number) stopped at 4096 members per dispatch with the rate
+still rising: narrower gangs underfill the device, while wider ones inflate
+the epoch program's working set (and the quantile histogram transient,
+which scales with the vmap width — parallel/fleet.py
 ``run_error_scalers``). Default member widths are whatever the caller's
-bucketing produced, which leaves ~3x on the table even for dense fleets.
+bucketing produced.
 
 ``GORDO_FLEET_WIDTH`` caps the member width of every training dispatch:
 
@@ -16,7 +17,7 @@ bucketing produced, which leaves ~3x on the table even for dense fleets.
   machine that has already measured this architecture. The sweep times a
   proxy of the epoch's inner op (a member-batched matmul) at a ladder of
   widths and takes the SMALLEST width within 10% of peak per-member
-  throughput, breaking flat ties toward the measured TPU knee (4096) —
+  throughput, breaking flat ties toward 4096 (the widest width measured) —
   under-capping costs real throughput, over-capping only transient memory.
 
 Persistence is a tiny JSON table keyed ``{arch}|{device_kind}`` at

@@ -33,7 +33,6 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gordo_components_tpu.ops.losses import mse_loss
-from gordo_components_tpu.parallel.compat import shard_map
 
 DATA_AXIS = "data"
 
@@ -75,7 +74,7 @@ def make_dp_train_step(
         return mse_loss(pred, yb)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(P(), P(), P()),
@@ -123,7 +122,7 @@ def make_dp_epoch_fn(
     loss_fn = make_loss_fn(module, loss=loss, kl_weight=kl_weight)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P(), P(), P(), P()), out_specs=(P(), P()),
+        jax.shard_map, mesh=mesh, in_specs=(P(), P(), P(), P()), out_specs=(P(), P()),
         # the static varying-manual-axes analysis rejects recurrent modules
         # whose scan carry initializes unvarying (flax nn.RNN zeros) while
         # inputs vary over 'data' — numerically fine (all cross-device

@@ -51,6 +51,7 @@ from gordo_components_tpu.ops.scaler import (
 from gordo_components_tpu.parallel.autotune import resolve_fleet_width
 from gordo_components_tpu.parallel.mesh import (
     MODEL_AXIS,
+    device_block,
     fleet_mesh,
     pad_count_to_mesh,
     shard_model_axis,
@@ -845,6 +846,7 @@ class FleetTrainer:
             )
         self.require_thresholds = bool(require_thresholds)
         self._bucket_layout = "legacy"  # layout of the last-built bucket
+        self._bucket_device = None  # device block of the last-built bucket
         self.epochs = int(epochs)
         self.batch_size = int(batch_size)
         self.learning_rate = float(learning_rate)
@@ -1123,6 +1125,8 @@ class FleetTrainer:
                     # "legacy" = vmap(epoch); dense buckets are always
                     # legacy) — resolved per program, recorded per bucket
                     "layout": self._bucket_layout,
+                    # the devices the bucket's stacked state sat on
+                    "device": self._bucket_device,
                 }
             )
         self.last_stats = {
@@ -1226,6 +1230,7 @@ class FleetTrainer:
             self.threshold_quantile,
         )
         self._bucket_layout = progs.layout
+        self._bucket_device = device_block(Xd)
         init_stacked = progs.init_stacked
         run_epoch = progs.run_epoch
 

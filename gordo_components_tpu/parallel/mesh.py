@@ -10,7 +10,7 @@ Multi-host pods work unchanged: ``jax.devices()`` spans the pod under
 shard.
 """
 
-from typing import Optional, Sequence
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 import numpy as np
@@ -41,3 +41,22 @@ def pad_count_to_mesh(count: int, mesh: Mesh) -> int:
     """Smallest multiple of the mesh's model-axis size >= count."""
     size = mesh.shape[MODEL_AXIS]
     return -(-count // size) * size
+
+
+def device_block(arrays: Any) -> Optional[Dict[str, Any]]:
+    """``{"platform", "kind", "count"}`` of the devices that actually hold
+    ``arrays`` (a pytree of jax Arrays), read from the arrays themselves —
+    what ran where, not what ``jax.devices()`` offers. A parent process
+    that stays off JAX reports its child's device from this (the
+    fleet-build manifest, ``GET /models``). None for an empty tree."""
+    devices = set()
+    for leaf in jax.tree.leaves(arrays):
+        devices |= leaf.devices()
+    if not devices:
+        return None
+    first = min(devices, key=lambda d: d.id)
+    return {
+        "platform": first.platform,
+        "kind": first.device_kind,
+        "count": len(devices),
+    }

@@ -7,7 +7,7 @@ drift -> recalibrate -> refit -> hot-swap loop, with bounds asserted by
 history); at the engine's default compression they each run in seconds
 of wall time.
 
-The scenario set mirrors the incident taxonomy in ROADMAP item 5:
+The scenario set mirrors the incident classes in ROADMAP item 5:
 calibration drift (mean shift, variance inflation — singly and
 correlated fleet-wide), sensor pathologies (dropout, flatline),
 delivery pathologies (late + duplicated rows), the seasonal

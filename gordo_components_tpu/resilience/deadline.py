@@ -22,7 +22,7 @@ fix is a budget that travels WITH the request:
 :class:`DeadlineExceeded` subclasses :class:`asyncio.TimeoutError` so
 existing best-effort call sites (watchman scrapes, the shared
 ``fetch_metadata_all`` helper) that already catch timeouts degrade the
-same way for a blown deadline — one exception taxonomy for "out of
+same way for a blown deadline — one exception family for "out of
 time" everywhere.
 
 Deadlines are monotonic-clock absolute instants: immune to wall-clock

@@ -449,9 +449,10 @@ def build_app(
     tuning"): ``bank_inflight`` (env ``GORDO_BANK_INFLIGHT``) bounds how
     many bucket groups ``score_many`` keeps in flight on the device;
     ``arena_max_mb`` (env ``GORDO_ARENA_MAX_MB``) bounds the
-    padded-buffer arena. ``GORDO_COMPILE_CACHE_DIR`` arms the persistent
-    XLA compilation cache before the bank's bucket programs build, so a
-    restarted replica re-warms from disk instead of recompiling.
+    padded-buffer arena. The persistent XLA compilation cache is placed
+    (``utils.profiling.resolve_compile_cache``) before the bank's bucket
+    programs build, so a restarted replica re-warms from disk instead of
+    recompiling.
 
     ``clock`` is the wall-time seam (replay/clock.py): the streaming
     plane's lateness/staleness accounting and the SLO tracker's window
@@ -483,18 +484,16 @@ def build_app(
     # armed BEFORE the bank compiles its bucket programs, so a restarted
     # or rolling-deployed replica loads them from the shared volume
     # instead of stalling its first requests on recompiles
-    cache_dir = os.environ.get("GORDO_COMPILE_CACHE_DIR")
-    if cache_dir:
-        from gordo_components_tpu.utils.profiling import enable_compile_cache
+    from gordo_components_tpu.utils.profiling import resolve_compile_cache
 
-        try:
-            enable_compile_cache(cache_dir)
-        except Exception:
-            logger.warning(
-                "GORDO_COMPILE_CACHE_DIR=%s: could not enable the "
-                "persistent compilation cache; serving continues without it",
-                cache_dir, exc_info=True,
-            )
+    try:
+        resolve_compile_cache()
+    except OSError:
+        logger.warning(
+            "could not create the persistent compilation cache directory; "
+            "serving continues without it",
+            exc_info=True,
+        )
     if use_bank is None:
         use_bank = os.environ.get("GORDO_SERVER_BANK", "1") != "0"
     if devices is None:
@@ -795,9 +794,13 @@ def build_app(
                 # the whole compile loop and fail readiness probes on
                 # large fleets
                 if os.environ.get("GORDO_SERVER_WARMUP", "1") != "0":
-                    app["warmup_future"] = asyncio.get_running_loop().run_in_executor(
+                    fut = asyncio.get_running_loop().run_in_executor(
                         None, bank.warmup
                     )
+                    # a warm-up compile failure is not a warning: it stays
+                    # in the future, where /healthz reads it (unhealthy)
+                    fut.add_done_callback(_log_warmup_failure)
+                    app["warmup_future"] = fut
 
             app.on_startup.append(_start_engine)
 
@@ -873,6 +876,15 @@ def build_app(
     app.on_cleanup.append(_stop_engine)
     app.add_routes(routes)
     return app
+
+
+def _log_warmup_failure(fut: "asyncio.Future") -> None:
+    if not fut.cancelled() and fut.exception() is not None:
+        logger.error(
+            "Model bank warm-up FAILED: the bucket programs requests "
+            "dispatch did not compile; /healthz reports unhealthy",
+            exc_info=fut.exception(),
+        )
 
 
 def run_server(

@@ -64,6 +64,7 @@ from gordo_components_tpu.ops.quantize import (
     tree_weight_bytes,
 )
 from gordo_components_tpu.ops.scaler import ScalerParams
+from gordo_components_tpu.parallel.mesh import device_block
 from gordo_components_tpu.ops.seq_scan import (
     lstm_time_major_forward,
     resolve_seq_kernel_mode,
@@ -478,8 +479,6 @@ class _Bucket:
         else:
             from jax.sharding import PartitionSpec as P
 
-            from gordo_components_tpu.parallel.compat import shard_map
-
             from gordo_components_tpu.parallel.mesh import MODEL_AXIS
 
             spec = P(MODEL_AXIS)
@@ -509,7 +508,7 @@ class _Bucket:
                 # (every output row depends only on the local shard), and
                 # the varying-axes checker rejects the LSTM scan's
                 # unvarying initial carry under a varying input
-                return shard_map(
+                return jax.shard_map(
                     local,
                     mesh=self.mesh,
                     in_specs=(spec,) * 8,
@@ -1098,6 +1097,12 @@ class ModelBank:
             "devices": int(self.mesh.devices.size) if self.mesh is not None else 1,
             "bank_dtype": self.bank_dtype,
             "kernel": self.kernel_mode,
+            # where the stacked weights actually sit, read from the
+            # arrays' own devices (None for an empty bank); one leaf per
+            # bucket — finalize() places a bucket's whole stack together
+            "device": device_block(
+                [jax.tree.leaves(b.params)[0] for b in self._buckets.values()]
+            ),
         }
 
     def capacity_stats(self) -> Dict[str, Any]:
@@ -1265,24 +1270,25 @@ class ModelBank:
                     for B in batches
                 }
             )
-            try:
-                for T, B in shapes:
-                    if self.mesh is None:
-                        X = np.zeros((B, T, bucket.n_features), np.float32)
-                        bucket.score_batch(np.zeros((B,), np.int32), X, X)
-                    else:
-                        D = bucket.n_shards
-                        X = np.zeros((D, B, T, bucket.n_features), np.float32)
-                        bucket.score_batch_sharded(
-                            np.zeros((D, B), np.int32), X, X
-                        )
-                warmed += 1
-                total_shapes += len(shapes)
-            except Exception:
-                logger.warning(
-                    "bank warmup failed for bucket %s/%s",
-                    bucket.registry_type, bucket.kind, exc_info=True,
-                )
+            # a compile failure here propagates: the programs warmed are
+            # the ones requests dispatch, so a bucket that cannot compile
+            # cannot serve (the server's background warm-up lands it in
+            # app["warmup_future"], which /healthz reports)
+            for T, B in shapes:
+                if self.mesh is None:
+                    X = np.zeros((B, T, bucket.n_features), np.float32)
+                    out = bucket.score_batch(np.zeros((B,), np.int32), X, X)
+                else:
+                    D = bucket.n_shards
+                    X = np.zeros((D, B, T, bucket.n_features), np.float32)
+                    out = bucket.score_batch_sharded(
+                        np.zeros((D, B), np.int32), X, X
+                    )
+                # dispatch is async: a kernel that compiles but faults on
+                # the device would otherwise surface in the first request
+                jax.block_until_ready(out)
+            warmed += 1
+            total_shapes += len(shapes)
         if warmed:
             logger.info(
                 "Model bank warmed: %d bucket(s) pre-compiled over %d "

@@ -347,6 +347,8 @@ def _bank_coverage(request: web.Request, names) -> Any:
         },
         "n_buckets": cov["n_buckets"],
         "devices": cov["devices"],
+        "kernel": cov["kernel"],
+        "device": cov["device"],
     }
 
 
@@ -403,8 +405,11 @@ def _healthz_body(app: web.Application) -> tuple:
     flap and restart a process that is serving its healthy majority)
     means a subset is impaired: models quarantined by the failure
     breaker, or artifacts the collection could not load on its latest
-    scan. ``unhealthy`` (503) means nothing is servable. The body always
-    says WHY, so "degraded" is a pager link, not a mystery."""
+    scan. ``unhealthy`` (503) means nothing is servable — or the bank's
+    background warm-up compile failed: the programs it compiles are the
+    ones requests dispatch, so a replica whose warm-up raised must not
+    pass for a serving one. The body always says WHY, so "degraded" is a
+    pager link, not a mystery."""
     collection = app.get("collection")
     quarantine = app.get("quarantine")
     bank = app.get("bank")
@@ -412,7 +417,13 @@ def _healthz_body(app: web.Application) -> tuple:
     load_failures = dict(collection.load_failures) if collection is not None else {}
     quarantined = quarantine.snapshot()["quarantined"] if quarantine is not None else {}
     finalize_failures = dict(getattr(bank, "finalize_failures", None) or {})
-    if models == 0 and app.get("mesh") is None:
+    warmup = app.get("warmup_future")
+    warmup_error = None
+    if warmup is not None and warmup.done() and not warmup.cancelled():
+        exc = warmup.exception()
+        if exc is not None:
+            warmup_error = f"{type(exc).__name__}: {exc}"
+    if warmup_error is not None or (models == 0 and app.get("mesh") is None):
         status, http = "unhealthy", 503
     elif quarantined or load_failures or finalize_failures:
         status, http = "degraded", 200
@@ -424,6 +435,7 @@ def _healthz_body(app: web.Application) -> tuple:
         "quarantined": quarantined,
         "load_failures": load_failures,
         "bank_finalize_failures": finalize_failures,
+        "bank_warmup_error": warmup_error,
     }, http
 
 

@@ -13,6 +13,7 @@ from gordo_components_tpu.utils.profiling import (
     device_memory_stats,
     enable_compile_cache,
     maybe_profile,
+    resolve_compile_cache,
 )
 
 __all__ = [

@@ -79,30 +79,58 @@ def enable_compile_cache(cache_dir: str, min_compile_seconds: float = 1.0) -> st
     — and builder pods are routinely preempted and restarted (the
     checkpoint-resume path), while rolling server deploys re-warm every
     bucket. Pointing this at a shared volume makes those recompiles disk
-    reads (~tens of seconds per shape saved, measured ~34s/shape for
-    fleet programs on one CPU core). Programs cheaper than
-    ``min_compile_seconds`` stay uncached — writing them costs more than
-    recompiling. Returns the directory (created if absent).
+    reads. Programs cheaper than ``min_compile_seconds`` stay uncached —
+    writing them costs more than recompiling. Returns the directory
+    (created if absent).
     """
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", float(min_compile_seconds)
     )
-    try:
-        # jax (>=0.4.30s) memoizes "is the cache used" at the FIRST
-        # compile of the process: any jit before this call would freeze
-        # the verdict at "no" and silently ignore the config above for
-        # the process lifetime. Reset the memo so the next compile
-        # re-evaluates — this makes enabling the cache mid-process (a
-        # /reload-created bank, the rebalance swap's rebuild, tests)
-        # actually take effect, not just enabling-before-first-compile.
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # private API: degrade to the old behavior
-        logger.debug("compilation_cache.reset_cache unavailable", exc_info=True)
+    # jax memoizes "is the cache used" at the FIRST compile of the
+    # process: any jit before this call would freeze the verdict at "no"
+    # and ignore the config above for the process lifetime. Reset the memo
+    # so enabling the cache mid-process (a /reload-created bank, the
+    # rebalance swap's rebuild, tests) takes effect.
+    compilation_cache.reset_cache()
     logger.info("persistent XLA compilation cache at %s", cache_dir)
     return cache_dir
+
+
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+COMPILE_CACHE_ENV = "GORDO_COMPILE_CACHE_DIR"
+# the path is part of the cache's key, so the default never moves: no
+# tempfile, pid or timestamp — <checkout>/.jax_cache (git-ignored)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def resolve_compile_cache(knob: Optional[str] = None) -> str:
+    """Place the persistent compilation cache; every entry point (CLI
+    group, ``build_app``, ``chip_smoke.py``, ``bench.py``) calls this once.
+
+    1. ``JAX_COMPILATION_CACHE_DIR`` set: JAX already uses it. Nothing is
+       configured here and ``knob`` / ``GORDO_COMPILE_CACHE_DIR`` are
+       ignored — where the machine names a cache, what the program
+       caches there is found again by its next run.
+    2. else ``knob`` (``--compile-cache-dir``) or
+       ``GORDO_COMPILE_CACHE_DIR``.
+    3. else the fixed :data:`DEFAULT_COMPILE_CACHE_DIR`.
+
+    Returns the directory in use."""
+    knob = knob or os.environ.get(COMPILE_CACHE_ENV)
+    jax_dir = os.environ.get(JAX_CACHE_ENV)
+    if jax_dir:
+        if knob and knob != jax_dir:
+            logger.info(
+                "%s=%s is set: ignoring the compile-cache knob (%s)",
+                JAX_CACHE_ENV, jax_dir, knob,
+            )
+        return jax_dir
+    return enable_compile_cache(knob or DEFAULT_COMPILE_CACHE_DIR)

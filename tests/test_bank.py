@@ -61,6 +61,25 @@ def test_bank_membership_and_buckets(fleet_models):
     assert bank.n_buckets == 3
 
 
+def test_coverage_device_block_reads_the_arrays_devices(fleet_models):
+    """``coverage()["device"]`` is where the stacked weights actually sit
+    (platform/kind/count from the arrays' own devices), single-device and
+    sharded — and None for a bank that holds nothing."""
+    import jax
+
+    from gordo_components_tpu.parallel.mesh import fleet_mesh
+
+    models, _ = fleet_models
+    dev = jax.devices()[0]
+    block = {"platform": dev.platform, "kind": dev.device_kind}
+    assert ModelBank.from_models(models, registry=False).coverage()[
+        "device"
+    ] == {**block, "count": 1}
+    sharded = ModelBank.from_models(models, registry=False, mesh=fleet_mesh(4))
+    assert sharded.coverage()["device"] == {**block, "count": 4}
+    assert ModelBank.from_models({}, registry=False).coverage()["device"] is None
+
+
 @pytest.mark.parametrize("name", ["plain", "jax-scaled", "sk-scaled", "wide"])
 def test_bank_scoring_matches_per_model_path(fleet_models, name):
     models, data = fleet_models

@@ -143,20 +143,26 @@ def test_resolve_bank_kernel_mode(monkeypatch):
         banked_anomaly_score(*args, mode="auto")
 
 
-def test_bank_dispatches_kernel_end_to_end():
-    """The bank's compiled bucket program with the kernel in interpreter
-    mode vs the default jnp program: same fp32 parity contract as the
-    raw kernel, through the real ``score_many`` path (chunking, arena,
-    reassembly and all)."""
+@pytest.fixture(scope="module")
+def fitted():
     from gordo_components_tpu.models import AutoEncoder, DiffBasedAnomalyDetector
-    from gordo_components_tpu.server.bank import ModelBank
 
-    rng = np.random.RandomState(0)
-    X = rng.rand(120, 4).astype("float32")
+    X = np.random.RandomState(0).rand(120, 4).astype("float32")
     det = DiffBasedAnomalyDetector(
         base_estimator=AutoEncoder(epochs=1, batch_size=64)
     )
     det.fit(X)
+    return det, X
+
+
+def test_bank_dispatches_kernel_end_to_end(fitted):
+    """The bank's compiled bucket program with the kernel in interpreter
+    mode vs the default jnp program: same fp32 parity contract as the
+    raw kernel, through the real ``score_many`` path (chunking, arena,
+    reassembly and all)."""
+    from gordo_components_tpu.server.bank import ModelBank
+
+    det, X = fitted
     models = {"m": det}
     requests = [("m", X[:37], None), ("m", X[:21], None)]
     jnp_bank = ModelBank.from_models(models, registry=False, bank_kernel="jnp")
@@ -177,3 +183,62 @@ def test_bank_dispatches_kernel_end_to_end():
         np.testing.assert_allclose(
             g.total_unscaled, w.total_unscaled, rtol=NORM_RTOL, atol=NORM_ATOL
         )
+
+
+# ------------------------------------------------------------------ #
+# no silent degrade: `auto` is a pure function of the backend, and a
+# kernel that cannot compile is an error that reaches the caller
+# ------------------------------------------------------------------ #
+
+
+def test_auto_on_tpu_is_pallas_without_compiling(monkeypatch):
+    """With the backend reporting ``tpu``, ``auto`` resolves to the
+    compiled kernel by looking at the backend alone: nothing is
+    probe-compiled, so nothing can quietly decide otherwise."""
+    import jax
+
+    from gordo_components_tpu.ops import pallas_score, seq_scan
+
+    def no_compile(*a, **k):
+        raise AssertionError("auto resolution must not run a kernel")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pallas_score, "_pallas_banked_score", no_compile)
+    monkeypatch.setattr(seq_scan, "fused_lstm_step", no_compile)
+    monkeypatch.delenv("GORDO_BANK_KERNEL", raising=False)
+    monkeypatch.delenv(seq_scan.SEQ_KERNEL_ENV, raising=False)
+    assert resolve_bank_kernel_mode() == "pallas"
+    assert seq_scan.resolve_seq_kernel_mode() == "pallas"
+    assert seq_scan.resolve_seq_layout() == "time_major"
+
+
+def test_auto_kernel_failure_reaches_the_caller(monkeypatch):
+    """``fused_anomaly_score`` in auto mode on a TPU backend runs the
+    compiled kernel and lets its failure out — no fall-back to jnp."""
+    import jax
+
+    from gordo_components_tpu.ops import pallas_score
+
+    def refused(*a, **k):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pallas_score, "_pallas_score", refused)
+    x = np.ones((8, 4), "float32")
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        pallas_score.fused_anomaly_score(
+            x, x, np.zeros(4, "float32"), np.ones(4, "float32")
+        )
+
+
+def test_bank_warmup_compile_failure_propagates(fitted):
+    """A bucket program that cannot compile fails ``warmup`` (here: the
+    compiled kernel has no lowering on the CPU backend) — it is not
+    logged and swallowed."""
+    from gordo_components_tpu.server.bank import ModelBank
+
+    det, _ = fitted
+    bank = ModelBank.from_models({"m": det}, registry=False, bank_kernel="pallas")
+    assert bank.finalize_failures == {}  # finalize builds, warm-up compiles
+    with pytest.raises(Exception):
+        bank.warmup(rows=8)

@@ -236,13 +236,20 @@ class TestBuildFleet:
             for i in range(n)
         ]
 
-    def test_fleet_path_builds_artifacts(self, tmp_path):
-        machines = self._machines(3)
+    @pytest.fixture(scope="class")
+    def built(self, tmp_path_factory):
+        """One three-machine gang build shared by the tests that only
+        read its results."""
+        root = tmp_path_factory.mktemp("fleet-build")
         results = build_fleet(
-            machines,
-            str(tmp_path / "out"),
-            model_register_dir=str(tmp_path / "reg"),
+            self._machines(3),
+            str(root / "out"),
+            model_register_dir=str(root / "reg"),
         )
+        return root, results
+
+    def test_fleet_path_builds_artifacts(self, built):
+        tmp_path, results = built
         assert set(results) == {"machine-0", "machine-1", "machine-2"}
         for name, path in results.items():
             model = serializer.load(path)
@@ -258,6 +265,28 @@ class TestBuildFleet:
             assert model.tags_ == ["a", "b", "c"]
             # mirrored into output_dir for the serving volume
             assert os.path.exists(tmp_path / "out" / name / "model.pkl")
+
+    def test_manifest_carries_device_and_bucket_provenance(self, built):
+        """The manifest says what the trainer resolved and where it ran —
+        read from the trained arrays' own devices — so a parent process
+        that stays off JAX can report its child's device."""
+        import jax
+
+        _, report = built
+        manifest = report.manifest()
+        dev = jax.devices()[0]
+        # the default trainer mesh spans every device of the rig
+        assert manifest["device"] == {
+            "platform": dev.platform,
+            "kind": dev.device_kind,
+            "count": len(jax.devices()),
+        }
+        (bucket,) = manifest["buckets"]
+        assert bucket["model_type"] == "AutoEncoder"
+        assert bucket["n_members"] == 3 and bucket["n_features"] == 3
+        assert bucket["layout"] == "legacy"  # dense buckets never time-major
+        assert bucket["device"] == manifest["device"]
+        assert manifest["gang_width"] == report.gang_width
 
     def test_cache_hit_on_rerun(self, tmp_path):
         machines = self._machines(2)

@@ -30,6 +30,7 @@ from gordo_components_tpu.models import (
 from gordo_components_tpu.observability import MetricsRegistry
 from gordo_components_tpu.observability.cost import (
     CostModel,
+    bucket_cost_row,
     conv1d_autoencoder_flops,
     dense_chain_flops,
     estimate_flops_per_row,
@@ -572,9 +573,11 @@ def test_resolve_peak_flops_env(monkeypatch):
     assert resolve_peak_flops() == (2.5e14, "env")
     monkeypatch.delenv("GORDO_DEVICE_PEAK_FLOPS")
     peak, source = resolve_peak_flops()
-    # CPU dev loop: the assumed fallback keeps the MFU plumbing live,
-    # stamped so nobody mistakes it for a utilization measurement
-    assert peak > 0 and source in ("device", "assumed")
+    # CPU dev loop: a device the spec table does not know has NO peak —
+    # never an assumed one — and the MFU fields derived from it are null
+    assert (peak, source) == (None, "unknown")
+    row = bucket_cost_row(100.0, "analytic", 10, 0, 0.5, 0.0, 0.0, peak)
+    assert row["mfu"] is None and row["mfu_dispatched"] is None
 
 
 # ------------------------------------------------------------------ #
@@ -648,11 +651,17 @@ async def test_skewed_load_heat_ranking_and_watchman_rollup(
 
 
 @pytest.mark.slow
-async def test_costs_mfu_per_bucket_and_watchman_rollup(mixed_arch_dir):
+async def test_costs_mfu_per_bucket_and_watchman_rollup(
+    mixed_arch_dir, monkeypatch
+):
     """`GET /costs` reports a per-bucket MFU for EVERY live bucket
     (mixed dense + LSTM architectures), and watchman's fleet rollup
     reproduces the single replica's body byte-for-byte."""
     from gordo_components_tpu.watchman.server import build_watchman_app
+
+    # the CPU rig's device has no spec-table peak (MFU would be null):
+    # the operator knob supplies one so the MFU plumbing is exercised
+    monkeypatch.setenv("GORDO_DEVICE_PEAK_FLOPS", "1e12")
 
     client = await _serve(mixed_arch_dir)
     try:
@@ -671,7 +680,7 @@ async def test_costs_mfu_per_bucket_and_watchman_rollup(mixed_arch_dir):
             assert b["flops_per_row"] > 0 and b["flops_method"] == "analytic"
             assert b["routed_rows"] > 0 and b["device_s"] > 0
             assert b["mfu"] > 0
-        assert body["peak_source"] in ("env", "device", "assumed")
+        assert body["peak_source"] == "env"
         assert [r["bucket"] for r in body["ranking"]]
 
         base = f"http://{client.server.host}:{client.server.port}"
@@ -693,10 +702,13 @@ async def test_costs_mfu_per_bucket_and_watchman_rollup(mixed_arch_dir):
 
 
 @pytest.mark.slow
-async def test_heat_cost_no_drift_endpoint_stats_registry(hot_cold_dir):
+async def test_heat_cost_no_drift_endpoint_stats_registry(
+    hot_cold_dir, monkeypatch
+):
     """The no-drift contract: between samples, /heat and /costs bodies,
     the /stats embeds, and the registry's gauge values all read the
     SAME cached snapshot."""
+    monkeypatch.setenv("GORDO_DEVICE_PEAK_FLOPS", "1e12")  # MFU gauges live
     client = await _serve(hot_cold_dir)
     try:
         for _ in range(4):

@@ -8,9 +8,9 @@ rounding (documented band, NOT bitwise) — while the jnp-step forward
 matches ``vmap(module.apply)`` exactly and the interpret-mode fused
 kernel matches the jnp step within ULP-level bands like
 tests/test_banked_kernel.py. On this CPU rig ``auto`` resolves the
-layout to ``legacy`` (the speedup is a lane-utilization effect measured
-on TPU — see BENCH_TPU_20260731 and docs/operations.md), so every test
-that exercises the fast path opts in explicitly via ``GORDO_SEQ_LAYOUT``.
+layout to ``legacy`` (the layout is a lane-utilization bet for the TPU
+with no rate on the chip yet — PERF.md), so every test that exercises
+the fast path opts in explicitly via ``GORDO_SEQ_LAYOUT``.
 
 The ``seqperf`` marker forms the `make seqperf` lane; the heavier
 end-to-end legs also carry ``slow`` so tier-1 stays inside its budget.
@@ -92,7 +92,7 @@ def test_resolve_seq_layout(monkeypatch):
 
 def test_resolve_seq_kernel_mode(monkeypatch):
     monkeypatch.delenv(seq_scan.SEQ_KERNEL_ENV, raising=False)
-    # auto off-TPU is the jnp step (never probe-compiles on CPU)
+    # auto off-TPU is the jnp step (a pure function of the backend)
     assert resolve_seq_kernel_mode() == "jnp"
     assert resolve_seq_kernel_mode("interpret") == "interpret"
     assert resolve_seq_kernel_mode("pallas") == "pallas"
@@ -154,12 +154,15 @@ def test_fused_step_interpret_matches_jnp_aligned():
     Wh = jnp.asarray(rng.randn(M, H, 4 * H).astype("float32") * 0.1)
     b = jnp.asarray(rng.randn(M, 4 * H).astype("float32"))
     want_c, want_h = lstm_step_jnp(xz, h, c, Wh, b)
-    got_c, got_h = fused_lstm_step(xz, h, c, Wh, b, interpret=True)
+    # the kernel is member-major (M, B, ·); the jnp step keeps members
+    # second-last — same math, swapped axes
+    mm = lambda a: jnp.swapaxes(a, 0, 1)
+    got_c, got_h = fused_lstm_step(mm(xz), mm(h), mm(c), Wh, b, interpret=True)
     np.testing.assert_allclose(
-        np.asarray(got_c), np.asarray(want_c), rtol=1e-6, atol=1e-6
+        np.asarray(mm(got_c)), np.asarray(want_c), rtol=1e-6, atol=1e-6
     )
     np.testing.assert_allclose(
-        np.asarray(got_h), np.asarray(want_h), rtol=1e-6, atol=1e-6
+        np.asarray(mm(got_h)), np.asarray(want_h), rtol=1e-6, atol=1e-6
     )
 
 

@@ -179,7 +179,9 @@ async def main() -> int:
 
         # ---------------------- advisor: cost ----------------------- #
         print()
-        print(f"DEVICE COST  (peak {costs.get('peak_flops'):.3g} FLOP/s, "
+        peak = costs.get("peak_flops")
+        print(f"DEVICE COST  (peak "
+              f"{(f'{peak:.3g} FLOP/s' if peak else 'unknown')}, "
               f"source={costs.get('peak_source')})")
         print(f"  {'bucket':<34} {'mfu':>10} {'flops/row':>12} "
               f"{'dev_s/1k':>10} {'pad_waste':>10}")
@@ -224,7 +226,9 @@ async def main() -> int:
             and hottest == sorted(HOT)
             and costs.get("enabled") is True
             and len(live) >= 2
-            and all(row.get("mfu") is not None for row in live.values())
+            # MFU is null where the device's peak is unknown (a CPU run):
+            # the attribution itself is what must be live
+            and all(row.get("device_s", 0) > 0 for row in live.values())
             and bool(advice)
         )
         doc = {
