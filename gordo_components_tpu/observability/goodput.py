@@ -53,13 +53,19 @@ from gordo_components_tpu.observability.metrics import (
     LATENCY_BINS_PER_DECADE,
     Histogram,
 )
+from gordo_components_tpu.observability.tracing import union_length
 
 __all__ = ["GoodputLedger", "STAGES", "attribute_trace"]
 
-# the span stages wall time attributes across (docs/observability.md's
+# the span stages wall time attributes across: a request's top-level
+# spans, in the order it passes through them (docs/observability.md's
 # span-name stability contract); "other" is the residual attribute_trace
-# reports for time no named stage covers (parse, response write, ...)
-STAGES = ("queue_wait", "coalesce", "pad", "device_execute", "postprocess")
+# reports for time no named stage covers (the middleware's own work, the
+# response write)
+STAGES = (
+    "parse", "admit", "queue_wait", "handoff", "coalesce", "pad",
+    "device_execute", "postprocess", "resolve", "encode",
+)
 
 _ENV_ENABLE = "GORDO_SLO"
 
@@ -424,22 +430,6 @@ def _flatten_spans(node: Dict[str, Any], out: List[Dict[str, Any]]) -> None:
         _flatten_spans(child, out)
 
 
-def _merged_len(intervals: List[Tuple[float, float]]) -> float:
-    """Total length of the union of [start, end) intervals."""
-    if not intervals:
-        return 0.0
-    intervals.sort()
-    total = 0.0
-    cur_s, cur_e = intervals[0]
-    for s, e in intervals[1:]:
-        if s > cur_e:
-            total += cur_e - cur_s
-            cur_s, cur_e = s, e
-        else:
-            cur_e = max(cur_e, e)
-    return total + (cur_e - cur_s)
-
-
 def attribute_trace(trace) -> Dict[str, Any]:
     """Attribute one request's wall time across the stage spans.
 
@@ -449,7 +439,8 @@ def attribute_trace(trace) -> Dict[str, Any]:
     "coverage"}`` where per-stage time is the union of that stage's
     intervals (a multi-chunk request records several spans per stage;
     overlaps must not double-count), ``other`` is the residual no named
-    stage covers (request parse, response write, ...), and ``coverage``
+    stage covers (the middleware's own work, the response write), and
+    ``coverage``
     is the named-stage share of the wall. The acceptance contract
     (tests/test_goodput.py): the attribution sums to within 5% of the
     request's wall time."""
@@ -473,9 +464,9 @@ def attribute_trace(trace) -> Dict[str, Any]:
         by_stage[name].append((start, end))
         all_intervals.append((start, end))
     stages_ms = {
-        stage: round(_merged_len(list(iv)), 3) for stage, iv in by_stage.items()
+        stage: round(union_length(iv), 3) for stage, iv in by_stage.items()
     }
-    covered = _merged_len(all_intervals)
+    covered = union_length(all_intervals)
     stages_ms["other"] = round(max(0.0, wall_ms - covered), 3)
     return {
         "wall_ms": round(wall_ms, 3),

@@ -1,20 +1,24 @@
-"""Dependency-free request tracing: spans, W3C context, Chrome export.
+"""Request and fit tracing: spans, W3C context, Chrome export, and the
+program's stages in the profiler's trace.
 
 The metrics registry (observability/metrics.py) answers "how is the
 fleet doing"; this module answers "where did THIS request's 200 ms go".
 A :class:`Tracer` produces request-scoped :class:`Trace` objects whose
 :class:`Span` records carry monotonic timestamps, so the serving hot
-path (`queue_wait` -> `coalesce` -> `pad` -> `device_execute` ->
-`postprocess`) and the builder (`fit`/`compile`/`checkpoint` per bucket)
-become a per-request timeline instead of one histogram bucket.
+path (`parse` -> `admit` -> `queue_wait` -> `handoff` -> `coalesce` ->
+`pad` -> `device_execute` -> `postprocess` -> `resolve` -> `encode`)
+and a fit (`fit:<bucket>` over the trainer's stages) become a timeline
+instead of one histogram bucket.
 
 Design rules, mirroring the metrics layer:
 
 - **Hot-path safe** — a disabled tracer (``GORDO_TRACE_SAMPLE=0``)
   returns ``None`` from ``start_trace`` and every call site guards on
-  that one reference; recording a span is two ``time.monotonic()`` reads
-  and a ``list.append`` (atomic under the GIL, so spans may be appended
-  from the scoring executor thread while the event loop owns the trace).
+  that one reference (a :class:`stage` skips it); recording a span is
+  two ``time.monotonic()`` reads and a ``list.append`` (atomic under the
+  GIL, so spans may be appended from the scoring executor thread while
+  the event loop owns the trace). The requests of one coalesced batch
+  hold ONE span object per shared stage (:func:`group_span`).
 - **W3C context propagation** — ``traceparent`` headers
   (``00-<32hex trace-id>-<16hex span-id>-<2hex flags>``) parse on the
   way in and format on the way out, so the client -> server -> engine ->
@@ -32,6 +36,15 @@ Design rules, mirroring the metrics layer:
 - **Chrome trace-event export** — ``chrome_trace(traces)`` emits the
   Trace Event Format JSON (``ph: "X"`` complete events, microsecond
   ``ts``/``dur``) that ``chrome://tracing`` and Perfetto open directly.
+- **One primitive for work the host does** — :class:`stage` records a
+  span and a ``jax.profiler.TraceAnnotation`` (``gordo:<name>``) from the
+  same two clock reads, so an open profiler session shows the program's
+  stages beside the XLA ops they launched. Spans whose boundaries are
+  observed across an ``await`` keep :meth:`Trace.add_span` and get no
+  annotation (a profiler region must not straddle an ``await``). JAX's
+  own compile timings (``jax.monitoring``) land as ``trace_lower`` /
+  ``backend_compile`` / ``cache_load`` spans on the current trace. JAX
+  is imported by the first :class:`stage`, not by this module.
 
 Span names are a stability contract like metric names — see
 docs/observability.md ("Tracing").
@@ -54,10 +67,14 @@ __all__ = [
     "Trace",
     "Tracer",
     "chrome_trace",
+    "covered_seconds",
     "current_trace",
     "format_traceparent",
     "get_tracer",
+    "group_span",
     "parse_traceparent",
+    "stage",
+    "union_length",
     "use_trace",
 ]
 
@@ -114,27 +131,39 @@ class Span:
     created open (``end is None``) and closed later, or recorded whole
     with explicit timestamps (``Trace.add_span``) when the boundary
     events were measured elsewhere — the engine's ``queue_wait`` is
-    enqueue -> dispatch, both observed before the span object exists."""
+    enqueue -> dispatch, both observed before the span object exists.
 
-    __slots__ = ("name", "span_id", "parent_id", "start", "end", "error", "attributes")
+    ``parent`` is the span it hangs under; ``None`` is the root of
+    whichever trace holds it. That is what lets ONE span object stand in
+    every traced request of a coalesced group (:func:`group_span`): the
+    group's stages have the same boundaries for all of them. ``span_id``
+    is minted when first read (export, ``traceparent``), not on the hot
+    path."""
+
+    __slots__ = ("name", "parent", "start", "end", "error", "attributes", "_span_id")
 
     def __init__(
         self,
         name: str,
-        span_id: str,
-        parent_id: Optional[str],
+        parent: Optional["Span"],
         start: float,
         end: Optional[float] = None,
         error: bool = False,
         attributes: Optional[Dict[str, Any]] = None,
     ):
         self.name = name
-        self.span_id = span_id
-        self.parent_id = parent_id
+        self.parent = parent
         self.start = start
         self.end = end
         self.error = error
         self.attributes = attributes or {}
+        self._span_id: Optional[str] = None
+
+    @property
+    def span_id(self) -> str:
+        if self._span_id is None:
+            self._span_id = _new_span_id()
+        return self._span_id
 
     @property
     def duration_s(self) -> float:
@@ -145,6 +174,25 @@ class Span:
             self.end = time.monotonic()
         if error:
             self.error = True
+
+
+def group_span(
+    name: str,
+    traces: Iterable[Optional["Trace"]],
+    start: float,
+    end: Optional[float] = None,
+    parent: Optional[Span] = None,
+    error: bool = False,
+    **attributes: Any,
+) -> Span:
+    """One span held by every trace given (``None`` entries skipped):
+    what the requests of one coalesced batch share. ``end=None`` leaves
+    it open; set ``end`` (or ``close()``) once, for all of them."""
+    span = Span(name, parent, start, end, error, attributes or None)
+    for trace in traces:
+        if trace is not None:
+            trace.spans.append(span)
+    return span
 
 
 class Trace:
@@ -192,7 +240,7 @@ class Trace:
         # is True, or they dangle on a head-sample drop
         self.retained = False
         self.wall_start = time.time()
-        self.root = Span(name, _new_span_id(), None, time.monotonic())
+        self.root = Span(name, None, time.monotonic())
         self.spans: List[Span] = [self.root]
         self._finished = False
 
@@ -203,13 +251,7 @@ class Trace:
     ) -> Span:
         """Open a span now; close it with ``span.close()``. Parent
         defaults to the root."""
-        span = Span(
-            name,
-            _new_span_id(),
-            (parent or self.root).span_id,
-            time.monotonic(),
-            attributes=attributes or None,
-        )
+        span = Span(name, parent, time.monotonic(), attributes=attributes or None)
         self.spans.append(span)
         return span
 
@@ -224,15 +266,7 @@ class Trace:
     ) -> Span:
         """Record a completed span from boundary timestamps measured
         elsewhere (monotonic seconds)."""
-        span = Span(
-            name,
-            _new_span_id(),
-            (parent or self.root).span_id,
-            start,
-            end=max(start, end),
-            error=error,
-            attributes=attributes or None,
-        )
+        span = Span(name, parent, start, max(start, end), error, attributes or None)
         self.spans.append(span)
         return span
 
@@ -280,7 +314,7 @@ class Trace:
     def error(self) -> bool:
         return any(s.error for s in self.spans)
 
-    def _span_dict(self, span: Span, children: Dict[Optional[str], List[Span]]) -> dict:
+    def _span_dict(self, span: Span, children: Dict[int, List[Span]]) -> dict:
         out: Dict[str, Any] = {
             "name": span.name,
             "span_id": span.span_id,
@@ -291,25 +325,37 @@ class Trace:
             out["error"] = True
         if span.attributes:
             out["attributes"] = dict(span.attributes)
-        kids = children.get(span.span_id)
+        kids = children.get(id(span))
         if kids:
             out["children"] = [self._span_dict(k, children) for k in kids]
         return out
 
+    def children(self, parent: Optional[Span] = None) -> List[Span]:
+        """The spans directly under ``parent`` (default: the root), by
+        start time."""
+        if parent is self.root:
+            parent = None
+        return sorted(
+            (s for s in self.spans if s.parent is parent and s is not self.root),
+            key=lambda s: s.start,
+        )
+
     def tree(self) -> dict:
         """Nested span tree (children sorted by start time)."""
-        children: Dict[Optional[str], List[Span]] = {}
+        held = {id(s) for s in self.spans}
+        children: Dict[int, List[Span]] = {}
         for span in self.spans:
-            if span is not self.root:
-                children.setdefault(span.parent_id, []).append(span)
+            if span is self.root:
+                continue
+            parent = span.parent
+            # top-level spans, and orphans (their parent was never
+            # registered in this trace) re-root so they stay visible
+            # rather than silently vanishing from the tree
+            if parent is None or id(parent) not in held:
+                parent = self.root
+            children.setdefault(id(parent), []).append(span)
         for kids in children.values():
             kids.sort(key=lambda s: s.start)
-        # orphans (parent span object never registered) re-root so they
-        # stay visible rather than silently vanishing from the tree
-        known = {s.span_id for s in self.spans}
-        for pid in list(children):
-            if pid not in known:
-                children.setdefault(self.root.span_id, []).extend(children.pop(pid))
         return self._span_dict(self.root, children)
 
     def summary(self, spans: bool = True) -> dict:
@@ -325,6 +371,23 @@ class Trace:
         if spans:
             out["spans"] = self.tree()
         return out
+
+
+def union_length(intervals: Iterable[Tuple[float, float]]) -> float:
+    """Total length of the union of ``(start, end)`` intervals: nested or
+    overlapping ones count once."""
+    total, edge = 0.0, float("-inf")
+    for start, end in sorted(intervals):
+        if end > edge:
+            total += end - max(start, edge)
+            edge = end
+    return total
+
+
+def covered_seconds(spans: Iterable[Span]) -> float:
+    """Seconds the (closed) spans cover between them; JAX reports an outer
+    function's tracing over its callees', and those count once."""
+    return union_length((s.start, s.end) for s in spans if s.end is not None)
 
 
 def chrome_trace(traces: Iterable[Trace]) -> dict:
@@ -523,6 +586,11 @@ def get_tracer() -> Tracer:
 _CURRENT: "contextvars.ContextVar[Optional[Trace]]" = contextvars.ContextVar(
     "gordo_current_trace", default=None
 )
+# the span new children of the current trace hang under (the trainer's
+# open ``fit:<bucket>``); None = the root
+_CURRENT_PARENT: "contextvars.ContextVar[Optional[Span]]" = contextvars.ContextVar(
+    "gordo_current_parent", default=None
+)
 
 
 def current_trace() -> Optional[Trace]:
@@ -530,9 +598,130 @@ def current_trace() -> Optional[Trace]:
 
 
 @contextlib.contextmanager
-def use_trace(trace: Optional[Trace]):
+def use_trace(trace: Optional[Trace], parent: Optional[Span] = None):
     token = _CURRENT.set(trace)
+    parent_token = _CURRENT_PARENT.set(parent)
     try:
         yield trace
     finally:
+        _CURRENT_PARENT.reset(parent_token)
         _CURRENT.reset(token)
+
+
+# ------------------------------------------------------------------ #
+# stages: one span and one profiler annotation from the same clock reads
+# ------------------------------------------------------------------ #
+
+ANNOTATION_PREFIX = "gordo:"
+
+# JAX's own duration events -> span names on the current trace. Both
+# tracing events read as ``trace_lower``; ``cache_load`` (a persistent
+# cache hit) falls inside the ``backend_compile`` that asked for it.
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_lower",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "trace_lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+# JAX reports every eager primitive's first trace too (tens of
+# microseconds each, hundreds in a process's first fit): noise in a tree
+# whose job is to say which program recompiled
+_MIN_COMPILE_SPAN_S = 1e-3
+
+_annotation: Any = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _on_jax_duration(event: str, duration_secs: float, **kwargs: Any) -> None:
+    """``jax.monitoring`` listener: JAX reports a duration when the work
+    ends, on the thread that did it, so the span is ``[now - d, now]`` on
+    that thread's current trace. No current trace, nothing recorded."""
+    name = _COMPILE_SPANS.get(event)
+    if name is None or duration_secs < _MIN_COMPILE_SPAN_S:
+        return
+    trace = _CURRENT.get()
+    if trace is None or trace.finished:
+        return
+    end = time.monotonic()
+    trace.add_span(
+        name, end - duration_secs, end, parent=_CURRENT_PARENT.get(), **kwargs
+    )
+
+
+def _bind_jax():
+    """First :class:`stage` of the process: import JAX's profiler (this
+    module stays importable without JAX) and register the compile
+    listener, once."""
+    global _annotation
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
+class stage:
+    """``with stage("pad", *traces):`` — one stage of the program's work.
+
+    Opens ``jax.profiler.TraceAnnotation("gordo:" + name)`` (a no-op in
+    the runtime unless a profiler session is open) and, on exit, appends
+    one span ``name`` with the same two ``time.monotonic()`` reads to each
+    trace given (``None`` entries are skipped; no trace at all is fine).
+    Always on: there is no flag. ``start``/``end``/``seconds`` stay
+    readable after the block for callers that account the same interval
+    elsewhere (the goodput ledger, ``epoch_seconds``).
+
+    A span is the HOST's time in the stage: nothing is fenced, so a stage
+    that launches device work asynchronously ends when the launch
+    returns, and the annotation puts it beside the device ops it
+    launched. Never wrap an ``await``: a profiler region must not
+    straddle one (record such a span with :meth:`Trace.add_span`).
+
+    ``parent``: the span the new one hangs under (default: each trace's
+    root). One span object is held by every trace given
+    (:func:`group_span`). Keyword arguments become span attributes, and
+    ``attributes`` may be amended inside the block. The span is flagged
+    as an error when the block raises, or when the block sets ``error``
+    (a failure it handled itself)."""
+
+    __slots__ = (
+        "name", "traces", "parent", "attributes", "error", "start", "end",
+        "_region",
+    )
+
+    def __init__(
+        self,
+        name: str,
+        *traces: Optional[Trace],
+        parent: Optional[Span] = None,
+        **attributes: Any,
+    ):
+        self.name = name
+        self.traces = traces
+        self.parent = parent
+        self.attributes = attributes
+        self.error = False
+        self._region = (_annotation or _bind_jax())(ANNOTATION_PREFIX + name)
+
+    def __enter__(self) -> "stage":
+        self._region.__enter__()
+        self.start = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.end = time.monotonic()
+        self._region.__exit__(exc_type, exc, tb)
+        if self.traces:
+            group_span(
+                self.name,
+                self.traces,
+                self.start,
+                self.end,
+                self.parent,
+                self.error or exc_type is not None,
+                **self.attributes,
+            )
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start

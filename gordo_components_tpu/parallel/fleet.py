@@ -37,7 +37,13 @@ import numpy as np
 from gordo_components_tpu.models import train_core
 from gordo_components_tpu.models.register import lookup_factory
 from gordo_components_tpu.observability import get_registry
-from gordo_components_tpu.observability.tracing import current_trace
+from gordo_components_tpu.observability.tracing import (
+    covered_seconds,
+    current_trace,
+    get_tracer,
+    stage,
+    use_trace,
+)
 from gordo_components_tpu.ops.seq_scan import (
     resolve_seq_layout,
     supports_time_major,
@@ -889,21 +895,18 @@ class FleetTrainer:
         self.quantize_members = bool(quantize_members)
         self.factory_kwargs = factory_kwargs
         self.last_stats: Dict[str, Any] = {}
-        # (trace, open fit span) for the bucket currently training — the
-        # checkpoint writer nests its spans under it (observability/tracing)
-        self._trace_span: Optional[Tuple[Any, Any]] = None
+        # (trace, open ``fit:<bucket>`` span) of the bucket in training:
+        # its stages nest under that span (observability/tracing)
+        self._trace_span: Tuple[Any, Any] = (None, None)
 
-    def _trace_checkpoint(self, start: float, epoch: int, error: bool = False) -> None:
-        """Record one checkpoint save as a span under the active bucket's
-        ``fit`` span; no-op outside a build trace."""
-        ts = self._trace_span
-        if ts is None:
-            return
-        trace, fit_span = ts
-        trace.add_span(
-            "checkpoint", start, time.monotonic(), parent=fit_span,
-            epoch=int(epoch), error=error,
-        )
+    def _stage(self, name: str, **attributes: Any) -> stage:
+        """One stage of the bucket in training: a span under its
+        ``fit:<bucket>`` and a ``gordo:<name>`` profiler annotation. No
+        fence is added anywhere: a span is the HOST's time in the stage
+        (for ``epoch``, up to the blocking read of the losses), and the
+        annotation puts it beside the device ops it launched."""
+        trace, fit_span = self._trace_span
+        return stage(name, trace, parent=fit_span, **attributes)
 
     # ------------------------------------------------------------------ #
 
@@ -934,7 +937,37 @@ class FleetTrainer:
         of training from scratch (the streaming plane's incremental
         refit). Trees must match the gang's architecture exactly; a
         structure or shape mismatch fails fast naming the member.
+
+        A fit is a trace (observability/tracing.py): it records into the
+        caller's (``build_fleet`` opens one per build), else into a
+        ``fleet_fit`` trace of its own on the process tracer, always
+        retained — a fit is rare and long; head sampling is for request
+        volume. Every bucket is a ``fit:<bucket>`` span over its stages
+        (:meth:`_stage`), ``checkpoint`` saves and the compiles JAX
+        reports inside it.
         """
+        trace = current_trace()
+        own = None
+        if trace is None:
+            # None again with tracing off: the stages still annotate
+            trace = own = get_tracer().start_trace("fleet_fit", force=True)
+        try:
+            out = self._fit(trace, members, member_hparams, initial_params)
+        except BaseException:
+            if own is not None:
+                own.finish(error=True)
+            raise
+        if own is not None:
+            own.finish(members=len(members))
+        return out
+
+    def _fit(
+        self,
+        trace,
+        members: Dict[str, np.ndarray],
+        member_hparams: Optional[Dict[str, Dict[str, Any]]],
+        initial_params: Optional[Dict[str, Any]],
+    ) -> Dict[str, FleetMemberModel]:
         t0 = time.time()
         # fleet-build progress, published to the process metrics registry
         # (observability/): a gang builder has no HTTP surface, but bench
@@ -1027,11 +1060,6 @@ class FleetTrainer:
         bucket_stats = []
         self._g_members_total.set(len(members))
         self._g_members_trained.set(0)
-        # build-trace context (observability/tracing.py): when the caller
-        # (build_fleet) opened a trace, every bucket records a ``fit``
-        # span with ``compile``/``checkpoint`` children — the builder-side
-        # counterpart of the serving stage spans
-        trace = current_trace()
         for (n_features, padded_rows), names in work:
             tb = time.time()
             blabel = f"f{n_features}x{padded_rows}"
@@ -1041,11 +1069,14 @@ class FleetTrainer:
                 fit_span = trace.start_span(
                     f"fit:{blabel}", bucket=blabel, members=len(names)
                 )
-                self._trace_span = (trace, fit_span)
+            self._trace_span = (trace, fit_span)
             try:
-                res, epoch_seconds, padded_m = self._fit_bucket(
-                    n_features, padded_rows, names, arrays
-                )
+                # use_trace: what JAX traces, compiles or loads from its
+                # cache inside this bucket lands under its fit span
+                with use_trace(trace, fit_span):
+                    res, epoch_seconds, padded_m = self._fit_bucket(
+                        n_features, padded_rows, names, arrays
+                    )
             except BaseException:
                 # commit (best-effort) and release the async checkpoint
                 # writer: the pending save is complete training state, so
@@ -1064,31 +1095,22 @@ class FleetTrainer:
                 raise
             finally:
                 self._active_ckpt = None
-                self._trace_span = None
+                self._trace_span = (None, None)
             out.update(res)
             self._g_members_trained.set(len(out))
-            # per-bucket compile visibility: epoch 0 carries the XLA
-            # compile (bucket_stats records the same split); the gauge
-            # makes it scrapeable/snapshotable without parsing metadata
+            # per-bucket compile visibility, measured: the seconds JAX
+            # itself reported for tracing, lowering and compiling (or
+            # loading from the persistent cache) inside this bucket. The
+            # spans say which call recompiled. 0 with tracing off.
             compile_s = 0.0
-            if epoch_seconds:
-                steady = min(epoch_seconds[1:]) if len(epoch_seconds) > 1 else 0.0
-                compile_s = max(0.0, epoch_seconds[0] - steady)
             if fit_span is not None:
                 fit_span.attributes["epochs"] = len(epoch_seconds)
                 fit_span.close()
-                if compile_s > 0:
-                    # the compile window is epoch 0's excess over steady
-                    # state — an ESTIMATE anchored at bucket start, and
-                    # flagged as such
-                    trace.add_span(
-                        "compile",
-                        fit_span.start,
-                        fit_span.start + compile_s,
-                        parent=fit_span,
-                        bucket=blabel,
-                        estimated=True,
-                    )
+                compile_s = covered_seconds(
+                    s
+                    for s in trace.children(fit_span)
+                    if s.name in ("trace_lower", "backend_compile")
+                )
             reg.counter(
                 "gordo_fleet_bucket_builds_total",
                 "Bucket training runs", ("bucket",),
@@ -1099,7 +1121,8 @@ class FleetTrainer:
             ).labels(blabel).inc(len(epoch_seconds))
             reg.gauge(
                 "gordo_fleet_bucket_compile_seconds",
-                "Estimated XLA compile seconds (epoch 0 minus steady state)",
+                "Seconds JAX reported tracing, lowering and compiling in the "
+                "bucket's last fit",
                 ("bucket",),
             ).labels(blabel).set(round(compile_s, 3))
             bucket_stats.append(
@@ -1117,8 +1140,9 @@ class FleetTrainer:
                     # means a shared XLA program
                     "padded_members": padded_m,
                     "seconds": time.time() - tb,
-                    # structured per-epoch timing: epoch 0 includes the XLA
-                    # compile, steady-state is the rest
+                    # per-epoch host seconds (the ``epoch`` spans): a
+                    # process's first epochs include tracing and the
+                    # compile or cache load, steady-state is the rest
                     "epoch_seconds": epoch_seconds,
                     # which sequence layout the bucket's epoch program used
                     # ("time_major" = gang scan, members innermost;
@@ -1163,316 +1187,320 @@ class FleetTrainer:
         # ---- stack + pad host-side (the one unavoidable host loop;
         # multithreaded C++ when the native lib is available, with dummies
         # replicating real members for mesh padding either way) ----
-        from gordo_components_tpu.native import fleet_stack_pad
+        with self._stage("stack_pad"):
+            from gordo_components_tpu.native import fleet_stack_pad
 
-        Xs, masks = fleet_stack_pad(
-            [arrays[n] for n in names], M, padded_rows, n_features
-        )
-
-        sharding = shard_model_axis(mesh)
-        Xd = jax.device_put(jnp.asarray(Xs), sharding)
-        maskd = jax.device_put(jnp.asarray(masks), sharding)
-
-        # ---- per-member train/validation masks in ITEM space (items ==
-        # rows for the dense family, window starts for sequences): the LAST
-        # int(items*split) real items of each member are holdout — exactly
-        # BaseEstimator.fit's split over the (windowed) training units.
-        # Input/error scalers keep the FULL row mask (the single-model
-        # pipeline's scaler also fits before the estimator's internal
-        # split). Members whose split floors to 0 val items monitor train
-        # loss, like a single build with n_val == 0. ----
-        use_val = self.validation_split > 0.0
-        # mesh-padding dummy slots replicate real members CYCLICALLY
-        # (fleet_stack_pad uses i % n), so their masks must use the row
-        # count of the member whose data they actually hold
-        n_rows = np.array(
-            [arrays[names[i % M_real]].shape[0] for i in range(M)]
-        )
-        n_items = n_rows - warmup
-        item_idx = np.arange(padded_items)[None, :]
-        item_mask_np = (item_idx < n_items[:, None]).astype(np.float32)
-        item_maskd = jax.device_put(jnp.asarray(item_mask_np), sharding)
-        n_val = (n_items * self.validation_split).astype(np.int64)
-        n_train = n_items - n_val
-        has_val = n_val > 0
-        if use_val:
-            train_mask = (item_idx < n_train[:, None]).astype(np.float32)
-            vmask_np = (
-                (item_idx >= n_train[:, None]) & (item_idx < n_items[:, None])
-            ).astype(np.float32)
-            train_maskd = jax.device_put(jnp.asarray(train_mask), sharding)
-            val_maskd = jax.device_put(jnp.asarray(vmask_np), sharding)
-        else:
-            train_maskd = item_maskd
-            val_maskd = jax.device_put(
-                jnp.zeros((M, padded_items), jnp.float32), sharding
+            Xs, masks = fleet_stack_pad(
+                [arrays[n] for n in names], M, padded_rows, n_features
             )
 
-        # ---- per-member scalers, fitted on device (masked rows excluded
-        # by writing NaNs, which the nan-aware fit ignores) ----
-        scalers = _fit_scalers(Xd, maskd, self.input_scaler)
-        Xd = _transform_all(scalers, Xd)
-        # padded rows were NaN-protected during fit; re-zero them post-scale
-        Xd = jnp.where(maskd[..., None] > 0, Xd, 0.0)
+        with self._stage("to_device"):
+            sharding = shard_model_axis(mesh)
+            Xd = jax.device_put(jnp.asarray(Xs), sharding)
+            maskd = jax.device_put(jnp.asarray(masks), sharding)
 
-        # ---- build module + stacked train state (programs are cached
-        # process-wide per (module, optimizer, batch size, seq)) ----
-        factory = lookup_factory(self.model_type, self.kind)
-        module = factory(
-            n_features, compute_dtype=self.compute_dtype, **self.factory_kwargs
-        )
-        loss = self.loss
-        if loss == "auto":  # parity with BaseEstimator._resolved_loss
-            loss = "vae" if hasattr(module, "elbo_terms") else "mse"
-        progs = _bucket_programs(
-            module, self.optimizer, self.learning_rate,
-            min(bs, padded_items), seq, loss, self.kl_weight,
-            self.threshold_quantile,
-        )
-        self._bucket_layout = progs.layout
-        self._bucket_device = device_block(Xd)
-        init_stacked = progs.init_stacked
-        run_epoch = progs.run_epoch
-
-        rngs = jax.random.split(jax.random.PRNGKey(self.seed), M)
-        # shape-inference sample: one row (dense) or one window (sequence)
-        sample = Xd[:, 0, :] if seq is None else Xd[:, : self.lookback_window, :]
-        states = init_stacked(rngs, sample)
-
-        # ---- warm start (incremental refit): overwrite the stacked init's
-        # member rows with the provided serving weights. Mesh-padding
-        # dummies replicate their source member's warm leaves (i % M_real),
-        # like the data; the optimizer state stays freshly initialized ----
-        warm = getattr(self, "_initial_params", None) or {}
-        if any(names[i % M_real] in warm for i in range(M)):
-            host = jax.tree.map(np.array, states.params)
-            treedef = jax.tree.structure(host)
-            leaves = jax.tree.leaves(host)
-            warm_leaves: Dict[str, List[np.ndarray]] = {}
-            for name in set(names) & set(warm):
-                tree = jax.tree.map(np.asarray, warm[name])
-                if jax.tree.structure(tree) != treedef:
-                    raise ValueError(
-                        f"initial_params[{name!r}]: tree structure does not "
-                        "match this gang's architecture"
-                    )
-                wl = jax.tree.leaves(tree)
-                for li, leaf in enumerate(leaves):
-                    if wl[li].shape != leaf.shape[1:]:
-                        raise ValueError(
-                            f"initial_params[{name!r}]: leaf {li} shape "
-                            f"{wl[li].shape} != expected {leaf.shape[1:]}"
-                        )
-                warm_leaves[name] = wl
-            for i in range(M):
-                wl = warm_leaves.get(names[i % M_real])
-                if wl is None:
-                    continue
-                for li, leaf in enumerate(leaves):
-                    leaf[i] = wl[li]
-            states = states._replace(
-                params=jax.tree.unflatten(
-                    treedef,
-                    [
-                        jax.device_put(jnp.asarray(leaf), sharding)
-                        for leaf in leaves
-                    ],
+            # ---- per-member train/validation masks in ITEM space (items ==
+            # rows for the dense family, window starts for sequences): the LAST
+            # int(items*split) real items of each member are holdout — exactly
+            # BaseEstimator.fit's split over the (windowed) training units.
+            # Input/error scalers keep the FULL row mask (the single-model
+            # pipeline's scaler also fits before the estimator's internal
+            # split). Members whose split floors to 0 val items monitor train
+            # loss, like a single build with n_val == 0. ----
+            use_val = self.validation_split > 0.0
+            # mesh-padding dummy slots replicate real members CYCLICALLY
+            # (fleet_stack_pad uses i % n), so their masks must use the row
+            # count of the member whose data they actually hold
+            n_rows = np.array(
+                [arrays[names[i % M_real]].shape[0] for i in range(M)]
+            )
+            n_items = n_rows - warmup
+            item_idx = np.arange(padded_items)[None, :]
+            item_mask_np = (item_idx < n_items[:, None]).astype(np.float32)
+            item_maskd = jax.device_put(jnp.asarray(item_mask_np), sharding)
+            n_val = (n_items * self.validation_split).astype(np.int64)
+            n_train = n_items - n_val
+            has_val = n_val > 0
+            if use_val:
+                train_mask = (item_idx < n_train[:, None]).astype(np.float32)
+                vmask_np = (
+                    (item_idx >= n_train[:, None]) & (item_idx < n_items[:, None])
+                ).astype(np.float32)
+                train_maskd = jax.device_put(jnp.asarray(train_mask), sharding)
+                val_maskd = jax.device_put(jnp.asarray(vmask_np), sharding)
+            else:
+                train_maskd = item_maskd
+                val_maskd = jax.device_put(
+                    jnp.zeros((M, padded_items), jnp.float32), sharding
                 )
+
+        with self._stage("scaler_fit"):
+            # ---- per-member scalers, fitted on device (masked rows excluded
+            # by writing NaNs, which the nan-aware fit ignores) ----
+            scalers = _fit_scalers(Xd, maskd, self.input_scaler)
+            Xd = _transform_all(scalers, Xd)
+            # padded rows were NaN-protected during fit; re-zero them post-scale
+            Xd = jnp.where(maskd[..., None] > 0, Xd, 0.0)
+
+        with self._stage("init_state"):
+            # ---- build module + stacked train state (programs are cached
+            # process-wide per (module, optimizer, batch size, seq)) ----
+            factory = lookup_factory(self.model_type, self.kind)
+            module = factory(
+                n_features, compute_dtype=self.compute_dtype, **self.factory_kwargs
             )
-
-        # ---- per-member hyperparameter vectors (mesh-padding dummies
-        # replicate their source member's values, like the data) ----
-        hparams = getattr(self, "_member_hparams", {})
-
-        def _mvec(key, base, dtype):
-            return np.array(
-                [
-                    hparams.get(names[i % M_real], {}).get(key, base)
-                    for i in range(M)
-                ],
-                dtype=dtype,
+            loss = self.loss
+            if loss == "auto":  # parity with BaseEstimator._resolved_loss
+                loss = "vae" if hasattr(module, "elbo_terms") else "mse"
+            progs = _bucket_programs(
+                module, self.optimizer, self.learning_rate,
+                min(bs, padded_items), seq, loss, self.kl_weight,
+                self.threshold_quantile,
             )
+            self._bucket_layout = progs.layout
+            self._bucket_device = device_block(Xd)
+            init_stacked = progs.init_stacked
+            run_epoch = progs.run_epoch
 
-        lr_vec = _mvec("learning_rate", self.learning_rate, np.float32)
-        if hparams:
-            # the injected opt state carries learning_rate as a stacked
-            # (M,) leaf (vmapped init broadcasts the base scalar):
-            # overwrite it with the per-member vector — the ONLY surgery
-            # per-member LR needs, no extra program or gang split
-            states = _set_stacked_lr(states, lr_vec)
-        state_treedef = jax.tree.structure(states)
+            rngs = jax.random.split(jax.random.PRNGKey(self.seed), M)
+            # shape-inference sample: one row (dense) or one window (sequence)
+            sample = Xd[:, 0, :] if seq is None else Xd[:, : self.lookback_window, :]
+            states = init_stacked(rngs, sample)
 
-        # ---- epoch loop: device does the work; host only sees (M,) losses
-        # and drives per-model early stopping ----
-        active = np.ones((M,), dtype=np.float32)
-        best = np.full((M,), np.inf)
-        es_enabled = self.early_stopping_patience is not None
-        # patience RESET values, per member (scalar broadcast when no
-        # overrides): both the host ES loop and the chunked device ES use
-        # this vector, so per-member patience is free in either path
-        p0_vec = (
-            _mvec("early_stopping_patience", self.early_stopping_patience, np.int64)
-            if es_enabled
-            else np.full((M,), -1, dtype=np.int64)
-        )
-        patience = p0_vec.copy()
-        histories: List[List[float]] = [[] for _ in range(M)]
-        histories_val: List[List[float]] = [[] for _ in range(M)]
-
-        # best-params restore, matching BaseEstimator.fit: each member ends
-        # on the params of its best epoch, not the epoch it stopped at
-        best_params = None
-
-        # ---- preemption recovery: resume a matching interrupted run ----
-        ckpt = None
-        start_epoch = 0
-        if self.checkpoint_dir:
-            from gordo_components_tpu.parallel.checkpoint import (
-                FleetBucketCheckpoint,
-                bucket_checkpoint_key,
-            )
-
-            key = bucket_checkpoint_key(
-                [
-                    self.model_type,
-                    # lookback only shapes sequence programs; keying it for
-                    # the dense family would invalidate resumable dense
-                    # checkpoints whenever its (unused) default shifts
-                    self.lookback_window if seq is not None else None,
-                    self.kind,
-                    sorted(self.factory_kwargs.items()),
-                    self.compute_dtype,
-                    self.input_scaler,
-                    loss,
-                    self.kl_weight,
-                    n_features,
-                    padded_rows,
-                    list(names),
-                    self.epochs,
-                    self.batch_size,
-                    self.learning_rate,
-                    # per-member overrides change training: key them so a
-                    # resume can't mix runs with different LR/patience
-                    sorted(
-                        (n, sorted(hp.items()))
-                        for n, hp in hparams.items()
-                        if n in names
-                    ),
-                    # warm-started members change the trajectory: a resume
-                    # must not mix a warm run with a cold one (content is
-                    # not keyed — refits don't checkpoint in practice, and
-                    # the member names + data hash bound the blast radius)
-                    sorted(n for n in warm if n in names),
-                    self.optimizer,
-                    self.early_stopping_patience,
-                    self.early_stopping_min_delta,
-                    self.validation_split,
-                    self.seed,
-                    int(mesh.shape[MODEL_AXIS]),
-                    # sync width changes the ES decision engine (device f32
-                    # vs host f64): a resume must not mix the two
-                    max(1, int(self.host_sync_every)),
-                ],
-                # content hash per member (streamed, pre-padding): same-shaped
-                # but different data must not resume
-                data=(arrays[n] for n in names),
-            )
-            # async: the orbax write overlaps the next epochs; the commit
-            # marker lands at the next save (or the post-loop flush). A
-            # preemption can lose at most one extra checkpoint interval.
-            ckpt = FleetBucketCheckpoint(self.checkpoint_dir, key, use_async=True)
-            # fit() flushes/closes this on any exception so an orphaned
-            # async writer can't race a same-process retry of the bucket
-            self._active_ckpt = ckpt
-            resumed = ckpt.restore()
-            if resumed is not None:
-                try:
-                    restore_leaves = lambda d: [
-                        jax.device_put(jnp.asarray(d[str(i)]), sharding)
-                        for i in range(len(d))
-                    ]
-                    states = jax.tree.unflatten(
-                        state_treedef, restore_leaves(resumed["state"]["state"])
-                    )
-                    if "best" in resumed["state"]:
-                        best_params = jax.tree.unflatten(
-                            jax.tree.structure(states.params),
-                            restore_leaves(resumed["state"]["best"]),
+            # ---- warm start (incremental refit): overwrite the stacked init's
+            # member rows with the provided serving weights. Mesh-padding
+            # dummies replicate their source member's warm leaves (i % M_real),
+            # like the data; the optimizer state stays freshly initialized ----
+            warm = getattr(self, "_initial_params", None) or {}
+            if any(names[i % M_real] in warm for i in range(M)):
+                host = jax.tree.map(np.array, states.params)
+                treedef = jax.tree.structure(host)
+                leaves = jax.tree.leaves(host)
+                warm_leaves: Dict[str, List[np.ndarray]] = {}
+                for name in set(names) & set(warm):
+                    tree = jax.tree.map(np.asarray, warm[name])
+                    if jax.tree.structure(tree) != treedef:
+                        raise ValueError(
+                            f"initial_params[{name!r}]: tree structure does not "
+                            "match this gang's architecture"
                         )
-                    active = np.asarray(resumed["active"], np.float32)
-                    best = np.asarray(resumed["best"], np.float64)
-                    patience = np.asarray(resumed["patience"], np.int64)
-                    histories = [list(h) for h in resumed["histories"]]
-                    histories_val = [
-                        list(h) for h in resumed.get("histories_val", [[]] * M)
-                    ]
-                    start_epoch = int(resumed["epoch"]) + 1
-                    if es_enabled and not active.any():
-                        # every member already early-stopped when preempted
-                        # (during the post-loop scaler pass): skip the loop
-                        # entirely instead of running one no-op epoch
-                        start_epoch = self.epochs
-                except Exception:
-                    # e.g. a library upgrade changed the opt-state pytree
-                    # structure between preemption and restart: start fresh
-                    # rather than crash every restarted gang
-                    logger.warning(
-                        "Fleet checkpoint structure mismatch; training from scratch",
-                        exc_info=True,
+                    wl = jax.tree.leaves(tree)
+                    for li, leaf in enumerate(leaves):
+                        if wl[li].shape != leaf.shape[1:]:
+                            raise ValueError(
+                                f"initial_params[{name!r}]: leaf {li} shape "
+                                f"{wl[li].shape} != expected {leaf.shape[1:]}"
+                            )
+                    warm_leaves[name] = wl
+                for i in range(M):
+                    wl = warm_leaves.get(names[i % M_real])
+                    if wl is None:
+                        continue
+                    for li, leaf in enumerate(leaves):
+                        leaf[i] = wl[li]
+                states = states._replace(
+                    params=jax.tree.unflatten(
+                        treedef,
+                        [
+                            jax.device_put(jnp.asarray(leaf), sharding)
+                            for leaf in leaves
+                        ],
                     )
-                    states = init_stacked(rngs, sample)
-                    if hparams:
-                        # from-scratch restart must re-apply the same
-                        # per-member LR surgery the initial path did
-                        states = _set_stacked_lr(states, lr_vec)
-                    best_params = None
-                    active = np.ones((M,), dtype=np.float32)
-                    best = np.full((M,), np.inf)
-                    patience = p0_vec.copy()
-                    histories = [[] for _ in range(M)]
-                    histories_val = [[] for _ in range(M)]
-                    start_epoch = 0
+                )
+
+            # ---- per-member hyperparameter vectors (mesh-padding dummies
+            # replicate their source member's values, like the data) ----
+            hparams = getattr(self, "_member_hparams", {})
+
+            def _mvec(key, base, dtype):
+                return np.array(
+                    [
+                        hparams.get(names[i % M_real], {}).get(key, base)
+                        for i in range(M)
+                    ],
+                    dtype=dtype,
+                )
+
+            lr_vec = _mvec("learning_rate", self.learning_rate, np.float32)
+            if hparams:
+                # the injected opt state carries learning_rate as a stacked
+                # (M,) leaf (vmapped init broadcasts the base scalar):
+                # overwrite it with the per-member vector — the ONLY surgery
+                # per-member LR needs, no extra program or gang split
+                states = _set_stacked_lr(states, lr_vec)
+            state_treedef = jax.tree.structure(states)
+
+            # ---- epoch loop: device does the work; host only sees (M,) losses
+            # and drives per-model early stopping ----
+            active = np.ones((M,), dtype=np.float32)
+            best = np.full((M,), np.inf)
+            es_enabled = self.early_stopping_patience is not None
+            # patience RESET values, per member (scalar broadcast when no
+            # overrides): both the host ES loop and the chunked device ES use
+            # this vector, so per-member patience is free in either path
+            p0_vec = (
+                _mvec("early_stopping_patience", self.early_stopping_patience, np.int64)
+                if es_enabled
+                else np.full((M,), -1, dtype=np.int64)
+            )
+            patience = p0_vec.copy()
+            histories: List[List[float]] = [[] for _ in range(M)]
+            histories_val: List[List[float]] = [[] for _ in range(M)]
+
+            # best-params restore, matching BaseEstimator.fit: each member ends
+            # on the params of its best epoch, not the epoch it stopped at
+            best_params = None
+
+            # ---- preemption recovery: resume a matching interrupted run ----
+            ckpt = None
+            start_epoch = 0
+            if self.checkpoint_dir:
+                from gordo_components_tpu.parallel.checkpoint import (
+                    FleetBucketCheckpoint,
+                    bucket_checkpoint_key,
+                )
+
+                key = bucket_checkpoint_key(
+                    [
+                        self.model_type,
+                        # lookback only shapes sequence programs; keying it for
+                        # the dense family would invalidate resumable dense
+                        # checkpoints whenever its (unused) default shifts
+                        self.lookback_window if seq is not None else None,
+                        self.kind,
+                        sorted(self.factory_kwargs.items()),
+                        self.compute_dtype,
+                        self.input_scaler,
+                        loss,
+                        self.kl_weight,
+                        n_features,
+                        padded_rows,
+                        list(names),
+                        self.epochs,
+                        self.batch_size,
+                        self.learning_rate,
+                        # per-member overrides change training: key them so a
+                        # resume can't mix runs with different LR/patience
+                        sorted(
+                            (n, sorted(hp.items()))
+                            for n, hp in hparams.items()
+                            if n in names
+                        ),
+                        # warm-started members change the trajectory: a resume
+                        # must not mix a warm run with a cold one (content is
+                        # not keyed — refits don't checkpoint in practice, and
+                        # the member names + data hash bound the blast radius)
+                        sorted(n for n in warm if n in names),
+                        self.optimizer,
+                        self.early_stopping_patience,
+                        self.early_stopping_min_delta,
+                        self.validation_split,
+                        self.seed,
+                        int(mesh.shape[MODEL_AXIS]),
+                        # sync width changes the ES decision engine (device f32
+                        # vs host f64): a resume must not mix the two
+                        max(1, int(self.host_sync_every)),
+                    ],
+                    # content hash per member (streamed, pre-padding): same-shaped
+                    # but different data must not resume
+                    data=(arrays[n] for n in names),
+                )
+                # async: the orbax write overlaps the next epochs; the commit
+                # marker lands at the next save (or the post-loop flush). A
+                # preemption can lose at most one extra checkpoint interval.
+                ckpt = FleetBucketCheckpoint(self.checkpoint_dir, key, use_async=True)
+                # fit() flushes/closes this on any exception so an orphaned
+                # async writer can't race a same-process retry of the bucket
+                self._active_ckpt = ckpt
+                resumed = ckpt.restore()
+                if resumed is not None:
+                    try:
+                        restore_leaves = lambda d: [
+                            jax.device_put(jnp.asarray(d[str(i)]), sharding)
+                            for i in range(len(d))
+                        ]
+                        states = jax.tree.unflatten(
+                            state_treedef, restore_leaves(resumed["state"]["state"])
+                        )
+                        if "best" in resumed["state"]:
+                            best_params = jax.tree.unflatten(
+                                jax.tree.structure(states.params),
+                                restore_leaves(resumed["state"]["best"]),
+                            )
+                        active = np.asarray(resumed["active"], np.float32)
+                        best = np.asarray(resumed["best"], np.float64)
+                        patience = np.asarray(resumed["patience"], np.int64)
+                        histories = [list(h) for h in resumed["histories"]]
+                        histories_val = [
+                            list(h) for h in resumed.get("histories_val", [[]] * M)
+                        ]
+                        start_epoch = int(resumed["epoch"]) + 1
+                        if es_enabled and not active.any():
+                            # every member already early-stopped when preempted
+                            # (during the post-loop scaler pass): skip the loop
+                            # entirely instead of running one no-op epoch
+                            start_epoch = self.epochs
+                    except Exception:
+                        # e.g. a library upgrade changed the opt-state pytree
+                        # structure between preemption and restart: start fresh
+                        # rather than crash every restarted gang
+                        logger.warning(
+                            "Fleet checkpoint structure mismatch; training from scratch",
+                            exc_info=True,
+                        )
+                        states = init_stacked(rngs, sample)
+                        if hparams:
+                            # from-scratch restart must re-apply the same
+                            # per-member LR surgery the initial path did
+                            states = _set_stacked_lr(states, lr_vec)
+                        best_params = None
+                        active = np.ones((M,), dtype=np.float32)
+                        best = np.full((M,), np.inf)
+                        patience = p0_vec.copy()
+                        histories = [[] for _ in range(M)]
+                        histories_val = [[] for _ in range(M)]
+                        start_epoch = 0
 
         def save_checkpoint(epoch):
-            t_ck = time.monotonic()
-            try:
-                tosave = {"state": dict(
-                    (str(i), leaf) for i, leaf in enumerate(jax.tree.leaves(states))
-                )}
-                if best_params is not None:
-                    tosave["best"] = dict(
+            with self._stage("checkpoint", epoch=int(epoch)) as saving:
+                try:
+                    tosave = {"state": dict(
                         (str(i), leaf)
-                        for i, leaf in enumerate(jax.tree.leaves(best_params))
+                        for i, leaf in enumerate(jax.tree.leaves(states))
+                    )}
+                    if best_params is not None:
+                        tosave["best"] = dict(
+                            (str(i), leaf)
+                            for i, leaf in enumerate(jax.tree.leaves(best_params))
+                        )
+                    # start EVERY leaf's device->host copy before the first
+                    # blocking np.asarray: the copies overlap instead of
+                    # paying one full round-trip per leaf (checkpoint.py
+                    # then materializes them)
+                    for leaf in jax.tree.leaves(tosave):
+                        if hasattr(leaf, "copy_to_host_async"):
+                            leaf.copy_to_host_async()
+                    ckpt.save(
+                        epoch,
+                        tosave,
+                        {
+                            "active": active.tolist(),
+                            "best": best.tolist(),
+                            "patience": patience.tolist(),
+                            "histories": histories,
+                            "histories_val": histories_val,
+                        },
                     )
-                # start EVERY leaf's device->host copy before the first
-                # blocking np.asarray: the copies overlap instead of paying
-                # one full round-trip per leaf (checkpoint.py then
-                # materializes them)
-                for leaf in jax.tree.leaves(tosave):
-                    if hasattr(leaf, "copy_to_host_async"):
-                        leaf.copy_to_host_async()
-                ckpt.save(
-                    epoch,
-                    tosave,
-                    {
-                        "active": active.tolist(),
-                        "best": best.tolist(),
-                        "patience": patience.tolist(),
-                        "histories": histories,
-                        "histories_val": histories_val,
-                    },
-                )
-            except Exception:
-                # best-effort by contract: a full checkpoint volume (or an
-                # injected checkpoint.write fault) costs resumability, not
-                # the hours of training it was protecting
-                logger.warning(
-                    "fleet checkpoint save failed at epoch %d; training "
-                    "continues without it", epoch, exc_info=True,
-                )
-                self._trace_checkpoint(t_ck, epoch, error=True)
-            else:
-                self._trace_checkpoint(t_ck, epoch)
+                except Exception:
+                    # best-effort by contract: a full checkpoint volume (or
+                    # an injected checkpoint.write fault) costs
+                    # resumability, not the hours of training it was
+                    # protecting
+                    logger.warning(
+                        "fleet checkpoint save failed at epoch %d; training "
+                        "continues without it", epoch, exc_info=True,
+                    )
+                    saving.error = True
 
         epoch_times: List[float] = []
         sync = max(1, int(self.host_sync_every))
@@ -1506,45 +1534,47 @@ class FleetTrainer:
 
         if sync == 1:
             for epoch in range(start_epoch, self.epochs):
-                te = time.time()
-                active_pre = active
-                states, losses = run_epoch(
-                    states, Xd, train_maskd, jnp.asarray(active)
-                )
-                losses = np.asarray(losses)
-                if use_val:
-                    vals = np.asarray(
-                        progs.eval_stacked(states.params, Xd, val_maskd)
+                with self._stage("epoch", epoch=epoch) as dispatched:
+                    active_pre = active
+                    states, losses = run_epoch(
+                        states, Xd, train_maskd, jnp.asarray(active)
                     )
-                    vals = np.where(active_pre > 0, vals, np.nan)
-                    monitored = np.where(has_val, vals, losses)
-                else:
-                    vals = np.full_like(losses, np.nan)
-                    monitored = losses
-                epoch_times.append(time.time() - te)
-                if es_enabled:
-                    improved = (monitored < best - self.early_stopping_min_delta) & (
-                        active > 0
-                    )
-                    best = np.where(improved, monitored, best)
-                    if best_params is None:
-                        best_params = jax.tree.map(jnp.copy, states.params)
-                    else:
-                        best_params = _merge_best(
-                            best_params, states.params,
-                            jnp.asarray(improved, jnp.float32),
+                    losses = np.asarray(losses)
+                    if use_val:
+                        vals = np.asarray(
+                            progs.eval_stacked(states.params, Xd, val_maskd)
                         )
-                    patience = np.where(
-                        improved, p0_vec, patience - (active > 0)
-                    )
-                    # patience=0 parity with BaseEstimator.fit: a model stops
-                    # only after a NON-improving epoch exhausts patience — an
-                    # epoch that just improved (patience reset) keeps going.
-                    after = np.where(
-                        (patience <= 0) & ~improved, 0.0, active
-                    ).astype(np.float32)
-                    active = after
-                after_epochs(epoch, [losses], [vals], [active_pre])
+                        vals = np.where(active_pre > 0, vals, np.nan)
+                        monitored = np.where(has_val, vals, losses)
+                    else:
+                        vals = np.full_like(losses, np.nan)
+                        monitored = losses
+                epoch_times.append(dispatched.seconds)
+                with self._stage("epoch_host", epoch=epoch):
+                    if es_enabled:
+                        improved = (
+                            monitored < best - self.early_stopping_min_delta
+                        ) & (active > 0)
+                        best = np.where(improved, monitored, best)
+                        if best_params is None:
+                            best_params = jax.tree.map(jnp.copy, states.params)
+                        else:
+                            best_params = _merge_best(
+                                best_params, states.params,
+                                jnp.asarray(improved, jnp.float32),
+                            )
+                        patience = np.where(
+                            improved, p0_vec, patience - (active > 0)
+                        )
+                        # patience=0 parity with BaseEstimator.fit: a model
+                        # stops only after a NON-improving epoch exhausts
+                        # patience — an epoch that just improved (patience
+                        # reset) keeps going.
+                        after = np.where(
+                            (patience <= 0) & ~improved, 0.0, active
+                        ).astype(np.float32)
+                        active = after
+                    after_epochs(epoch, [losses], [vals], [active_pre])
                 if es_enabled and not active.any():
                     logger.info(
                         "All %d models early-stopped at epoch %d", M, epoch + 1
@@ -1576,23 +1606,23 @@ class FleetTrainer:
             epoch = start_epoch
             while epoch < self.epochs:
                 K = min(sync, self.epochs - epoch)
-                te = time.time()
-                carry, (losses_k, vals_k, act_k) = get_chunk_fn(K)(
-                    carry, Xd, train_maskd, val_maskd, p0_dev
-                )
-                losses_k = np.asarray(losses_k)  # (K, M)
-                vals_k = np.asarray(vals_k)  # (K, M) val losses (NaN when off)
-                act_k = np.asarray(act_k)  # (K, M) pre-epoch active masks
-                chunk_t = time.time() - te
-                epoch_times.extend([round(chunk_t / K, 4)] * K)
-                # host snapshots for checkpoint/break bookkeeping
-                states = carry[0]
-                active = np.asarray(carry[1])
-                best = np.asarray(carry[2], np.float64)
-                patience = np.asarray(carry[3], np.int64)
-                if es_enabled:
-                    best_params = carry[4]  # (seeded flag rides at carry[5])
-                after_epochs(epoch, list(losses_k), list(vals_k), list(act_k))
+                with self._stage("epoch", epoch=epoch, epochs=K) as dispatched:
+                    carry, (losses_k, vals_k, act_k) = get_chunk_fn(K)(
+                        carry, Xd, train_maskd, val_maskd, p0_dev
+                    )
+                    losses_k = np.asarray(losses_k)  # (K, M)
+                    vals_k = np.asarray(vals_k)  # (K, M) val losses (NaN when off)
+                    act_k = np.asarray(act_k)  # (K, M) pre-epoch active masks
+                epoch_times.extend([dispatched.seconds / K] * K)
+                with self._stage("epoch_host", epoch=epoch):
+                    # host snapshots for checkpoint/break bookkeeping
+                    states = carry[0]
+                    active = np.asarray(carry[1])
+                    best = np.asarray(carry[2], np.float64)
+                    patience = np.asarray(carry[3], np.int64)
+                    if es_enabled:
+                        best_params = carry[4]  # (seeded flag rides at carry[5])
+                    after_epochs(epoch, list(losses_k), list(vals_k), list(act_k))
                 epoch += K
                 if es_enabled and not active.any():
                     logger.info(
@@ -1606,64 +1636,68 @@ class FleetTrainer:
             # error-scaler pass / unstacking below can then resume from
             # the last epoch checkpoint (the write already overlapped the
             # epochs, so this wait is near-free)
-            ckpt.flush()
-
-        final_params = best_params if best_params is not None else states.params
+            with self._stage("checkpoint", flush=True):
+                ckpt.flush()
 
         # ---- error scalers + thresholds for the anomaly contract: one
         # vmapped pass (parity with DiffBasedAnomalyDetector.fit, which
         # records max scaled training error as the default threshold);
         # item mask == row mask for the dense family ----
-        err_scalers, feat_thresh, total_thresh = progs.run_error_scalers(
-            final_params, Xd, item_maskd
-        )
-        feat_thresh = np.asarray(feat_thresh)
-        total_thresh = np.asarray(total_thresh)
+        with self._stage("error_scalers"):
+            final_params = best_params if best_params is not None else states.params
+            err_scalers, feat_thresh, total_thresh = progs.run_error_scalers(
+                final_params, Xd, item_maskd
+            )
+            feat_thresh = np.asarray(feat_thresh)
+            total_thresh = np.asarray(total_thresh)
 
         # ---- unstack to host (pipeline every leaf's device->host copy
         # before the first blocking materialization — per-leaf fetches pay
         # a full round-trip each otherwise) ----
-        device_trees = (final_params, scalers, err_scalers)
-        for leaf in jax.tree.leaves(device_trees):
-            if hasattr(leaf, "copy_to_host_async"):
-                leaf.copy_to_host_async()
-        params_np, scalers_np, err_np = jax.tree.map(np.asarray, device_trees)
+        with self._stage("unstack"):
+            device_trees = (final_params, scalers, err_scalers)
+            for leaf in jax.tree.leaves(device_trees):
+                if hasattr(leaf, "copy_to_host_async"):
+                    leaf.copy_to_host_async()
+            params_np, scalers_np, err_np = jax.tree.map(np.asarray, device_trees)
 
         out = {}
-        for i, name in enumerate(names):  # drop dummy pads (i >= M_real)
-            history = {"loss": histories[i]}
-            if use_val and has_val[i]:
-                history["val_loss"] = histories_val[i]
-            out[name] = FleetMemberModel(
-                name=name,
-                kind=self.kind,
-                factory_kwargs=dict(
-                    self.factory_kwargs, compute_dtype=self.compute_dtype
-                ),
-                n_features=n_features,
-                params=jax.tree.map(lambda a: np.asarray(a[i]), params_np),
-                scaler=ScalerParams(
-                    shift=scalers_np.shift[i], scale=scalers_np.scale[i]
-                ),
-                error_scaler=ScalerParams(
-                    shift=err_np.shift[i], scale=err_np.scale[i]
-                ),
-                history=history,
-                tags=self._tags_map.get(name),
-                feature_thresholds=feat_thresh[i],
-                total_threshold=float(total_thresh[i]),
-                scaler_kind=self.input_scaler,
-                model_type=self.model_type,
-                lookback_window=self.lookback_window,
-                loss=self.loss,
-                kl_weight=self.kl_weight,
-                threshold_quantile=self.threshold_quantile,
-                require_thresholds=self.require_thresholds,
-                threshold_method=progs.threshold_method,
-            )
-        # clear only once results are unstacked on host: a preemption during
-        # the error-scaler pass / unstacking above can still resume from the
-        # last epoch checkpoint instead of retraining from scratch
-        if ckpt is not None:
-            ckpt.clear()
-        return out, [round(t, 4) for t in epoch_times], M
+        with self._stage("members"):
+            for i, name in enumerate(names):  # drop dummy pads (i >= M_real)
+                history = {"loss": histories[i]}
+                if use_val and has_val[i]:
+                    history["val_loss"] = histories_val[i]
+                out[name] = FleetMemberModel(
+                    name=name,
+                    kind=self.kind,
+                    factory_kwargs=dict(
+                        self.factory_kwargs, compute_dtype=self.compute_dtype
+                    ),
+                    n_features=n_features,
+                    params=jax.tree.map(lambda a: np.asarray(a[i]), params_np),
+                    scaler=ScalerParams(
+                        shift=scalers_np.shift[i], scale=scalers_np.scale[i]
+                    ),
+                    error_scaler=ScalerParams(
+                        shift=err_np.shift[i], scale=err_np.scale[i]
+                    ),
+                    history=history,
+                    tags=self._tags_map.get(name),
+                    feature_thresholds=feat_thresh[i],
+                    total_threshold=float(total_thresh[i]),
+                    scaler_kind=self.input_scaler,
+                    model_type=self.model_type,
+                    lookback_window=self.lookback_window,
+                    loss=self.loss,
+                    kl_weight=self.kl_weight,
+                    threshold_quantile=self.threshold_quantile,
+                    require_thresholds=self.require_thresholds,
+                    threshold_method=progs.threshold_method,
+                )
+            # clear only once results are unstacked on host: a preemption
+            # during the error-scaler pass / unstacking above can still
+            # resume from the last epoch checkpoint instead of retraining
+            # from scratch
+            if ckpt is not None:
+                ckpt.clear()
+        return out, epoch_times, M

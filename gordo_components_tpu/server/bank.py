@@ -53,6 +53,7 @@ from gordo_components_tpu.models.register import lookup_factory
 from gordo_components_tpu.models.train_core import _next_pow2
 from gordo_components_tpu.observability import get_registry
 from gordo_components_tpu.observability.cost import estimate_flops_per_row
+from gordo_components_tpu.observability.tracing import group_span, stage
 from gordo_components_tpu.ops.pallas_score import (
     banked_anomaly_score,
     resolve_bank_kernel_mode,
@@ -634,17 +635,22 @@ class _GroupRun:
 
     __slots__ = (
         "bucket", "req_ids", "req_plans", "slots", "n_chunks",
-        "Xb", "Yb", "idx", "score_fn", "out", "off", "group_traces",
-        "t_group", "t_chunks", "t_pad", "t_dispatch", "t_ready",
-        "t_device_done", "t_post", "profile_dir", "_bufs",
-        "routed_rows", "total_rows", "shard_rows",
+        "Xb", "Yb", "idx", "score_fn", "out", "off", "traces", "exec_span",
+        "coalesce_s", "pad_s", "postprocess_s", "t_dispatch", "t_ready",
+        "t_device_done", "_bufs", "routed_rows", "total_rows", "shard_rows",
     )
 
     def __init__(self):
         self.out = None
+        # the group's traced requests (observability/tracing.py) and the
+        # ``device_execute`` span they share, open from dispatch to fence
+        self.traces: Sequence[Any] = ()
+        self.exec_span: Any = None
+        # host seconds per stage, for the goodput ledger
+        self.coalesce_s = self.pad_s = self.postprocess_s = 0.0
+        # the device window's bounds: ``enqueue`` start, ``device_wait`` end
         self.t_dispatch = 0.0
         self.t_ready = 0.0
-        self.t_post = 0.0
         # goodput accounting feed (observability/goodput.py): real vs
         # dispatched rows for the padded-waste split, per shard
         self.routed_rows = 0
@@ -654,7 +660,6 @@ class _GroupRun:
         # stage boundaries); 0.0 until then — the fence time is only an
         # upper bound that absorbs whatever host work ran in between
         self.t_device_done = 0.0
-        self.profile_dir = None
         self._bufs = ()
 
     def poll_ready(self, now: float) -> None:
@@ -1354,11 +1359,12 @@ class ModelBank:
         :class:`~gordo_components_tpu.observability.tracing.Trace`
         objects to record the hot-path stage spans into — ``coalesce``,
         ``pad``, ``device_execute`` (dispatch -> fenced-ready, the
-        group's device window), ``postprocess``, plus one
+        group's device window; children ``enqueue``, ``device_wait``),
+        ``postprocess`` (children ``fetch``, ``reassemble``), plus one
         ``pipeline_overlap`` span per multi-group call carrying the
-        measured overlap ratio. The stage-timing path is skipped when no
-        request in a group is traced (the near-free-when-disabled
-        contract; see the tracing hot-loop overhead guard).
+        measured overlap ratio. The stages run either way (each is a
+        profiler annotation and two clock reads; see the tracing hot-loop
+        overhead guard); only the span appends depend on a trace.
 
         ``return_exceptions`` (the batching engine's mode): instead of
         raising on the first failure, a failed bucket group's requests
@@ -1403,7 +1409,7 @@ class ModelBank:
             poll_inflight()
             ok = True
             try:
-                self._postprocess(run, requests, results, traces)
+                self._postprocess(run, requests, results)
             except Exception as exc:
                 ok = False
                 if not return_exceptions:
@@ -1508,29 +1514,40 @@ class ModelBank:
         requests: Sequence[Tuple[str, np.ndarray, Optional[np.ndarray]]],
         traces: Optional[Sequence[Any]],
     ) -> _GroupRun:
-        """Pipeline stage 1 — coalesce + pad (pure host work).
-
-        Validates the group's requests, chunks long ones (sequence chunks
-        OVERLAP by the warm-up so no output rows are lost at chunk
-        boundaries), and assembles the pow2-padded batch arrays in arena
-        scratch buffers, zeroing only the pad tail of reused buffers."""
+        """Pipeline stage 1 — ``coalesce`` + ``pad`` (pure host work), each
+        a :class:`~gordo_components_tpu.observability.tracing.stage`: the
+        spans land in the group's traced requests, the seconds in the
+        goodput ledger."""
         bucket = self._buckets[key]
         run = _GroupRun()
         run.bucket = bucket
         run.req_ids = req_ids
-        group_traces = None
+        run.off = bucket.offset
         if traces is not None:
-            group_traces = [
+            run.traces = [
                 t for t in (traces[ri] for ri in req_ids) if t is not None
-            ] or None
-        run.group_traces = group_traces
-        # stage timestamps serve BOTH tracing and goodput accounting;
-        # with neither attached they stay 0.0 and cost nothing
-        timed = group_traces is not None or self.ledger is not None
-        run.t_group = time.monotonic() if timed else 0.0
+            ]
+        with stage(
+            "coalesce", *run.traces, bucket=bucket.label, requests=len(req_ids)
+        ) as coalesce:
+            chunks, T = self._coalesce(run, requests)
+            coalesce.attributes["chunks"] = run.n_chunks
+        with stage("pad", *run.traces) as pad:
+            self._pad(run, chunks, T, requests)
+        run.coalesce_s, run.pad_s = coalesce.seconds, pad.seconds
+        return run
+
+    def _coalesce(
+        self,
+        run: _GroupRun,
+        requests: Sequence[Tuple[str, np.ndarray, Optional[np.ndarray]]],
+    ) -> Tuple[List[Tuple[int, np.ndarray, np.ndarray]], int]:
+        """Validate the group's requests and chunk long ones (sequence
+        chunks OVERLAP by the warm-up so no output rows are lost at chunk
+        boundaries). Returns the chunks and the rows-per-call ``T``."""
+        bucket, req_ids = run.bucket, run.req_ids
         F = bucket.n_features
-        off = bucket.offset
-        run.off = off
+        off = run.off
         rows = [np.asarray(requests[ri][1], np.float32) for ri in req_ids]
         mrows = self.model_rows
         heat = self.heat
@@ -1597,7 +1614,20 @@ class ModelBank:
             req_plans.append((ri, X, cis, valids, X.shape[0] - off))
         run.req_plans = req_plans
         run.n_chunks = len(chunks)
-        run.t_chunks = time.monotonic() if timed else 0.0
+        return chunks, T
+
+    def _pad(
+        self,
+        run: _GroupRun,
+        chunks: List[Tuple[int, np.ndarray, np.ndarray]],
+        T: int,
+        requests: Sequence[Tuple[str, np.ndarray, Optional[np.ndarray]]],
+    ) -> None:
+        """Assemble the pow2-padded batch arrays in arena scratch buffers
+        (zeroing only the pad tail of reused buffers), routing each chunk
+        to the shard that owns its model under a mesh."""
+        bucket, req_ids = run.bucket, run.req_ids
+        F = bucket.n_features
         if self._m_shard_rows is not None:
             # per-bucket coalescing visibility: dispatches, request
             # fan-in, and the coalesced batch-size distribution
@@ -1697,104 +1727,72 @@ class ModelBank:
             raise
         run.Xb, run.Yb, run.idx = Xb, Yb, idx
         run.slots = slots
-        run.t_pad = time.monotonic() if timed else 0.0
-        return run
 
     def _dispatch(self, run: _GroupRun) -> None:
         """Pipeline stage 2 — async device dispatch.
 
         The XLA call returns device arrays WITHOUT fetching them (JAX
         async dispatch), so the host is free to pad the next group and
-        fetch the previous one while this group executes; the device
-        window closes at :meth:`_postprocess`'s fence."""
+        fetch the previous one while this group executes. ``enqueue`` is
+        the call itself (argument transfer and launch); the
+        ``device_execute`` span it opens closes at :meth:`_postprocess`'s
+        fence, the end of ``device_wait``."""
         _FP_SCORE.fire()
-        run.t_dispatch = time.monotonic()
-        prof_root = (
-            os.environ.get("GORDO_PROFILE_DIR") if run.group_traces else None
+        run.exec_span = group_span(
+            "device_execute", run.traces, time.monotonic(), bucket=run.bucket.label
         )
-        if prof_root:
-            # JAX profiler capture of exactly this dispatch
-            # (utils/profiling.maybe_profile): the profiler trace
-            # directory is named by the request's trace id, so the span
-            # tree and the op-level timeline share one identity — the
-            # span's ``profile`` attribute links them. The capture must
-            # SEE the execution, so this opt-in debugging path fences
-            # inside the profile context, serializing only this group.
-            from gordo_components_tpu.utils.profiling import maybe_profile
-
-            prof_name = f"serve-{run.group_traces[0].trace_id}"
-            run.profile_dir = os.path.join(prof_root, prof_name)
-            with maybe_profile(prof_name):
-                run.out = run.score_fn(run.idx, run.Xb, run.Yb)
-                jax.block_until_ready(run.out)
-            run.t_ready = run.t_device_done = time.monotonic()
-        else:
+        with stage("enqueue", *run.traces, parent=run.exec_span) as enqueue:
             run.out = run.score_fn(run.idx, run.Xb, run.Yb)
+        run.t_dispatch = enqueue.start
 
     def _postprocess(
         self,
         run: _GroupRun,
         requests: Sequence[Tuple[str, np.ndarray, Optional[np.ndarray]]],
         results: List[Any],
-        traces: Optional[Sequence[Any]],
     ) -> None:
-        """Pipeline stage 3 — fence, fetch, reassemble, release."""
+        """Pipeline stage 3 — fence (``device_wait``), then ``postprocess``:
+        ``fetch``, ``reassemble``; release. The stage boundaries are per
+        coalesced GROUP: every traced request in it gets the same span
+        timestamps — per-request attribution of the shared batch's cost,
+        which is exactly what coalescing makes invisible in a plain
+        latency histogram."""
         try:
-            if not run.t_ready:
-                try:
+            wait = stage("device_wait", *run.traces, parent=run.exec_span)
+            try:
+                with wait:
                     # fence: this group's device window ends HERE (a
                     # device-side error surfaces here too, after the
                     # timestamp, so overlap accounting stays sane)
                     jax.block_until_ready(run.out)
-                finally:
-                    run.t_ready = time.monotonic()
-            # one transfer for all five outputs (device_get batches the
-            # D2H copies) instead of five blocking np.asarray round-trips
-            outs = jax.device_get(run.out)
-            slots = run.slots
-            for ri, X_conv, cis, valids, n_out in run.req_plans:
-                if len(cis) == 1:
-                    vals = _slice_single(outs, slots[cis[0]], n_out)
-                else:
-                    vals = _concat_chunks(outs, slots, cis, valids, n_out)
-                results[ri] = ScoreResult(
-                    tags=self._tags[requests[ri][0]],
-                    model_input=X_conv,
-                    model_output=vals[0],
-                    diff=vals[1],
-                    scaled=vals[2],
-                    total_unscaled=vals[3],
-                    total_scaled=vals[4],
-                    offset=run.off,
-                )
-            if run.group_traces or self.ledger is not None:
-                run.t_post = time.monotonic()
-            if run.group_traces:
-                # the stage boundaries are per coalesced GROUP: every
-                # traced request in it gets the same span timestamps —
-                # per-request attribution of the shared batch's cost,
-                # which is exactly what coalescing makes invisible in a
-                # plain latency histogram
-                t_done = run.t_post
-                blabel = run.bucket.label
-                for ri in run.req_ids:
-                    tr = traces[ri]  # type: ignore[index]
-                    if tr is None:
-                        continue
-                    tr.add_span(
-                        "coalesce", run.t_group, run.t_chunks,
-                        bucket=blabel, requests=len(run.req_ids),
-                        chunks=run.n_chunks,
+            finally:
+                run.t_ready = run.exec_span.end = wait.end
+            # a group that fails from here on leaves its ``postprocess``
+            # open: Trace.finish closes it as an error
+            post = group_span("postprocess", run.traces, run.t_ready)
+            with stage("fetch", *run.traces, parent=post):
+                # one transfer for all five outputs (device_get batches the
+                # D2H copies) instead of five blocking np.asarray round-trips
+                outs = jax.device_get(run.out)
+            with stage("reassemble", *run.traces, parent=post) as reassemble:
+                slots = run.slots
+                for ri, X_conv, cis, valids, n_out in run.req_plans:
+                    if len(cis) == 1:
+                        vals = _slice_single(outs, slots[cis[0]], n_out)
+                    else:
+                        vals = _concat_chunks(outs, slots, cis, valids, n_out)
+                    results[ri] = ScoreResult(
+                        tags=self._tags[requests[ri][0]],
+                        model_input=X_conv,
+                        model_output=vals[0],
+                        diff=vals[1],
+                        scaled=vals[2],
+                        total_unscaled=vals[3],
+                        total_scaled=vals[4],
+                        offset=run.off,
                     )
-                    tr.add_span("pad", run.t_chunks, run.t_pad)
-                    exec_attrs: Dict[str, Any] = {"bucket": blabel}
-                    if run.profile_dir is not None:
-                        exec_attrs["profile"] = run.profile_dir
-                    tr.add_span(
-                        "device_execute", run.t_dispatch, run.t_ready,
-                        **exec_attrs,
-                    )
-                    tr.add_span("postprocess", run.t_ready, t_done)
+            post.end = reassemble.end
+            run.postprocess_s = post.duration_s
         finally:
             run.release(self.arena)
 
@@ -1820,13 +1818,9 @@ class ModelBank:
             useful_s=useful_s,
             padded_s=padded_s,
             ok=ok,
-            coalesce_s=(
-                max(0.0, run.t_chunks - run.t_group) if run.t_group else 0.0
-            ),
-            pad_s=max(0.0, run.t_pad - run.t_chunks) if run.t_chunks else 0.0,
-            postprocess_s=(
-                max(0.0, run.t_post - run.t_ready) if run.t_post else 0.0
-            ),
+            coalesce_s=run.coalesce_s,
+            pad_s=run.pad_s,
+            postprocess_s=run.postprocess_s,
             shard_rows=run.shard_rows,
         )
         if ok and useful_s > 0.0:
@@ -2034,13 +2028,17 @@ class BatchingEngine:
         except (ConcurrentInvalidState, asyncio.InvalidStateError):
             pass
 
-    def _bank_call(self, fn, *args, **kwargs):
+    def _bank_call(self, fn, *args, handoff=None, **kwargs):
         """Run a bank entrypoint (executor thread), serialized by the
         shared dispatch lock when several worker engines front one
-        bank."""
-        if self.dispatch_lock is None:
-            return fn(*args, **kwargs)
-        with self.dispatch_lock:
+        bank. ``handoff`` = (the loop's dispatch time, the batch's
+        traces) records the ``handoff`` span: dispatch -> the bank call
+        starts on this thread (executor pick-up, and the wait for the
+        dispatch lock where there is one)."""
+        with self.dispatch_lock or contextlib.nullcontext():
+            if handoff is not None:
+                dispatched, traces = handoff
+                group_span("handoff", traces, dispatched, time.monotonic())
             return fn(*args, **kwargs)
 
     def _collect_metrics(self):
@@ -2352,7 +2350,12 @@ class BatchingEngine:
             requests = results = live = failed = None  # noqa: F841
             first = await self._queue.get()
             batch.append(first)
-            deadline = time.monotonic() + self.flush_s
+            # the loop is back at the queue: whatever a request waited
+            # before this is the wait behind the batch in flight
+            # (``queue_behind``), the rest of its ``queue_wait`` the
+            # deliberate flush window (``queue_flush``)
+            taken = time.monotonic()
+            deadline = taken + self.flush_s
             while len(batch) < self.max_batch:
                 # drain whatever is already queued without arming a timer
                 # per item — wait_for's per-call timer handle was real
@@ -2434,11 +2437,21 @@ class BatchingEngine:
                     traced = True
                     # the coalescing window's per-request cost, named:
                     # submit -> batch dispatch, with the batch size the
-                    # wait bought as an attribute
-                    p.trace.add_span(
+                    # wait bought as an attribute. A request that arrived
+                    # inside the flush window waited behind nothing.
+                    wait = p.trace.add_span(
                         "queue_wait", p.enqueued, dispatch, batch=len(batch)
                     )
+                    flush_from = max(p.enqueued, taken)
+                    p.trace.add_span(
+                        "queue_behind", p.enqueued, flush_from, parent=wait
+                    )
+                    p.trace.add_span(
+                        "queue_flush", flush_from, dispatch, parent=wait
+                    )
             requests = [(p.name, p.X, p.y) for p in batch]
+            traces = [p.trace for p in batch] if traced else None
+            handoff = (dispatch, traces) if traced else None
             try:
                 if self._supports_partial():
                     # group-isolated scoring: a failed bucket group (or a
@@ -2452,7 +2465,8 @@ class BatchingEngine:
                             self._bank_call,
                             self.bank.score_many,
                             requests,
-                            traces=[p.trace for p in batch] if traced else None,
+                            handoff=handoff,
+                            traces=traces,
                             deadline=batch_deadline,
                             return_exceptions=True,
                         ),
@@ -2467,14 +2481,21 @@ class BatchingEngine:
                             self._bank_call,
                             self.bank.score_many,
                             requests,
-                            traces=[p.trace for p in batch] if traced else None,
+                            handoff=handoff,
+                            traces=traces,
                             deadline=batch_deadline,
                         ),
                     )
                 elif traced:
                     results = await loop.run_in_executor(
-                        None, self._bank_call, self.bank.score_many, requests,
-                        [p.trace for p in batch],
+                        None,
+                        functools.partial(
+                            self._bank_call,
+                            self.bank.score_many,
+                            requests,
+                            traces,
+                            handoff=handoff,
+                        ),
                     )
                 else:
                     results = await loop.run_in_executor(
@@ -2556,14 +2577,16 @@ class BatchingEngine:
             self.service.record(time.monotonic() - p.enqueued)
             return
         try:
-            # carry the trace into the retry ONLY if the failed batch
-            # call never recorded stage spans for this request (its
-            # bucket group died before the span block) — a request whose
+            # carry the trace into the retry ONLY if this request's
+            # bucket group did not complete in the failed batch call (its
+            # trace then holds the failed attempt's stages, the last one
+            # flagged, and no closed ``postprocess``) — a request whose
             # group completed before another group raised would otherwise
             # get a duplicate coalesce/pad/execute/postprocess set
             retry_trace = p.trace
             if retry_trace is not None and any(
-                s.name == "device_execute" for s in retry_trace.spans
+                s.name == "postprocess" and s.end is not None
+                for s in retry_trace.spans
             ):
                 retry_trace = None
             if retry_trace is not None:

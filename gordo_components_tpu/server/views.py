@@ -28,7 +28,7 @@ import pandas as pd
 from aiohttp import web
 
 from gordo_components_tpu import __version__, serializer
-from gordo_components_tpu.observability.tracing import chrome_trace
+from gordo_components_tpu.observability.tracing import chrome_trace, stage
 from gordo_components_tpu.qos.admission import QosShed
 from gordo_components_tpu.qos.classify import classify_meta
 from gordo_components_tpu.resilience.deadline import DeadlineExceeded
@@ -1721,6 +1721,31 @@ async def _parse_scoring(request: web.Request):
     return encoding, X, y, Xf, yf
 
 
+def _span_engine_edges(trace) -> None:
+    """The two spans of a banked request whose ends lie on either side of
+    the engine, recorded where the view coroutine resumes after
+    ``await engine.score(...)``: ``admit`` (end of ``parse`` -> the
+    request enqueued: quarantine and QoS admission) and ``resolve`` (the
+    bank's ``postprocess`` done on the executor thread -> now: the
+    hand-off back to the event loop and the future's wake-up). Both are
+    observed across an ``await``, so neither is a profiler stage. A
+    request that never queued, or that a retry scored untraced, gets
+    neither."""
+    now = time.monotonic()
+    parsed = queued = done = None
+    for span in trace.spans:
+        if span.name == "parse":
+            parsed = span.end
+        elif span.name == "queue_wait":
+            queued = span.start
+        elif span.name == "postprocess":
+            done = span.end
+    if parsed is not None and queued is not None:
+        trace.add_span("admit", parsed, queued)
+    if done is not None:
+        trace.add_span("resolve", done, now)
+
+
 @routes.post("/gordo/v0/{project}/{target}/prediction")
 async def prediction(request: web.Request) -> web.Response:
     model, _ = _get_model(request)
@@ -1742,6 +1767,8 @@ async def prediction(request: web.Request) -> web.Response:
                 tenant=tenant_label,
                 qos_class=qos_class,
             )
+            if trace is not None:
+                _span_engine_edges(trace)
             output = result.model_output
             # goodput: the request's share of its group's device window
             # (bank-attributed), committed by the middleware on response
@@ -1781,17 +1808,17 @@ async def prediction(request: web.Request) -> web.Response:
         # binary out for binary in: the output array is framed into one
         # preallocated body — no tolist, no index stringification (the
         # client trims its own index by the offset in __meta__)
-        return web.Response(
-            body=encode_prediction_response(output, len(Xf)),
-            content_type=TENSOR_CONTENT_TYPE,
+        with stage("encode", trace, stage="to_wire"):
+            body = encode_prediction_response(output, len(Xf))
+        return web.Response(body=body, content_type=TENSOR_CONTENT_TYPE)
+    with stage("encode", trace, stage="to_json"):
+        out_index = X.index[len(X) - len(output):]
+        return web.json_response(
+            {
+                "data": np.asarray(output).tolist(),
+                "index": [str(i) for i in out_index],
+            }
         )
-    out_index = X.index[len(X) - len(output):]
-    return web.json_response(
-        {
-            "data": np.asarray(output).tolist(),
-            "index": [str(i) for i in out_index],
-        }
-    )
 
 
 @routes.post("/gordo/v0/{project}/{target}/anomaly/prediction")
@@ -1822,26 +1849,21 @@ async def anomaly_prediction(request: web.Request) -> web.Response:
                 tenant=tenant_label,
                 qos_class=qos_class,
             )
+            if trace is not None:
+                _span_engine_edges(trace)
             request["device_s"] = result.device_s
-            t0 = time.monotonic()
             if encoding == "tensor":
                 # the banked fast path end-to-end: fetched device buffers
                 # -> ScoreResult arrays -> one preallocated response
                 # body. No DataFrame is ever constructed on this path.
-                body = encode_anomaly_response(
-                    result.tags, result.to_arrays(), result.offset
-                )
+                with stage("encode", trace, stage="to_wire"):
+                    body = encode_anomaly_response(
+                        result.tags, result.to_arrays(), result.offset
+                    )
                 total_scaled = result.total_scaled
-                if trace is not None:
-                    trace.add_span(
-                        "postprocess", t0, time.monotonic(), stage="to_wire"
-                    )
             else:
-                frame = result.to_frame(index=X.index)
-                if trace is not None:
-                    trace.add_span(
-                        "postprocess", t0, time.monotonic(), stage="to_frame"
-                    )
+                with stage("encode", trace, stage="to_frame"):
+                    frame = result.to_frame(index=X.index)
         else:
             if deadline is not None and deadline.expired():
                 _note_deadline_expired_per_model(request)
@@ -1886,4 +1908,5 @@ async def anomaly_prediction(request: web.Request) -> web.Response:
     _note_scoring_result(request, target, Xf, total_scaled)
     if encoding == "tensor":
         return web.Response(body=body, content_type=TENSOR_CONTENT_TYPE)
-    return web.json_response(frame_to_dict(frame))
+    with stage("encode", trace, stage="to_json"):
+        return web.json_response(frame_to_dict(frame))
