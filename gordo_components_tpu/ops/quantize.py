@@ -21,13 +21,12 @@ Two storage modes below fp32 (``GORDO_BANK_DTYPE``):
   because scales never cross the member axis.
 
 Dequantization happens INSIDE the compiled scoring program, after the
-per-member gather (:func:`dequantize_params`): HBM holds the small
+batch's members are selected (:func:`dequantize_params`): HBM holds the small
 representation, VMEM/compute sees fp32. The int8 container
 (:class:`QuantizedLeaf`) is a registered pytree node so the bank's
-existing machinery — ``device_put`` with a ``NamedSharding``,
-``shard_map`` in-specs, ``jax.tree.map(lambda a: a[i], params)``
-gathers — works on quantized stacks unchanged: both children carry the
-leading member axis.
+existing machinery — ``device_put`` per leaf, ``shard_map`` in-specs,
+the per-member slices of ``server/bank.py::_select_members`` — works on
+quantized stacks unchanged: both children carry the leading member axis.
 """
 
 from typing import Any, Tuple

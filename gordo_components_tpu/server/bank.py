@@ -5,8 +5,9 @@ unverified; SURVEY.md §2 "server") — scoring N machines means N processes
 each holding one Keras graph. The TPU-native inversion (BASELINE.json
 config 5, SURVEY.md §7 stage 5): every *bankable* model in the collection
 is stacked into one params pytree per (kind, n_features, architecture)
-bucket, resident in device HBM. A request for any model becomes an indexed
-gather into the stack inside a single jit'd scoring program, so
+bucket, resident in device HBM, member-major (``_stored_shape``). A request
+for any model becomes a slice of that member out of the stack inside a
+single jit'd scoring program (``_select_members``), so
 
 - loading 1,000 models costs one ``device_put`` per bucket, not 1,000
   processes;
@@ -67,6 +68,8 @@ from gordo_components_tpu.ops.quantize import (
 from gordo_components_tpu.ops.scaler import ScalerParams
 from gordo_components_tpu.parallel.mesh import device_block
 from gordo_components_tpu.ops.seq_scan import (
+    LANE,
+    SUBLANE,
     lstm_time_major_forward,
     resolve_seq_kernel_mode,
     resolve_seq_layout,
@@ -221,6 +224,95 @@ def _prev_pow2(n: int) -> int:
     return p
 
 
+def _stored_shape(shape: Tuple[int, ...], dtype) -> Tuple[Tuple[int, ...], bool]:
+    """``(shape, swapped)`` of one member's leaf as the bank stores it:
+    the last dimension padded to whole lanes and the second-last to whole
+    sublanes, the two swapped first where that pads less.
+
+    HBM holds an array's two minor-most dimensions in tiles of LANE x
+    SUBLANE 32-bit elements (narrower ones pack along the sublanes). For
+    a ``(4096, 300, 250)`` stack the TPU's default layout therefore puts
+    the 4096 on the lanes (it pads best): ONE member is then one lane of
+    every tile of the bank, and a program that scores two members reads
+    all of it. Where the member's own dimensions already fill whole
+    tiles, no order pads less than row-major, the default layout keeps
+    the member axis major-most, and one member is one contiguous run of
+    tiles — what the padding would cost in HBM either way, a member-major
+    layout being made of whole tiles per member. (Asking for that layout
+    of the unpadded stack, ``device_put`` with a ``Format``, is not safe
+    with JAX 0.9.0: the placing program, once it comes from the persistent
+    compilation cache, returns arrays that report the default layout and
+    hold the other, and the bucket program then refuses its own bank.)
+
+    A (250, 300) kernel takes 256 x 384 elements as it is and 304 x 256
+    with the 250 on the lanes, so it is stored swapped. Which order the
+    matmul wants is the compiler's business either way: it lays out the B
+    selected members, not the bank."""
+    up = lambda n, m: -(-n // m) * m
+    sublanes = SUBLANE * max(1, 4 // np.dtype(dtype).itemsize)
+    if len(shape) < 2:
+        return tuple(up(n, LANE) for n in shape), False
+    *lead, rows, cols = shape
+    straight = (up(rows, sublanes), up(cols, LANE))
+    swapped = (up(cols, sublanes), up(rows, LANE))
+    swap = swapped[0] * swapped[1] < straight[0] * straight[1]
+    return (*lead, *(swapped if swap else straight)), swap
+
+
+@functools.partial(jax.jit, static_argnames="sharding")
+def _store_members(stacked, sharding=None):
+    """One stacked ``(M, ...)`` leaf as the bank keeps it on the device
+    (``_stored_shape``), zeros in the padding. The leaf arrives as the
+    host stacked it and is laid out again once, on the device, where that
+    takes milliseconds (padding 5.5 GB on the host took ten seconds of a
+    server's start); under a mesh it stays on its ``sharding``."""
+    shape, swap = _stored_shape(stacked.shape[1:], stacked.dtype)
+    if swap:
+        stacked = jnp.swapaxes(stacked, -1, -2)
+    stored = jnp.pad(
+        stacked, [(0, 0)] + [(0, n - m) for m, n in zip(stacked.shape[1:], shape)]
+    )
+    if sharding is not None:
+        stored = jax.lax.with_sharding_constraint(stored, sharding)
+    return stored
+
+
+def _restore_members(stored, like):
+    """Traced inverse of ``_store_members`` for a ``(B, ...)`` selection:
+    ``like`` is one member's leaf as the model takes it (shape, dtype)."""
+    _, swap = _stored_shape(like.shape, like.dtype)
+    shape = like.shape[:-2] + like.shape[:-3:-1] if swap else like.shape
+    stored = stored[(slice(None),) + tuple(slice(n) for n in shape)]
+    return jnp.swapaxes(stored, -1, -2) if swap else stored
+
+
+@jax.jit
+def _select_member(stacks, i):
+    """Member ``i`` of every ``(M, ...)`` leaf, as a ``(1, ...)`` slice.
+    Jitted so that a program of B slots traces and lowers it once and
+    calls it B times (inlined by XLA): unrolled in Python, B = 64 spent
+    1.6 s of every server start tracing 1152 slices."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=True), stacks
+    )
+
+
+def _select_members(stacks, idx):
+    """The ``(B, ...)`` stack of members ``idx`` (B,) of every ``(M, ...)``
+    leaf of ``stacks``, by B dynamic slices of each leaf (B is static).
+
+    Slices, not ``a[idx]``: XLA's layout assignment hands a gather's
+    operand the layout its CONSUMER wants, the operand here is the bank,
+    and the relayout is then a copy of all M members on every dispatch.
+    A slice is taken in the bank's own layout; whatever order the matmul
+    wants is made of the B selected members."""
+    rows = [
+        _select_member(stacks, jax.lax.index_in_dim(idx, b, keepdims=False))
+        for b in range(idx.shape[0])
+    ]
+    return jax.tree.map(lambda *slot: jnp.concatenate(slot), *rows)
+
+
 class _Bucket:
     """All models sharing (type, kind, n_features, lookback, factory
     kwargs, dtype): one stacked params pytree + scaler stacks in HBM, one
@@ -230,6 +322,11 @@ class _Bucket:
     (``ops/windows.sliding_windows``) with the bucket's static lookback,
     and outputs carry the warm-up ``offset`` (output row i <- input row
     i + offset), exactly like the per-model path.
+
+    The stacks live member-major (``_stored_shape``) and the scoring
+    program slices the batch's members out before it computes
+    (``_select_members``): its device time and bytes go with the batch,
+    not with the bank.
 
     With a ``mesh`` (1-D ``models`` axis, ``parallel/mesh.py``), the
     stacked params/scalers are placed under a ``NamedSharding`` on their
@@ -348,21 +445,23 @@ class _Bucket:
             entries = entries + [entries[-1]] * (n_pad - len(entries))
             self.shard_size = n_pad // self.n_shards
             sharding = self._sharding = shard_model_axis(self.mesh)
-        stacked = jax.tree.map(
-            lambda *leaves: np.stack(leaves), *[e.params for e in entries]
-        )
-        self.weight_bytes_fp32 = tree_weight_bytes(stacked)
+        # one member's leaves as the bank will store them (dtype, and for
+        # int8 the per-member scale beside the codes): what the program
+        # restores a selection to, the HBM accounting, and the one place a
+        # failing quantization shows, before anything large is made
+        one = jax.tree.map(lambda a: np.asarray(a)[None], entries[0].params)
+        self.weight_bytes_fp32 = len(entries) * tree_weight_bytes(one)
         self.effective_dtype = "float32"
         if self.bank_dtype != "float32":
             # low-precision weight bank (ops/quantize.py): HBM holds the
             # bf16/int8 stack, the compiled program dequantizes the
-            # gathered member back to fp32. A failed quantization is an
+            # selected members back to fp32. A failed quantization is an
             # IMPAIRMENT of capacity, not of correctness — this bucket
             # falls back to fp32 storage (counted by the bank) instead of
             # failing the whole build.
             try:
                 _FP_QUANTIZE.fire()
-                stacked = quantize_stacked(stacked, self.bank_dtype)
+                one = quantize_stacked(one, self.bank_dtype)
                 self.effective_dtype = self.bank_dtype
             except Exception as exc:
                 self.quantize_error = f"{type(exc).__name__}: {exc}"
@@ -371,11 +470,34 @@ class _Bucket:
                     "for this bucket",
                     self.label, self.bank_dtype, exc,
                 )
-        self.weight_bytes = tree_weight_bytes(stacked)
-        self.params = jax.device_put(stacked, sharding)
+        self.weight_bytes = len(entries) * tree_weight_bytes(one)
+        scaler_fields = ("in_shift", "in_scale", "err_shift", "err_scale")
+        members_like = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+            (one, tuple(getattr(entries[0], f)[None] for f in scaler_fields)),
+        )
+
+        # every stacked leaf goes to the device with its members' own
+        # dimensions filling whole tiles (``_stored_shape``), so that it
+        # lies member-major there, on one device and per shard of the mesh
+        # alike: the program below touches only the members a batch names
+        # (``_select_members``), and that is only cheap where one member's
+        # bytes lie together. Leaf by leaf: the host stacks the next leaf
+        # while this one's copy is under way, and the device holds one
+        # leaf twice (as stacked, as stored), never the bank.
+        def place(leaves, dtype="float32"):
+            stacked = quantize_stacked(np.stack(leaves), dtype)
+            return jax.tree.map(
+                lambda a: _store_members(jax.device_put(a, sharding), sharding=sharding),
+                stacked,
+            )
+
+        self.params = jax.tree.map(
+            lambda *leaves: place(leaves, self.effective_dtype),
+            *[e.params for e in entries],
+        )
         self.scalers = tuple(
-            jax.device_put(np.stack([getattr(e, f) for e in entries]), sharding)
-            for f in ("in_shift", "in_scale", "err_shift", "err_scale")
+            place([getattr(e, f) for e in entries]) for f in scaler_fields
         )
         module = lookup_factory(self.registry_type, self.kind)(
             self.n_features, compute_dtype=self.compute_dtype, **self.factory_kwargs
@@ -409,17 +531,14 @@ class _Bucket:
         if use_tm:
             self.flops_method += f":time_major(T={lookback})"
 
-        def forward_tm(params, in_shift, in_scale, idx, X, Y):
-            # idx: (B,) int32; X/Y: (B, T, F) raw-space. One gather
-            # stacks every slot's member params; one scan over time
-            # scores all slots' windows with the slot axis innermost.
+        def forward_tm(p, in_shift, in_scale, X, Y):
+            # p, in_shift, in_scale: the B selected members, (B, ...);
+            # X/Y: (B, T, F) raw-space. One scan over time scores all
+            # slots' windows with the slot axis innermost.
             from gordo_components_tpu.ops.windows import sliding_windows
 
-            p = jax.tree.map(lambda a: a[idx], params)
-            if dequant:
-                p = dequantize_params(p)
-            sh = in_shift[idx][:, None, :]
-            sc = in_scale[idx][:, None, :]
+            sh = in_shift[:, None, :]
+            sc = in_scale[:, None, :]
             xs = (X - sh) * sc
             ys = (Y - sh) * sc
             W = jax.vmap(lambda x: sliding_windows(x, lookback))(xs)
@@ -429,19 +548,14 @@ class _Bucket:
             target = ys[:, off : off + recon.shape[1]]
             return recon, target
 
-        def forward(params, in_shift, in_scale, i, x, y):
-            # i: () int32 into the (local) stack; x/y: (T, F) raw-space;
+        def forward(p, in_shift, in_scale, x, y):
+            # ONE selected member (vmapped over the batch's B below):
+            # p its params, in_shift/in_scale (F,); x/y: (T, F) raw-space;
             # returns (recon, target) — the epilogue runs batched below
             from gordo_components_tpu.ops.windows import sliding_windows
 
-            p = jax.tree.map(lambda a: a[i], params)
-            if dequant:
-                # per-member dequantization INSIDE the compiled program:
-                # only the gathered member's weights round-trip to fp32,
-                # compute accumulates in fp32 throughout
-                p = dequantize_params(p)
-            xs = (x - in_shift[i]) * in_scale[i]
-            ys = (y - in_shift[i]) * in_scale[i]
+            xs = (x - in_shift) * in_scale
+            ys = (y - in_shift) * in_scale
             if lookback > 1:
                 W = sliding_windows(xs, lookback)
                 if t_off:
@@ -453,56 +567,55 @@ class _Bucket:
                 target = ys
             return recon, target
 
-        if self.mesh is None:
+        # the jitted function is named ``score`` on one device and on the
+        # mesh: the XLA module is then ``jit_score``, the name the device
+        # trace's readers find the bucket program by
+        def score(params, in_shift, in_scale, err_shift, err_scale, idx, X, Y):
+            # idx: (B,) int32 into the (local) stacks; X/Y: (B, T, F)
+            # raw-space. Select, then compute: the B members' params and
+            # scaler rows are sliced out of the bank first, and everything
+            # after this line has B, never M, as its leading dimension.
+            p, (in_shift, in_scale, err_shift, err_scale) = jax.tree.map(
+                _restore_members,
+                _select_members(
+                    (params, (in_shift, in_scale, err_shift, err_scale)), idx
+                ),
+                members_like,
+            )
+            if dequant:
+                # dequantization INSIDE the compiled program: only the
+                # selected members' weights round-trip to fp32, compute
+                # accumulates in fp32 throughout
+                p = dequantize_params(p)
+            # the model forward runs per member; the scoring epilogue
+            # (scale -> reconstruction error -> row norms) runs over the
+            # WHOLE batch in one banked pass — the Pallas kernel's (member,
+            # row-tile) grid on TPU, identical jnp math elsewhere
+            # (ops/pallas_score.banked_anomaly_score) — against the selected
+            # error scalers, slot b's row being row b
+            recon, target = (forward_tm if use_tm else jax.vmap(forward))(
+                p, in_shift, in_scale, X, Y
+            )
+            return (recon,) + banked_anomaly_score(
+                target, recon, err_shift, err_scale,
+                jnp.arange(idx.shape[0], dtype=jnp.int32), mode=kernel_mode,
+            )
 
-            def score(params, in_shift, in_scale, err_shift, err_scale, idx, X, Y):
-                # idx: (B,) int32; X/Y: (B, T, F) raw-space. The model
-                # forward vmaps per member; the scoring epilogue (scale ->
-                # reconstruction error -> row norms) runs over the WHOLE
-                # batch in one banked pass — the Pallas kernel's
-                # (member, row-tile) grid on TPU, identical jnp math
-                # elsewhere (ops/pallas_score.banked_anomaly_score)
-                if use_tm:
-                    recon, target = forward_tm(
-                        params, in_shift, in_scale, idx, X, Y
-                    )
-                else:
-                    recon, target = jax.vmap(
-                        lambda i, x, y: forward(
-                            params, in_shift, in_scale, i, x, y
-                        )
-                    )(idx, X, Y)
-                diff, scaled, tot_u, tot_s = banked_anomaly_score(
-                    target, recon, err_shift, err_scale, idx, mode=kernel_mode
-                )
-                return recon, diff, scaled, tot_u, tot_s
-
-        else:
+        if self.mesh is not None:
             from jax.sharding import PartitionSpec as P
 
             from gordo_components_tpu.parallel.mesh import MODEL_AXIS
 
             spec = P(MODEL_AXIS)
+            score_shard = score
 
             def score(params, in_shift, in_scale, err_shift, err_scale, idx, X, Y):
                 # idx: (D, Blocal) LOCAL indices; X/Y: (D, Blocal, T, F);
                 # leading axis sharded over the mesh — each device scores
                 # its own sub-batch against its local (shard_size, ...)
-                # params block; no collectives. The banked epilogue runs
-                # per device on the local sub-batch with the LOCAL scaler
-                # stack — the gather indices are already shard-local.
+                # params and scaler blocks; no collectives.
                 def local(p, ish, isc, esh, esc, i, x, y):
-                    if use_tm:
-                        recon, target = forward_tm(
-                            p, ish, isc, i[0], x[0], y[0]
-                        )
-                    else:
-                        recon, target = jax.vmap(
-                            lambda ii, xx, yy: forward(p, ish, isc, ii, xx, yy)
-                        )(i[0], x[0], y[0])
-                    out = (recon,) + banked_anomaly_score(
-                        target, recon, esh, esc, i[0], mode=kernel_mode
-                    )
+                    out = score_shard(p, ish, isc, esh, esc, i[0], x[0], y[0])
                     return jax.tree.map(lambda t: t[None], out)
 
                 # check_vma off: the program is collective-free by design

@@ -236,9 +236,11 @@ class DriftDetector:
         bucket = bank._buckets.get(entry[0])
         if bucket is None or bucket.scalers is None:
             return None
-        i = entry[1]
-        in_shift = np.asarray(bucket.scalers[0])[i]
-        in_scale = np.asarray(bucket.scalers[1])[i]
+        # the member's row of each stack, cut to the model's width: the
+        # bank stores its stacks padded to whole tiles (bank.py::_stored_shape)
+        i, F = entry[1], bucket.n_features
+        in_shift = np.asarray(bucket.scalers[0][i])[:F]
+        in_scale = np.asarray(bucket.scalers[1][i])[:F]
         return (np.asarray(X, np.float32) - in_shift) * in_scale
 
     # ----------------------------- views ------------------------------- #

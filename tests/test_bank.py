@@ -19,6 +19,8 @@ from gordo_components_tpu.models import (
 from gordo_components_tpu.models.transformers import JaxMinMaxScaler
 from gordo_components_tpu.server.bank import BatchingEngine, ModelBank
 
+from bank_parity import assert_slots_equal_their_single_answers
+
 
 def _make_det(Xv, scaler=None, base=None, **ae_kwargs):
     if base is None:
@@ -88,6 +90,62 @@ def test_bank_scoring_matches_per_model_path(fleet_models, name):
     expected = models[name].anomaly(X)
     got = bank.score(name, X).to_frame()
     pd.testing.assert_frame_equal(got, expected, rtol=1e-4, atol=1e-5)
+
+
+@pytest.fixture(scope="module")
+def one_bucket_fleets():
+    """Nine dense members of ONE bucket and five LSTM members of another,
+    each fitted on its own data (so no two members' answers agree), in
+    stack order: the first and the last name are the stack's ends."""
+    rng = np.random.RandomState(3)
+    data = rng.rand(14, 120, 3).astype("float32") + np.arange(14)[:, None, None] / 14
+    dense = {
+        f"d{i}": _make_det(data[i], epochs=1) for i in range(9)
+    }
+    lstm = {
+        f"l{i}": _make_det(
+            data[9 + i],
+            base=LSTMAutoEncoder(lookback_window=4, epochs=1, batch_size=32),
+        )
+        for i in range(5)
+    }
+    return {"dense": dense, "lstm": lstm}, rng.rand(8, 64, 3).astype("float32")
+
+
+@pytest.mark.parametrize(
+    "slots",
+    [
+        [0], [-1],  # B = 1: the stack's two ends
+        [0, -1], [1, 1],  # B = 2; the same member twice in one batch
+        [-1, 0, 2],  # three requests in a batch padded to four
+        [0, 4, 4, -1, 2, 0, 1, 3],  # B = 8, repeats, both ends
+    ],
+    ids=lambda slots: "-".join(map(str, slots)),
+)
+@pytest.mark.parametrize("kind", ["dense", "lstm"])
+def test_batch_slots_match_single_and_per_model_path(
+    one_bucket_fleets, monkeypatch, kind, slots
+):
+    """Select-then-compute (ISSUE 27): each slot of a coalesced batch is
+    bitwise its member's own B = 1 answer, and the per-model path's to the
+    tolerance of ``test_bank_scoring_matches_per_model_path``."""
+    fleets, bodies = one_bucket_fleets
+    models = fleets[kind]
+    bank = ModelBank.from_models(models, registry=False)
+    assert bank.n_buckets == 1
+    names = list(models)
+    # distinct bodies and lengths per slot, all padded to 64 rows
+    requests = [
+        (names[member], bodies[slot][: 40 + 3 * slot], None)
+        for slot, member in enumerate(slots)
+    ]
+    batch = assert_slots_equal_their_single_answers(
+        bank, requests, monkeypatch, batch_size={1: 1, 2: 2, 3: 4, 8: 8}[len(slots)]
+    )
+    for (name, X, _), got in zip(requests, batch):
+        pd.testing.assert_frame_equal(
+            got.to_frame(), models[name].anomaly(X), rtol=1e-4, atol=1e-5
+        )
 
 
 def test_bank_scoring_with_y(fleet_models):

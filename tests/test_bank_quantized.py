@@ -33,6 +33,8 @@ from gordo_components_tpu.resilience import faults as resilience
 from gordo_components_tpu.resilience.faults import FaultInjected
 from gordo_components_tpu.server.bank import ModelBank
 
+from bank_parity import assert_slots_equal_their_single_answers
+
 # the documented tolerance bands, per storage dtype
 BANDS = {"bfloat16": dict(rtol=0.02, atol=0.02), "int8": dict(rtol=0.05, atol=0.05)}
 
@@ -195,6 +197,28 @@ def test_quantized_bank_within_band_single_device(
         assert cap["capacity_ratio"] > 1.8
     assert cap["models_per_gb"] > 0
     assert not cap["quantize_fallbacks"]
+
+
+@pytest.mark.parametrize("n_requests,batch_size", [(1, 1), (2, 2), (3, 4), (8, 8)])
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+def test_quantized_batch_slots_match_single(
+    hetero_models, monkeypatch, dtype, n_requests, batch_size
+):
+    """Selection runs on the STORED leaves (bf16 values; int8 codes and
+    their per-member scales), dequantization after it: every slot of a
+    batch over the two-member bucket — both ends of its stack, repeated —
+    is bitwise its own B = 1 answer, and the fp32 bank's inside the band."""
+    models, data = hetero_models
+    names = ["f3-b", "f3-a", "f3-a", "f3-b"]
+    requests = [
+        (names[k % 4], data["f3-a"][k : k + 20 + k], None) for k in range(n_requests)
+    ]
+    bank = ModelBank.from_models(models, registry=False, bank_dtype=dtype)
+    got = assert_slots_equal_their_single_answers(
+        bank, requests, monkeypatch, batch_size
+    )
+    want = ModelBank.from_models(models, registry=False).score_many(requests)
+    _assert_within_band(got, want, BANDS[dtype])
 
 
 @pytest.mark.skipif(

@@ -25,6 +25,8 @@ from gordo_components_tpu.models import (
 from gordo_components_tpu.parallel.mesh import MODEL_AXIS, fleet_mesh
 from gordo_components_tpu.server.bank import BatchingEngine, ModelBank
 
+from bank_parity import assert_slots_equal_their_single_answers
+
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 2, reason="needs the virtual multi-device mesh"
 )
@@ -81,6 +83,39 @@ def test_sharded_bank_matches_anomaly_frame(many_models):
         expected = models[name].anomaly(X[:50])
         got = sharded.score(name, X[:50]).to_frame()
         pd.testing.assert_frame_equal(got, expected, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "names,batch_size",
+    [
+        (["m-00"], 1), (["m-11"], 1),  # the stack's two ends, on two shards
+        (["m-00", "m-01"], 2),  # one shard's two members
+        (["m-01", "m-01", "m-00"], 4),  # three on one shard, padded to four
+        # six on the first shard (padded to eight), repeats, other shards' too
+        (["m-00", "m-01", "m-01", "m-00", "m-11", "m-10", "m-00", "m-01"], 8),
+        (["lstm"], 1), (["lstm", "lstm", "lstm"], 4),
+    ],
+    ids=lambda v: "-".join(n[-2:] for n in v) if isinstance(v, list) else str(v),
+)
+def test_sharded_batch_slots_match_single_and_per_model_path(
+    many_models, monkeypatch, names, batch_size
+):
+    """Each shard slices ITS slots' members out of its own block of the
+    stack: every slot is bitwise the same request's B = 1 answer, and the
+    per-model path's to the tolerance of the frame test above."""
+    models, X = many_models
+    bank = ModelBank.from_models(
+        {n: m for n, m in models.items() if (n == "lstm") == (names[0] == "lstm")},
+        mesh=fleet_mesh(), registry=False,
+    )
+    requests = [(name, X[k : k + 40 + k], None) for k, name in enumerate(names)]
+    got = assert_slots_equal_their_single_answers(
+        bank, requests, monkeypatch, batch_size
+    )
+    for (name, Xq, _), res in zip(requests, got):
+        pd.testing.assert_frame_equal(
+            res.to_frame(), models[name].anomaly(Xq), rtol=1e-4, atol=1e-5
+        )
 
 
 def test_sharded_heterogeneous_batch(many_models):

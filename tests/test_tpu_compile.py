@@ -22,6 +22,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 # topology and would skip. Nothing here touches a device.
 os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -130,48 +132,61 @@ _BUCKETS = {
 N_TAGS, ROWS = 10, 256
 
 
-def _bucket(name, monkeypatch, mesh=None):
+def _bucket(name, monkeypatch, home, n_tags=N_TAGS, stand_in=None):
     """A finalized ``_Bucket`` of randomly initialised members with the
     device decisions a TPU backend makes (pallas epilogue, time-major
     layout, fused step) — steered here, in the test, because
-    ``jax.default_backend()`` is the CPU in this process."""
+    ``jax.default_backend()`` is the CPU in this process.
+
+    A described device cannot hold an array: around ``finalize()``,
+    shapes stand in for the stacked state it places, on ``home`` (the
+    described chip, or a ``NamedSharding`` on the described mesh).
+    ``stand_in`` members instead of the few really stacked: the bank's
+    leading dimension at a real size, for nothing."""
     spec = _BUCKETS[name]
     monkeypatch.setenv(seq_scan.SEQ_LAYOUT_ENV, "time_major")
     monkeypatch.setenv(seq_scan.SEQ_KERNEL_ENV, "pallas")
-    if mesh is not None:
-        # a described device cannot hold an array: stand shapes in for
-        # the stacked state finalize() places on the mesh
-        monkeypatch.setattr(
-            jax,
-            "device_put",
-            lambda tree, sharding=None: jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
-                tree,
-            ),
-        )
-    module = lookup_factory(spec["registry_type"], spec["kind"])(N_TAGS)
+    members = spec["members"] if stand_in is None else 4
+
+    def describe(a, sharding=None):
+        assert sharding in (None, home)
+        shape = (stand_in or a.shape[0],) + a.shape[1:]
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=home)
+
+    store = bank_mod._store_members
+
+    def stored(a, sharding=None):
+        # ``finalize`` lays each placed leaf out again on the device:
+        # here its shape does
+        return jax.ShapeDtypeStruct(jax.eval_shape(store, a).shape, a.dtype, sharding=home)
+
+    mesh = home.mesh if isinstance(home, NamedSharding) else None
+    module = lookup_factory(spec["registry_type"], spec["kind"])(n_tags)
     sample = jnp.zeros(
-        (1, N_TAGS) if spec["lookback"] == 1 else (1, spec["lookback"], N_TAGS)
+        (1, n_tags) if spec["lookback"] == 1 else (1, spec["lookback"], n_tags)
     )
     params = jax.tree.map(
         np.asarray, module.init(jax.random.PRNGKey(0), sample)
     )
     bucket = bank_mod._Bucket(
-        spec["kind"], N_TAGS, {}, registry_type=spec["registry_type"],
+        spec["kind"], n_tags, {}, registry_type=spec["registry_type"],
         lookback=spec["lookback"], mesh=mesh, kernel_mode="pallas",
     )
-    vec = np.ones((N_TAGS,), np.float32)
-    for i in range(spec["members"]):
+    vec = np.ones((n_tags,), np.float32)
+    for i in range(members):
         bucket.add(
             bank_mod._BankEntry(
                 name=f"m{i}", registry_type=spec["registry_type"],
                 kind=spec["kind"], factory_kwargs={}, compute_dtype="float32",
-                n_features=N_TAGS, lookback=spec["lookback"], target_offset=0,
+                n_features=n_tags, lookback=spec["lookback"], target_offset=0,
                 params=params, in_shift=0 * vec, in_scale=vec,
                 err_shift=0 * vec, err_scale=vec,
             )
         )
-    bucket.finalize()
+    with monkeypatch.context() as described:
+        described.setattr(jax, "device_put", describe)
+        described.setattr(bank_mod, "_store_members", stored)
+        bucket.finalize()
     return bucket
 
 
@@ -186,19 +201,30 @@ def _assert_kernels(name, bucket, text):
         assert bucket.seq_layout == "legacy"
 
 
+def _home(v5e, chips):
+    """Where a bank lives: one described chip, or the ``models`` mesh
+    over all four (GORDO_SERVER_DEVICES=4)."""
+    if chips == 1:
+        return SingleDeviceSharding(v5e[0])
+    return NamedSharding(Mesh(np.asarray(v5e), (MODEL_AXIS,)), P(MODEL_AXIS))
+
+
+def _compile_bucket(bucket, home, B, n_tags=N_TAGS):
+    """The bucket program for B slots (per shard, under a mesh)."""
+    lead = (B,) if bucket.mesh is None else (bucket.n_shards, B)
+    X = jax.ShapeDtypeStruct(lead + (ROWS, n_tags), f32, sharding=home)
+    idx = jax.ShapeDtypeStruct(lead, i32, sharding=home)
+    return bucket._score.lower(
+        bucket.params, *bucket.scalers, idx, X, X
+    ).compile()
+
+
 @pytest.mark.parametrize("name", ["dense", "lstm"])
 def test_bucket_scoring_program_compiles_for_one_chip(v5e, monkeypatch, name):
     B = 64  # one full coalesced batch (BatchingEngine max_batch)
-    bucket = _bucket(name, monkeypatch)
-    chip = SingleDeviceSharding(v5e[0])
-    on_chip = lambda tree: jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree
-    )
-    X = jax.ShapeDtypeStruct((B, ROWS, N_TAGS), f32, sharding=chip)
-    idx = jax.ShapeDtypeStruct((B,), i32, sharding=chip)
-    compiled = bucket._score.lower(
-        on_chip(bucket.params), *on_chip(bucket.scalers), idx, X, X
-    ).compile()
+    chip = _home(v5e, 1)
+    bucket = _bucket(name, monkeypatch, chip)
+    compiled = _compile_bucket(bucket, chip, B)
     _assert_kernels(name, bucket, compiled.as_text())
     mem = compiled.memory_analysis()
     resident = (
@@ -208,23 +234,111 @@ def test_bucket_scoring_program_compiles_for_one_chip(v5e, monkeypatch, name):
     assert resident < 16e9  # one v5e chip's HBM
 
 
+def _no_collectives(text):
+    for collective in ("all-reduce", "all-gather", "all-to-all", "collective-permute"):
+        assert collective not in text, collective
+
+
 @pytest.mark.parametrize("name", ["dense", "lstm"])
 def test_sharded_bucket_program_compiles_for_four_chips(v5e, monkeypatch, name):
     """The bank sharded over a four-chip ``models`` mesh
     (GORDO_SERVER_DEVICES=4): a ``pallas_call`` inside ``shard_map``,
     each chip scoring its own sub-batch against its quarter of the
     stack — and no collective in the program."""
-    mesh = Mesh(np.asarray(v5e), (MODEL_AXIS,))
-    bucket = _bucket(name, monkeypatch, mesh=mesh)
-    sharded = NamedSharding(mesh, P(MODEL_AXIS))
-    B = 8  # slots per shard
-    X = jax.ShapeDtypeStruct((4, B, ROWS, N_TAGS), f32, sharding=sharded)
-    idx = jax.ShapeDtypeStruct((4, B), i32, sharding=sharded)
+    sharded = _home(v5e, 4)
+    bucket = _bucket(name, monkeypatch, sharded)
     assert bucket.shard_size == _BUCKETS[name]["members"] // 4
-    compiled = bucket._score.lower(
-        bucket.params, *bucket.scalers, idx, X, X
-    ).compile()
+    text = _compile_bucket(bucket, sharded, 8).as_text()  # 8 slots per shard
+    _assert_kernels(name, bucket, text)
+    _no_collectives(text)
+
+
+# ------------------------------------------------------------------ #
+# the bucket program reads the members it scores, not the bank
+# ------------------------------------------------------------------ #
+
+# (bucket, tags, members stood in as shapes, chips). ``dense300`` is the
+# benchmark's live cell: 4096 members of 1.34 MB, 5.5 GB of stack.
+# Member counts no batch size, layer width or row count equals, so a
+# shape that leads with one IS the bank's.
+_BANKS = {
+    "dense300": ("dense", 300, 4096, 1),
+    "lstm": ("lstm", N_TAGS, 640, 1),
+    "dense-sharded": ("dense", N_TAGS, 4096, 4),
+    "lstm-sharded": ("lstm", N_TAGS, 640, 4),
+}
+# XLA's byte count of the dense300 program (``cost_analysis()``), an upper
+# limit per B: the parent read 9.2 GB at B = 2 and 10.1 GB at B = 64, at
+# 819 GB/s the 14 ms a batch the chip measured
+_BYTES_LIMIT = {2: 0.3e9, 64: 2e9}
+
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+# ``%name = <result type> opcode(``, layouts removed: a tuple result has
+# no parenthesis of its own left
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%\S+ = (\([^()]*\)|\S+) [\w\-]+\(")
+
+
+def _bank_sized(text, members):
+    """Instructions of the scheduled module with a result that leads with
+    the bank's member count. Parameters are left out: the entry's are the
+    bank, a fused computation's are views of its operands."""
+    lead = re.compile(r"\w+\[%d[,\]]" % members)
+    found = []
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(_LAYOUT.sub("", line))
+        if m and " parameter(" not in line and lead.search(m.group(1)):
+            found.append(line.strip()[:240])
+    return found
+
+
+def _is_prefetch(line):
+    """XLA moving one entry parameter into the chip's fast memory (``S(1)``)
+    ahead of its use, in the layout it has: no relayout, no arithmetic, and
+    only ever of a leaf that fits there whole (a scaler stack of the live
+    cell at B = 1: 4.9 MB; the 1.2 GB kernels never)."""
+    m = re.search(r"= \((\S+), (\S+), u32\[\]\S*\) copy-start\(", line)
+    if m:
+        return m.group(1).replace("S(1)", "") == m.group(2)
+    m = re.search(r"= (\S+) copy-done\(", line)
+    return bool(m and re.search(r"\{(2,1,0|1,0|0):\S*S\(1\)\}$", m.group(1)))
+
+
+@pytest.mark.parametrize("B", [1, 2, 8, 64])
+@pytest.mark.parametrize("bank", sorted(_BANKS))
+def test_bucket_program_reads_only_the_members_it_scores(v5e, monkeypatch, bank, B):
+    """ISSUE 27. The stack lies member-major and the program slices its B
+    members out before it computes, so (a) nothing but an entry parameter
+    has the bank's leading dimension — XLA used to lay the WHOLE bank out
+    again (and round it to bf16 on the way) for the matmul behind a gather,
+    on every dispatch of two or more requests; (b) the compiler's own byte
+    count is that of B members; (c) every stacked leaf enters member-major
+    in the layout the device gives it unasked, so nothing is laid out again
+    on entry."""
+    name, n_tags, members, chips = _BANKS[bank]
+    home = _home(v5e, chips)
+    bucket = _bucket(name, monkeypatch, home, n_tags=n_tags, stand_in=members)
+    compiled = _compile_bucket(bucket, home, B, n_tags=n_tags)
     text = compiled.as_text()
     _assert_kernels(name, bucket, text)
-    for collective in ("all-reduce", "all-gather", "all-to-all", "collective-permute"):
-        assert collective not in text, collective
+    _no_collectives(text)
+    # (c) every stacked leaf enters member-major, in the default layout of
+    # its stored shape: what an array placed with no layout asked has
+    stacked = jax.tree.leaves((bucket.params, bucket.scalers))
+    entry = jax.tree.leaves(compiled.input_formats[0][:5])  # all but idx, X, Y
+    assert len(entry) == len(stacked)
+    for leaf, fmt in zip(stacked, entry):
+        assert leaf.shape[0] == members and leaf.format.layout is None
+        assert fmt.layout.major_to_minor[0] == 0, (leaf, fmt)
+    # (a) in the program a shard's block of the stack is the bank
+    local = members // chips
+    work = _bank_sized(text, local)
+    assert [l for l in work if not _is_prefetch(l)] == []
+    if bank == "dense300":
+        # (b), and what member-major costs: whole tiles per member, kept
+        # under 15% here by putting the better of a kernel's two
+        # dimensions on the lanes (as the model has them: 27%)
+        if B in _BYTES_LIMIT:
+            assert compiled.cost_analysis()["bytes accessed"] < _BYTES_LIMIT[B]
+        assert compiled.memory_analysis().argument_size_in_bytes < 1.15 * (
+            members * bucket.params_per_member * 4
+        )
