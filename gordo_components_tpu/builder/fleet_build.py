@@ -577,6 +577,18 @@ def build_fleet(
 
     try:
         for machine in machines:
+            if "TrunkForecast" in json.dumps(machine.model, default=str):
+                # its parameters split into a trunk many members share and
+                # per-machine leaves: the gang trainer stacks EVERY leaf per
+                # member and has no optimizer for the split. Building such
+                # members one by one under a fleet build would only look
+                # like one.
+                raise ValueError(
+                    f"Machine {machine.name}: a TrunkForecast member cannot be "
+                    "fleet-built (shared trunk leaves are not implemented in "
+                    "FleetTrainer); build it with `build` (build-model), one "
+                    "machine at a time, against its trunk artifact"
+                )
             ae_kwargs = extract_fleetable(machine.model)
             # the fleet engine trains X -> X (reconstruction); a dataset
             # declaring target tags supervises X -> y, so it must take the

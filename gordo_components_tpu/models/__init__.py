@@ -10,6 +10,7 @@ from gordo_components_tpu.models.models import (
     ConvAutoEncoder,
     LSTMAutoEncoder,
     LSTMForecast,
+    TrunkForecast,
 )
 from gordo_components_tpu.models.anomaly import DiffBasedAnomalyDetector
 
@@ -27,6 +28,7 @@ __all__ = [
     "LSTMAutoEncoder",
     "LSTMForecast",
     "ConvAutoEncoder",
+    "TrunkForecast",
     "DiffBasedAnomalyDetector",
     "KerasAutoEncoder",
     "KerasLSTMAutoEncoder",

@@ -21,6 +21,7 @@ from gordo_components_tpu.models.factories.lstm import (
 )
 from gordo_components_tpu.models.factories.conv import conv1d_autoencoder
 from gordo_components_tpu.models.factories.variational import feedforward_variational
+from gordo_components_tpu.models.factories.trunk import sparse_moe_decoder
 
 __all__ = [
     "feedforward_model",
@@ -32,4 +33,5 @@ __all__ = [
     "lstm_hourglass",
     "conv1d_autoencoder",
     "feedforward_variational",
+    "sparse_moe_decoder",
 ]

@@ -14,7 +14,9 @@ These classes drop into ``sklearn.pipeline.Pipeline`` and pickle cleanly
 server rely on.
 """
 
+import functools
 import logging
+import os
 from typing import Any, Dict, Optional
 
 import jax
@@ -404,6 +406,156 @@ class ConvAutoEncoder(SequenceBaseEstimator):
             self._params.setdefault("conv_impl", "lax")
 
     _target_offset = 0
+
+
+class TrunkForecast(BaseEstimator):
+    """One machine's view of a shared decoder trunk: a causal-sequence
+    forecaster (output row i forecasts input row i + 1 from rows 0..i,
+    the whole call being one sequence) whose parameters split in two.
+
+    - **Shared**: the trunk (``models/factories/trunk.py``), a *trunk
+      artifact* named by ``trunk`` (a directory; a relative path means
+      "beside this member's artifact"). Every member that names it holds
+      the same object, and a bank places it on the device once.
+    - **Per machine** (``params_``): the input projection ``tags ->
+      hidden`` and the head ``hidden -> tags``.
+
+    ``fit`` never touches the trunk. It draws the input projection from
+    ``seed`` and solves the head by ridge regression (``ridge``) of the
+    next row on the trunk's state, over the training rows cut into
+    sequences of ``sequence_rows``. A trunk artifact that does not exist
+    yet is made from ``seed`` with random weights and written for the
+    members to come; training a trunk, and fitting members as a fleet,
+    are not implemented (``build-fleet`` refuses this estimator).
+    ``**factory_kwargs`` are the trunk kind's sizes."""
+
+    lookback_window = 1
+    _target_offset = 1  # prediction i corresponds to input row i + 1
+
+    @capture_args
+    def __init__(
+        self,
+        kind: str = "sparse_moe_decoder",
+        trunk: str = "trunk",
+        sequence_rows: int = 512,
+        ridge: float = 1e-3,
+        seed: int = 0,
+        **factory_kwargs,
+    ):
+        super().__init__(kind=kind, seed=seed, **factory_kwargs)
+        self.trunk = str(trunk)
+        self.sequence_rows = int(sequence_rows)
+        self.ridge = float(ridge)
+        self.artifact_root_: Optional[str] = None
+        self._forward = None
+        # capture_args on both ctors: keep this one's view
+        self._params = {
+            "kind": kind, "trunk": trunk, "sequence_rows": sequence_rows,
+            "ridge": ridge, "seed": seed, **factory_kwargs,
+        }
+
+    def bind_artifact_root(self, root: str) -> None:
+        """``serializer.load``: the directory this member's artifact lies in."""
+        self.artifact_root_ = root
+
+    @property
+    def trunk_path(self) -> str:
+        if os.path.isabs(self.trunk) or self.artifact_root_ is None:
+            return os.path.abspath(self.trunk)
+        return os.path.join(self.artifact_root_, self.trunk)
+
+    @property
+    def trunk_params(self):
+        """The shared tree (``serializer.load_trunk`` caches it: one
+        object per process and artifact version)."""
+        from gordo_components_tpu import serializer
+
+        return serializer.load_trunk(self.trunk_path)
+
+    def _ensure_trunk(self):
+        from gordo_components_tpu import serializer
+
+        if not os.path.exists(os.path.join(self.trunk_path, "trunk.pkl")):
+            logger.warning(
+                "No trunk artifact at %s: writing a random one from seed %d",
+                self.trunk_path, self.seed,
+            )
+            trunk = self.module.init_trunk(jax.random.PRNGKey(self.seed))
+            serializer.dump_trunk(jax.tree.map(np.asarray, trunk), self.trunk_path)
+        return self.trunk_params
+
+    def _run(self, member, X: np.ndarray, n_valid) -> np.ndarray:
+        """``module.apply`` over (B, rows, F) sequences padded to whole
+        chunks, jitted once per shape; the kernel choice is the bank's. A
+        ``member`` without a head gets the state the heads read."""
+        from gordo_components_tpu.ops.pallas_score import resolve_bank_kernel_mode
+
+        module = self.module
+        if self._forward is None:
+            interpret = resolve_bank_kernel_mode() != "pallas"
+            self._forward = jax.jit(functools.partial(module.apply, interpret=interpret))
+        T = module.padded_rows(X.shape[1])
+        Xp = np.zeros((X.shape[0], T, X.shape[2]), np.float32)
+        Xp[:, : X.shape[1]] = X
+        out, _observed = self._forward(
+            self.trunk_params, member, jnp.asarray(Xp), jnp.asarray(n_valid, jnp.int32)
+        )
+        return np.asarray(out)[:, : X.shape[1]]
+
+    def fit(self, X, y=None, **kwargs):
+        X = _as_float32(X)
+        if X.ndim == 1:
+            X = X[:, None]
+        if y is not None:
+            raise ValueError("TrunkForecast forecasts its own input; y is not taken")
+        if X.shape[0] < 2:
+            raise ValueError("Need at least 2 rows to fit a forecast")
+        self.n_features_ = int(X.shape[-1])
+        self._module = self._forward = None
+        module = self.module
+        self._ensure_trunk()
+        member = jax.tree.map(np.asarray, module.init_member(jax.random.PRNGKey(self.seed)))
+        # whole sequences of ``sequence_rows``; the remainder is its own, shorter one
+        L = min(self.sequence_rows, X.shape[0])
+        starts = list(range(0, X.shape[0] - 1, L))
+        seqs = np.zeros((len(starts), L, X.shape[1]), np.float32)
+        lengths = np.asarray([min(L, X.shape[0] - s) for s in starts], np.int32)
+        for i, (s, n) in enumerate(zip(starts, lengths)):
+            seqs[i, :n] = X[s : s + n]
+        in_proj = jax.tree.map(lambda a: a[None].repeat(len(starts), 0), member["in_proj"])
+        state = self._run({"in_proj": in_proj}, seqs, lengths).astype(np.float64)
+        keep = np.arange(L - 1)[None, :] < (lengths - 1)[:, None]  # rows with a next row
+        H = np.concatenate([state[:, :-1][keep], np.ones((int(keep.sum()), 1))], axis=1)
+        Y = seqs[:, 1:][keep].astype(np.float64)
+        gram = H.T @ H + self.ridge * len(H) * np.eye(H.shape[1])
+        W = np.linalg.solve(gram, H.T @ Y)
+        member["head"] = {"kernel": W[:-1].astype(np.float32), "bias": W[-1].astype(np.float32)}
+        self.params_ = {"params": member}
+        resid = H @ W - Y
+        self.history = {"loss": [float(np.mean(resid * resid))]}
+        return self
+
+    def predict(self, X) -> np.ndarray:
+        """Output row i is the forecast of input row i + 1: one row
+        shorter than the input, the input being one sequence."""
+        self._check_fitted()
+        X = _as_float32(X)
+        if X.ndim == 1:
+            X = X[:, None]
+        if X.shape[0] < 2:
+            raise ValueError(f"Need at least 2 rows (one to forecast), got {X.shape[0]}")
+        member = jax.tree.map(lambda a: np.asarray(a)[None], self.params_["params"])
+        return self._run(member, X[None], [X.shape[0]])[0, :-1]
+
+    def _scoring_pair(self, X, y):
+        X = _as_float32(X)
+        base = X if y is None else _as_float32(y)
+        return base[1:], self.predict(X)
+
+    def __getstate__(self):
+        state = super().__getstate__()
+        state["_forward"] = None
+        return state
 
 
 def _jsonable(obj):

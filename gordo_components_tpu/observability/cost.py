@@ -175,6 +175,14 @@ def estimate_flops_per_row(
             conv1d_autoencoder_flops(n_features, channels, kernel, lookback),
             "analytic",
         )
+    routed = getattr(module, "forward_flops_per_row", None)
+    if routed is not None:
+        # a trunk of routed experts and selected attention counts what a
+        # row meets (its top-k experts, the keys it selected), not every
+        # parameter; a row's keys depend on its request's length, taken
+        # here as five times the selection (10 240 rows at top-k 2048)
+        context = 5 * int(module.indexer_topk)
+        return routed(context), f"analytic:context={context}"
     if params_per_member:
         return 2.0 * float(params_per_member) * max(1, int(lookback)), "params"
     return 0.0, "unknown"

@@ -16,10 +16,13 @@ from gordo_components_tpu.serializer.definitions import (
 )
 from gordo_components_tpu.serializer.artifacts import (
     dump,
+    dump_trunk,
     dumps,
     load,
     loads,
     load_metadata,
+    load_trunk,
+    release_trunks,
 )
 
 __all__ = [
@@ -32,4 +35,7 @@ __all__ = [
     "load",
     "loads",
     "load_metadata",
+    "dump_trunk",
+    "load_trunk",
+    "release_trunks",
 ]

@@ -11,6 +11,11 @@ persists a pipeline as a directory of pickled steps + Keras HDF5 +
                        language-neutrally for non-Python consumers
 - ``metadata.json``  — the build-metadata contract
 
+A *trunk* artifact is a directory of its own holding ``trunk.pkl`` (no
+``model.pkl``, so no server lists it as a model): the parameters that
+many members share (``models/factories/trunk.py``). Members name it;
+``load_trunk`` reads it once per process however many members do.
+
 The unit of persistence is the *finished model artifact* exactly as in the
 reference (SURVEY.md §5 "Checkpoint/resume"); mid-training checkpointing of
 fleet state lives in parallel/ (orbax), not here.
@@ -76,9 +81,49 @@ def loads(data: bytes) -> Any:
     return pickle.loads(data)
 
 
+def _final_estimator(obj: Any) -> Any:
+    if hasattr(obj, "base_estimator"):
+        return _final_estimator(obj.base_estimator)
+    if hasattr(obj, "steps"):
+        return _final_estimator(obj.steps[-1][1])
+    return obj
+
+
 def load(source_dir: str) -> Any:
     with open(os.path.join(source_dir, _MODEL_FILE), "rb") as f:
-        return pickle.load(f)
+        obj = pickle.load(f)
+    # a member that names a trunk by a relative path means: beside me
+    bind = getattr(_final_estimator(obj), "bind_artifact_root", None)
+    if bind is not None:
+        bind(os.path.dirname(os.path.abspath(source_dir)))
+    return obj
+
+
+_TRUNK_FILE = "trunk.pkl"
+_TRUNKS: Dict[str, Tuple[float, Any]] = {}  # real path -> (mtime, params)
+
+
+def dump_trunk(params: Any, dest_dir: str) -> None:
+    """Write a trunk artifact; the rename makes it appear whole."""
+    os.makedirs(dest_dir, exist_ok=True)
+    path = os.path.join(dest_dir, _TRUNK_FILE)
+    tmp = f"{path}.{os.getpid()}.tmp"
+    with open(tmp, "wb") as f:
+        pickle.dump(params, f, protocol=pickle.HIGHEST_PROTOCOL)
+    os.replace(tmp, path)
+
+
+def load_trunk(source_dir: str) -> Any:
+    """The trunk's parameter tree, read once per process and artifact
+    version: every member of a bank gets the SAME object, which is how
+    the bank knows to place it once."""
+    path = os.path.realpath(os.path.join(source_dir, _TRUNK_FILE))
+    mtime = os.path.getmtime(path)
+    cached = _TRUNKS.get(path)
+    if cached is None or cached[0] != mtime:
+        with open(path, "rb") as f:
+            cached = _TRUNKS[path] = (mtime, pickle.load(f))
+    return cached[1]
 
 
 def load_metadata(source_dir: str) -> Dict:
@@ -87,3 +132,9 @@ def load_metadata(source_dir: str) -> Dict:
         return {}
     with open(path) as f:
         return json.load(f)
+
+
+def release_trunks() -> None:
+    """Forget every trunk read so far (a server that dropped its bank and
+    wants the memory back; the next ``load_trunk`` reads the file again)."""
+    _TRUNKS.clear()

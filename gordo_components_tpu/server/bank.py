@@ -114,6 +114,9 @@ class _BankEntry:
     in_scale: np.ndarray
     err_shift: np.ndarray
     err_scale: np.ndarray
+    # leaves the member does NOT own: a trunk every member of the bucket
+    # shares (``(artifact path, parameter tree)``; models/factories/trunk.py)
+    shared: Optional[Tuple[str, Any]] = None
 
 
 def _affine_from_scaler(step, n_features: int):
@@ -152,7 +155,9 @@ def _affine_from_scaler(step, n_features: int):
 
 # estimator classes whose scoring the bank can reproduce exactly; the
 # registry type doubles as the factory namespace (models/register.py)
-_BANKABLE_TYPES = {"AutoEncoder", "LSTMAutoEncoder", "LSTMForecast", "ConvAutoEncoder"}
+_BANKABLE_TYPES = {
+    "AutoEncoder", "LSTMAutoEncoder", "LSTMForecast", "ConvAutoEncoder", "TrunkForecast",
+}
 
 
 def _extract_entry(name: str, model) -> Tuple[Optional[_BankEntry], Optional[str]]:
@@ -207,6 +212,11 @@ def _extract_entry(name: str, model) -> Tuple[Optional[_BankEntry], Optional[str
             in_scale=in_scale.astype(np.float32),
             err_shift=np.asarray(err.shift, np.float32),
             err_scale=np.asarray(err.scale, np.float32),
+            shared=(
+                (est.trunk_path, est.trunk_params)
+                if registry_type == "TrunkForecast"
+                else None
+            ),
         ),
         None,
     )
@@ -354,6 +364,7 @@ class _Bucket:
         mesh=None,
         bank_dtype: str = "float32",
         kernel_mode: str = "jnp",
+        shared: Optional[Tuple[str, Any]] = None,
     ):
         self.kind = kind
         self.n_features = n_features
@@ -416,10 +427,39 @@ class _Bucket:
         # sequence fast-path provenance, resolved by finalize()
         self.seq_layout = "legacy"
         self.seq_kernel = "jnp"
+        # leaves held ONCE beside the per-member stacks: a trunk the
+        # bucket's members share. ``(artifact path, tree)`` until
+        # finalize() places the tree on the device. Such a bucket scores a
+        # request as one causal sequence: it never cuts one into calls,
+        # pads it to whole chunks of its module instead of a power of two,
+        # and bounds a batch by the bytes its program needs (``max_batch``)
+        self.shared = shared
+        self.shared_bytes = 0
+        self._module = None
+        self._free_bytes: Optional[int] = None  # device memory left for a call
 
     @property
     def offset(self) -> int:
         return self.lookback - 1 + self.target_offset
+
+    def rows_per_call(self, rows: int, max_rows: int) -> int:
+        """``T`` of a call that carries requests of up to ``rows`` rows."""
+        if self.shared is not None:
+            return self._module.padded_rows(rows)
+        T = min(_next_pow2(max(1, int(rows))), _prev_pow2(max_rows))
+        # always at least one window + one output row
+        return max(T, _next_pow2(self.offset + 1))
+
+    def max_batch(self, T: int) -> Optional[int]:
+        """Requests of ``T`` padded rows one call may carry, from the bytes
+        the bucket's program needs beside what the device already holds;
+        ``None``: as many as the engine collects."""
+        if self.shared is None or self._free_bytes is None:
+            return None
+        B = 1
+        while self._module.program_bytes(2 * B, T) <= self._free_bytes:
+            B *= 2
+        return B
 
     def add(self, entry: _BankEntry) -> None:
         self._entries.append(entry)
@@ -492,6 +532,16 @@ class _Bucket:
                 stacked,
             )
 
+        if self.shared is not None:
+            if self.mesh is not None or self.bank_dtype != "float32":
+                raise NotImplementedError(
+                    "a bucket with shared leaves serves from one device at its "
+                    "own dtypes (no mesh, no GORDO_BANK_DTYPE)"
+                )
+            # placed once, in the dtypes the artifact holds (bfloat16
+            # matrices), leaf by leaf; a leaf already on the device stays
+            self.shared = jax.tree.map(jax.device_put, self.shared[1])
+            self.shared_bytes = tree_weight_bytes(self.shared)
         self.params = jax.tree.map(
             lambda *leaves: place(leaves, self.effective_dtype),
             *[e.params for e in entries],
@@ -499,7 +549,7 @@ class _Bucket:
         self.scalers = tuple(
             place([getattr(e, f) for e in entries]) for f in scaler_fields
         )
-        module = lookup_factory(self.registry_type, self.kind)(
+        module = self._module = lookup_factory(self.registry_type, self.kind)(
             self.n_features, compute_dtype=self.compute_dtype, **self.factory_kwargs
         )
         # one member's param count + analytic FLOPs, once per compiled
@@ -567,10 +617,31 @@ class _Bucket:
                 target = ys
             return recon, target
 
+        # A bucket with shared leaves runs as three programs (score_batch):
+        # ``score_enter`` (the B selected members' input projections),
+        # ``score_layer`` once per layer of the shared trunk (every layer has
+        # the same shapes: compiled once whatever the depth, each call handed
+        # its own layer's leaves in place), and ``score`` below, which then
+        # starts from the trunk's last state. All three names begin with
+        # ``score``: the device trace's readers sum the bucket's programs
+        # by that prefix.
+        def score_enter(params, in_shift, in_scale, idx, X):
+            p, (in_shift, in_scale) = jax.tree.map(
+                _restore_members,
+                _select_members((params, (in_shift, in_scale)), idx),
+                (members_like[0], members_like[1][:2]),
+            )
+            xs = (X - in_shift[:, None, :]) * in_scale[:, None, :]
+            return module.embed(p["params"]["in_proj"], xs)
+
+        def score_layer(w, x, n_valid):
+            return module.layer(w, x, n_valid, interpret=kernel_mode != "pallas")
+
         # the jitted function is named ``score`` on one device and on the
         # mesh: the XLA module is then ``jit_score``, the name the device
         # trace's readers find the bucket program by
-        def score(params, in_shift, in_scale, err_shift, err_scale, idx, X, Y):
+        def score(params, in_shift, in_scale, err_shift, err_scale, idx, X, Y,
+                  state=None, final_norm=None):
             # idx: (B,) int32 into the (local) stacks; X/Y: (B, T, F)
             # raw-space. Select, then compute: the B members' params and
             # scaler rows are sliced out of the bank first, and everything
@@ -593,9 +664,16 @@ class _Bucket:
             # row-tile) grid on TPU, identical jnp math elsewhere
             # (ops/pallas_score.banked_anomaly_score) — against the selected
             # error scalers, slot b's row being row b
-            recon, target = (forward_tm if use_tm else jax.vmap(forward))(
-                p, in_shift, in_scale, X, Y
-            )
+            if state is not None:
+                # the shared trunk's last state through the B members'
+                # heads: output row i forecasts input row i + 1
+                out = module.head(final_norm, p["params"]["head"], state)
+                recon = out[:, :-off]
+                target = ((Y - in_shift[:, None, :]) * in_scale[:, None, :])[:, off:]
+            else:
+                recon, target = (forward_tm if use_tm else jax.vmap(forward))(
+                    p, in_shift, in_scale, X, Y
+                )
             return (recon,) + banked_anomaly_score(
                 target, recon, err_shift, err_scale,
                 jnp.arange(idx.shape[0], dtype=jnp.int32), mode=kernel_mode,
@@ -631,15 +709,30 @@ class _Bucket:
                 )(params, in_shift, in_scale, err_shift, err_scale, idx, X, Y)
 
         self._score = jax.jit(score)
+        if self.shared is not None:
+            self._enter, self._layer = jax.jit(score_enter), jax.jit(score_layer)
         self._entries = []  # host copies no longer needed
 
-    def score_batch(self, indices: np.ndarray, X: np.ndarray, Y: np.ndarray):
+    def score_batch(self, indices: np.ndarray, X: np.ndarray, Y: np.ndarray,
+                    n_valid: Optional[np.ndarray] = None):
         """Single-device path. indices: (B,), X/Y: (B, T, F) — already
-        padded to pow2 B and T."""
+        padded to pow2 B and ``rows_per_call`` T. A bucket with shared
+        leaves takes each slot's real rows (``n_valid``, 0 for a pad slot)
+        and returns, after the five arrays, what each layer observed."""
+        if self.shared is None:
+            return self._score(
+                self.params, *self.scalers, jnp.asarray(indices), jnp.asarray(X),
+                jnp.asarray(Y),
+            )
+        idx, X, n_valid = jnp.asarray(indices), jnp.asarray(X), jnp.asarray(n_valid, jnp.int32)
+        x = self._enter(self.params, *self.scalers[:2], idx, X)
+        observed = []
+        for w in self.shared["layers"]:
+            x, seen = self._layer(w, x, n_valid)
+            observed.append(seen)
         return self._score(
-            self.params, *self.scalers, jnp.asarray(indices), jnp.asarray(X),
-            jnp.asarray(Y),
-        )
+            self.params, *self.scalers, idx, X, jnp.asarray(Y), x, self.shared["final_norm"]
+        ) + (observed,)
 
     def score_batch_sharded(self, indices: np.ndarray, X: np.ndarray, Y: np.ndarray):
         """Mesh path. indices: (D, Blocal) LOCAL indices (into each
@@ -682,6 +775,12 @@ class ScoreResult:
     # — the HTTP layer commits it to the goodput/wasted cells once the
     # request's final outcome is known; 0.0 when accounting is off
     device_s: float = 0.0
+    # a bucket with shared leaves: which experts each row was routed to,
+    # (layers, rows, top_k) uint8, and which keys every 64th row attended
+    # to, as packed bits (layers, sampled rows, padded rows // 8) uint8
+    # (ops/sparse_attention.py). They ride the tensor response as two more
+    # frames: what a client compares two servers' selections by.
+    selections: Optional[Dict[str, np.ndarray]] = None
 
     def to_frame(self, index=None):
         n_out = len(self.model_output)
@@ -713,7 +812,19 @@ class ScoreResult:
             "tag-anomaly-scaled": self.scaled,
             "total-anomaly-unscaled": self.total_unscaled,
             "total-anomaly-scaled": self.total_scaled,
+            **(self.selections or {}),
         }
+
+
+# counters of the buckets with shared leaves (``ModelBank.shared_stats``)
+_SHARED_COUNTERS = {
+    "dispatches": "Dispatches of bucket programs with shared leaves",
+    "rows": "Request rows those dispatches carried",
+    "tokens": "Rows they computed, padding included",
+    "expert_tokens": "Valid (row, expert) pairs routed, all layers",
+    "expert_tokens_busiest": "Pairs routed to each layer's busiest expert",
+    "key_selections": "(query, key) pairs the indexer selected, all layers",
+}
 
 
 def _slice_single(outs, slot, n_out: int):
@@ -883,6 +994,11 @@ class ModelBank:
             "wall_s": 0.0,
             "device_busy_s": 0.0,
         }
+        # what the buckets with shared leaves observed (``_count_observed``),
+        # from arrays their program returns with each dispatch; served in
+        # ``/stats`` as ``bank_shared`` and scraped as gordo_bank_shared_*.
+        # Empty for a bank that has no such bucket.
+        self.shared_stats: Dict[str, int] = {}
         self._buckets: Dict[str, _Bucket] = {}
         self._index: Dict[str, Tuple[str, int]] = {}  # name -> (bucket_key, i)
         self._tags: Dict[str, List[str]] = {}
@@ -1081,6 +1197,17 @@ class ModelBank:
                 return tuple(rows)
 
             registry.collector(_capacity_collect, key="bank_capacity")
+
+            def _shared_collect():
+                bank = ref()
+                return () if bank is None else tuple(
+                    (f"gordo_bank_shared_{name}_total", "counter", text, {},
+                     bank.shared_stats.get(name, 0))
+                    for name, text in _SHARED_COUNTERS.items()
+                    if bank.shared_stats
+                )
+
+            registry.collector(_shared_collect, key="bank_shared")
         else:
             # all of them, not just the one score_many guards on: a future
             # call site guarding on its own attribute must get None, not
@@ -1125,6 +1252,9 @@ class ModelBank:
                     # fp32 and a bf16 stack are different HBM layouts
                     # compiled into different programs
                     bank.bank_dtype,
+                    # members of one trunk share a bucket; another trunk
+                    # is other leaves, so another bucket
+                    entry.shared[0] if entry.shared else None,
                 ],
                 default=str,
             )
@@ -1141,6 +1271,7 @@ class ModelBank:
                     mesh=bank.mesh,
                     bank_dtype=bank.bank_dtype,
                     kernel_mode=bank.kernel_mode,
+                    shared=entry.shared,
                 )
             bank._index[name] = (key, len(bucket.names))
             bucket.add(entry)
@@ -1178,6 +1309,13 @@ class ModelBank:
                     bank._index.pop(name, None)
                     bank._tags.pop(name, None)
                     bank.fallback[name] = reason
+        # what the device has left once every bucket is placed bounds a
+        # call of a bucket whose program's bytes go with its rows
+        stats = jax.devices()[0].memory_stats() or {}
+        if "bytes_limit" in stats:
+            free = int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+            for bucket in bank._buckets.values():
+                bucket._free_bytes = free
         if bank._index:
             logger.info(
                 "Model bank: %d models in %d bucket(s)%s",
@@ -1233,12 +1371,16 @@ class ModelBank:
         their ratio is the capacity win low-precision storage bought.
         Buckets whose quantization fell back to fp32 appear in
         ``quantize_fallbacks`` and drag the ratio toward 1."""
-        total = sum(b.weight_bytes for b in self._buckets.values())
-        fp32 = sum(b.weight_bytes_fp32 for b in self._buckets.values())
+        # a shared leaf counts once, whatever the members that use it
+        shared = sum(b.shared_bytes for b in self._buckets.values())
+        total = sum(b.weight_bytes for b in self._buckets.values()) + shared
+        fp32 = sum(b.weight_bytes_fp32 for b in self._buckets.values()) + shared
         by_dtype: Dict[str, int] = {}
         for b in self._buckets.values():
             d = b.effective_dtype
             by_dtype[d] = by_dtype.get(d, 0) + b.weight_bytes
+        if shared:
+            by_dtype["shared"] = shared  # at the dtypes their artifact holds
         members = len(self._index)
         bpm = total / members if members else None
         return {
@@ -1246,6 +1388,7 @@ class ModelBank:
             "kernel": self.kernel_mode,
             "members": members,
             "weight_bytes": total,
+            "shared_bytes": shared,
             "weight_bytes_by_dtype": by_dtype,
             "fp32_bytes": fp32,
             "capacity_ratio": round(fp32 / total, 3) if total else None,
@@ -1370,20 +1513,10 @@ class ModelBank:
         for bucket in self._buckets.values():
             shapes = sorted(
                 {
-                    (
-                        # EXACTLY score_many's T computation (clamp to
-                        # max_rows, then floor at the warm-up window) —
-                        # warming any other shape leaves the dispatched
-                        # one cold and compiles a dead program
-                        max(
-                            min(
-                                _next_pow2(max(1, int(r))),
-                                _prev_pow2(self.max_rows),
-                            ),
-                            _next_pow2(bucket.offset + 1),
-                        ),
-                        B,
-                    )
+                    # EXACTLY score_many's T computation — warming any
+                    # other shape leaves the dispatched one cold and
+                    # compiles a dead program
+                    (bucket.rows_per_call(r, self.max_rows), B)
                     for r in row_list
                     for B in batches
                 }
@@ -1395,7 +1528,9 @@ class ModelBank:
             for T, B in shapes:
                 if self.mesh is None:
                     X = np.zeros((B, T, bucket.n_features), np.float32)
-                    out = bucket.score_batch(np.zeros((B,), np.int32), X, X)
+                    out = bucket.score_batch(
+                        np.zeros((B,), np.int32), X, X, n_valid=np.full((B,), T)
+                    )
                 else:
                     D = bucket.n_shards
                     X = np.zeros((D, B, T, bucket.n_features), np.float32)
@@ -1420,6 +1555,18 @@ class ModelBank:
 
     def __len__(self) -> int:
         return len(self._index)
+
+    def batch_limit(self, name: str, rows: int) -> Optional[int]:
+        """Requests like this one (``rows`` rows for ``name``) that one
+        call may carry, where ``name``'s bucket bounds its calls by the
+        bytes its program needs (``_Bucket.max_batch``); else ``None``."""
+        entry = self._index.get(name)
+        if entry is None:
+            return None
+        bucket = self._buckets[entry[0]]
+        if bucket.shared is None:
+            return None
+        return bucket.max_batch(bucket.rows_per_call(rows, self.max_rows))
 
     @property
     def n_buckets(self) -> int:
@@ -1498,7 +1645,18 @@ class ModelBank:
                 continue
             by_bucket.setdefault(entry[0], []).append(ri)
 
-        groups = list(by_bucket.items())
+        # a bucket with shared leaves bounds its call by bytes: a longer
+        # group goes as several calls, one at a time
+        groups = []
+        for key, req_ids in by_bucket.items():
+            bucket = self._buckets[key]
+            limit = None
+            if bucket.shared is not None:
+                limit = bucket.max_batch(bucket.rows_per_call(
+                    max(np.shape(requests[ri][1])[0] for ri in req_ids), self.max_rows
+                ))
+            step = limit or len(req_ids)
+            groups += [(key, req_ids[i : i + step]) for i in range(0, len(req_ids), step)]
         n_groups = len(groups)
         window = self._inflight_window
         inflight: "deque[_GroupRun]" = deque()
@@ -1561,6 +1719,11 @@ class ModelBank:
                 run = None
                 try:
                     run = self._host_prep(key, req_ids, requests, traces)
+                    if run.bucket.shared is not None:
+                        # its program's bytes are sized to what the device
+                        # has left: nothing else in flight beside it
+                        while inflight:
+                            finish(inflight.popleft())
                     self._dispatch(run)
                 except Exception as exc:
                     # the failed group's own buffers (host_prep cleans up
@@ -1692,11 +1855,9 @@ class ModelBank:
                 name = requests[ri][0]
                 pend[name] = pend.get(name, 0.0) + X.shape[0]
         # rows-per-call stays a power of two and never exceeds max_rows
-        # (but must always cover at least one window + one output row)
-        T = min(
-            _next_pow2(max(x.shape[0] for x in rows)), _prev_pow2(self.max_rows)
-        )
-        T = max(T, _next_pow2(off + 1))
+        # (but must always cover at least one window + one output row); a
+        # bucket of whole sequences covers its longest request instead
+        T = bucket.rows_per_call(max(x.shape[0] for x in rows), self.max_rows)
         step = T - off
         chunks: List[Tuple[int, np.ndarray, np.ndarray]] = []
         # per-request reassembly plan, built once here instead of the
@@ -1785,6 +1946,10 @@ class ModelBank:
                 run.total_rows = B * T
                 run.shard_rows = (("0", routed0, B * T - routed0),)
                 run.score_fn = bucket.score_batch
+                if bucket.shared is not None:
+                    n_valid = np.zeros((B,), np.int32)
+                    n_valid[: len(chunks)] = [xc.shape[0] for _ri, xc, _yc in chunks]
+                    run.score_fn = functools.partial(bucket.score_batch, n_valid=n_valid)
             else:
                 # route each chunk to the shard owning its model: the
                 # stacked leading axis is split into n_shards contiguous
@@ -1889,6 +2054,11 @@ class ModelBank:
                 outs = jax.device_get(run.out)
             with stage("reassemble", *run.traces, parent=post) as reassemble:
                 slots = run.slots
+                observed = None
+                if len(outs) > 5:  # a bucket with shared leaves (_Bucket.score_batch)
+                    outs, layers = outs[:5], outs[5]
+                    observed = {k: np.stack([seen[k] for seen in layers]) for k in layers[0]}
+                    self._count_observed(run, observed)
                 for ri, X_conv, cis, valids, n_out in run.req_plans:
                     if len(cis) == 1:
                         vals = _slice_single(outs, slots[cis[0]], n_out)
@@ -1903,11 +2073,34 @@ class ModelBank:
                         total_unscaled=vals[3],
                         total_scaled=vals[4],
                         offset=run.off,
+                        selections=(
+                            None if observed is None else {
+                                "expert-selection": observed["experts"][
+                                    :, slots[cis[0]], : X_conv.shape[0]
+                                ].copy(),
+                                "key-selection": observed["witness"][:, slots[cis[0]]].copy(),
+                            }
+                        ),
                     )
             post.end = reassemble.end
             run.postprocess_s = post.duration_s
         finally:
             run.release(self.arena)
+
+    def _count_observed(self, run: _GroupRun, observed: Dict[str, np.ndarray]) -> None:
+        """Counters from the arrays a shared-leaf bucket's program returned
+        with this dispatch (executor thread, one dispatch at a time)."""
+        tokens = observed["expert_tokens"]  # (layers, experts), valid rows only
+        stats = self.shared_stats
+        for name, value in (
+            ("dispatches", 1),
+            ("rows", run.routed_rows),
+            ("tokens", run.total_rows),
+            ("expert_tokens", int(tokens.sum())),
+            ("expert_tokens_busiest", int(tokens.max(axis=-1).sum())),
+            ("key_selections", int(observed["selections"].sum())),
+        ):
+            stats[name] = stats.get(name, 0) + value
 
     def _account_group(
         self, run: _GroupRun, results: List[Any], window_s: float, ok: bool
@@ -2469,12 +2662,20 @@ class BatchingEngine:
             # deliberate flush window (``queue_flush``)
             taken = time.monotonic()
             deadline = taken + self.flush_s
-            while len(batch) < self.max_batch:
+            # a batch counts requests, except behind a request whose
+            # bucket bounds a call by its program's bytes: one week-long
+            # sequence is 10^4 rows and gigabytes of activations, and the
+            # third would only wait for a second call inside this batch
+            max_batch = self.max_batch
+            limit = getattr(self.bank, "batch_limit", None)
+            if limit is not None:
+                max_batch = min(max_batch, limit(first.name, len(first.X)) or max_batch)
+            while len(batch) < max_batch:
                 # drain whatever is already queued without arming a timer
                 # per item — wait_for's per-call timer handle was real
                 # heap churn in the coalesced hot loop (profiled round 5)
                 try:
-                    while len(batch) < self.max_batch:
+                    while len(batch) < max_batch:
                         batch.append(self._queue.get_nowait())
                     break
                 except asyncio.QueueEmpty:

@@ -109,10 +109,12 @@ def encode_anomaly_response(
 ) -> bytes:
     """``POST /anomaly/prediction`` tensor response: the six score arrays
     (``ScoreResult.to_arrays`` order) written into one preallocated body
-    — no DataFrame assembly, no per-column ``tolist``."""
+    — no DataFrame assembly, no per-column ``tolist``. Whatever else
+    ``arrays`` holds (a shared-trunk member's selections) follows them."""
     meta = _meta_frame({"offset": int(offset), "tags": [str(t) for t in tags]})
+    more = [name for name in arrays if name not in ANOMALY_FRAME_NAMES]
     return pack_frames(
-        [meta] + [(name, arrays[name]) for name in ANOMALY_FRAME_NAMES]
+        [meta] + [(name, arrays[name]) for name in (*ANOMALY_FRAME_NAMES, *more)]
     )
 
 

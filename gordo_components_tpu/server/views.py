@@ -813,6 +813,11 @@ async def server_stats(request: web.Request) -> web.Response:
             # member, models-per-GB, and any buckets whose quantization
             # fell back to fp32 (docs/observability.md contract)
             body["bank_capacity"] = capacity()
+        if getattr(bank, "shared_stats", None):
+            # what the buckets with shared leaves observed, dispatch by
+            # dispatch: rows and tokens, tokens routed an expert (sum,
+            # max), (query, key) selections made
+            body["bank_shared"] = dict(bank.shared_stats)
     quarantine = request.app.get("quarantine")
     if quarantine is not None:
         # the degraded-mode surface: which models the breaker evicted

@@ -342,3 +342,41 @@ def test_bucket_program_reads_only_the_members_it_scores(v5e, monkeypatch, bank,
         assert compiled.memory_analysis().argument_size_in_bytes < 1.15 * (
             members * bucket.params_per_member * 4
         )
+
+
+# --------------------------------------------------------------------- #
+# a bucket with shared leaves: the trunk's layer program
+# --------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("B", [1, 2])
+def test_trunk_layer_program_compiles_at_published_widths(v5e, B):
+    """One decoder layer of the shared trunk (128 experts of 768, top 8;
+    32/4 heads of 128; the indexer's top 2048) over B week-long requests
+    of 10 240 padded rows, for one chip: the three grouped matmuls and the
+    masked attention are Pallas kernels, and what the program needs beside
+    its arguments stays under the count the bank bounds its batch by."""
+    from gordo_components_tpu.models.factories.trunk import SparseMoEDecoder
+
+    module = SparseMoEDecoder(n_features=300, num_hidden_layers=6)
+    home = SingleDeviceSharding(v5e[0])
+    T = module.padded_rows(10080)
+    assert T == 10240
+    layer = {
+        name: jax.ShapeDtypeStruct(
+            shape, jnp.float32 if len(shape) == 1 else jnp.bfloat16, sharding=home
+        )
+        for name, shape in module.layer_shapes().items()
+    }
+    x = jax.ShapeDtypeStruct((B, T, module.hidden_size), jnp.float32, sharding=home)
+    n_valid = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=home)
+    compiled = jax.jit(
+        lambda w, x, n: module.layer(w, x, n, interpret=False)
+    ).lower(layer, x, n_valid).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= module.program_bytes(B, T), (temp, module.program_bytes(B, T))
+    # and the count is not so loose that a chip's worth of batch is refused
+    assert module.program_bytes(B, T) <= 1.6 * temp
+    weights = sum(np.prod(s.shape) * s.dtype.itemsize for s in layer.values())
+    assert 1.24e9 < weights < 1.26e9  # 625.4 M parameters a layer in bfloat16
