@@ -378,6 +378,77 @@ def make_seq_gang_epoch(
     return epoch_fn
 
 
+def make_dense_gang_epoch(step_fns: Tuple[Callable, Callable, Callable], batch_size: int):
+    """GANG epoch of a dense bucket whose step is ONE program over the
+    member axis (``ops/dense_step.make_step``): ``vmap(epoch_fn)``'s
+    replacement for the buckets that kernel takes.
+
+    ``epoch_fn(states, X, mask, active) -> (states, (M,) losses)`` over
+    STACKED state, X: (M, rows_pad, f), mask: (M, rows_pad) of 0/1,
+    ``active``: (M,) > 0 for members still training. Per member it is
+    ``make_train_fns``'s epoch under the bucket's ``active`` rule: the
+    same three splits of its rng, the same shuffle with padding sorted to
+    the end, the same scan over batches and the same row-weighted loss
+    mean; a frozen member keeps its whole state, rng included, and
+    reports NaN. Only the step differs: where the vmapped body calls
+    ``value_and_grad`` + ``optimizer.update`` + ``apply_updates`` on one
+    member, this body hands the gang's state to ``step``, which picks
+    batch ``t`` out of the shuffled rows itself (no slice is copied) and
+    leaves a member without real rows in that batch, or frozen, untouched.
+    The scan carries the state as the step takes it (``enter``, ``leave``).
+    """
+    enter, step_fn, leave = step_fns
+
+    def epoch_fn(states: TrainState, X, mask, active):
+        n_pad = mask.shape[1]
+        n_batches = n_pad // batch_size
+
+        def plan(rng, x, m):
+            rng2, perm_rng, _ = jax.random.split(rng, 3)
+            keys = jax.random.uniform(perm_rng, (n_pad,))
+            perm = jnp.argsort(jnp.where(m > 0, keys, 2.0))
+            # the sort packs the real rows first: batch t holds the next
+            # ``batch_size`` of them, as many as are left (no gather of m)
+            left = jnp.sum(m > 0) - batch_size * jnp.arange(n_batches)
+            counts = jnp.clip(left, 0, batch_size).astype(jnp.float32)
+            # a batch fills whole 8-row tiles (the extra rows lie beyond the
+            # count, like padding): the batches are then a view of the
+            # gathered rows, where 100-row batches are laid out a second time
+            batches = jnp.pad(
+                perm.reshape((n_batches, batch_size)), ((0, 0), (0, -batch_size % 8))
+            )
+            return rng2, x[batches], counts
+
+        rng2, Xs, counts = jax.vmap(plan)(states.rng, X, mask)
+
+        hyperparams = states.opt_state.hyperparams
+
+        def step(carry, batch):
+            t, n_real = batch
+            return step_fn(carry, hyperparams, Xs, t, n_real, active)
+
+        carry, losses = jax.lax.scan(
+            step, enter(states.params, states.opt_state),
+            (jnp.arange(n_batches), counts.T),
+        )
+        params, opt_state = leave(carry, states.opt_state)
+        mean_loss = jnp.sum(losses * counts.T, axis=0) / jnp.maximum(
+            jnp.sum(counts, axis=1), 1.0
+        )
+        act = active > 0
+        return (
+            TrainState(
+                params=params, opt_state=opt_state,
+                rng=jnp.where(
+                    act.reshape(act.shape + (1,) * (rng2.ndim - 1)), rng2, states.rng
+                ),
+            ),
+            jnp.where(act, mean_loss, jnp.nan),
+        )
+
+    return epoch_fn
+
+
 def make_seq_eval_fn(
     module,
     batch_size: int,

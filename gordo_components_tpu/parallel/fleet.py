@@ -44,6 +44,7 @@ from gordo_components_tpu.observability.tracing import (
     stage,
     use_trace,
 )
+from gordo_components_tpu.ops import dense_step
 from gordo_components_tpu.ops.seq_scan import (
     resolve_seq_layout,
     supports_time_major,
@@ -165,14 +166,23 @@ class _BucketPrograms:
         self, module, opt_name: str, lr: float, batch_size: int, seq=None,
         loss: str = "mse", kl_weight: float = 1.0,
         threshold_quantile: float = 1.0, layout: str = "legacy",
+        fused_step: Tuple[Optional[str], Optional[str]] = (None, None), mesh=None,
     ):
         self.module = module
         self.seq = seq
-        # the RESOLVED sequence layout (ops/seq_scan.resolve_seq_layout,
-        # resolved by _bucket_programs so it is part of the cache key):
-        # "time_major" routes run_epoch/chunk_fn through the gang epoch
-        # whose scan keeps members innermost; "legacy" is vmap(epoch).
-        self.layout = layout if seq is not None else "legacy"
+        # ops/dense_step.resolve's answer: how the fused step runs here, or
+        # why this bucket trains through another program (the fit span
+        # carries it)
+        step_mode, self.fused_step_refused = fused_step
+        # the RESOLVED epoch program (resolved by _bucket_programs so it is
+        # part of the cache key). Sequence buckets: "time_major" routes
+        # run_epoch/chunk_fn through the gang epoch whose scan keeps
+        # members innermost (ops/seq_scan.resolve_seq_layout). Dense
+        # buckets: "fused_step" through the gang epoch whose step is one
+        # Pallas program over the members (ops/dense_step, run as
+        # ``step_mode`` says, under shard_map where ``mesh`` spreads the
+        # gang over devices). "legacy" is vmap(epoch).
+        self.layout = "fused_step" if step_mode is not None else layout
         # inject=True: the learning rate lives in the (vmapped, stacked)
         # opt state, so _fit_bucket can overwrite it with a per-member
         # (M,) vector — members differing only in LR share this program
@@ -214,6 +224,26 @@ class _BucketPrograms:
                 return merged, jnp.where(act, losses, jnp.nan)
 
             self._vm_epoch = masked_gang
+        elif self.layout == "fused_step":
+            fused_epoch = train_core.make_dense_gang_epoch(
+                dense_step.make_step(module, step_mode), batch_size
+            )
+            if mesh is not None and mesh.shape[MODEL_AXIS] > 1:
+                # members are independent: each device steps its own block
+                # of the gang (under GSPMD alone the kernel's operands
+                # would be gathered to one device)
+                spec = jax.sharding.PartitionSpec(MODEL_AXIS)
+                fused_epoch = jax.shard_map(
+                    fused_epoch, mesh=mesh, in_specs=spec, out_specs=spec,
+                    check_vma=False,
+                )
+
+            # the name the device trace knows the epoch program by: jit
+            # calls it ``jit_masked_epoch_fused``
+            def masked_epoch_fused(states, X, mask, active):
+                return fused_epoch(states, X, mask, active)
+
+            self._vm_epoch = masked_epoch_fused
         else:
             self._vm_epoch = jax.vmap(masked_epoch)
         self.run_epoch = jax.jit(self._vm_epoch, donate_argnums=(0,))
@@ -620,28 +650,42 @@ def _count_program_build() -> None:
 def _bucket_programs(
     module, opt_name: str, lr: float, batch_size: int, seq=None,
     loss: str = "mse", kl_weight: float = 1.0, threshold_quantile: float = 1.0,
+    mesh=None,
 ) -> _BucketPrograms:
-    # the sequence layout is resolved HERE (not inside _BucketPrograms) so
+    # the epoch program is resolved HERE (not inside _BucketPrograms) so
     # it participates in the cache key — flipping GORDO_SEQ_LAYOUT between
     # fits must never return a program compiled for the other layout. The
-    # gang epoch understands exactly the LSTMStack/mse combination;
-    # everything else stays on the legacy vmapped layout.
+    # gang epoch understands exactly the LSTMStack/mse combination, the
+    # fused step exactly what ops/dense_step.resolve lets through (a pure
+    # function of module, loss, optimizer, shapes and the platform of the
+    # devices the gang will sit on); everything else stays on the legacy
+    # vmapped layout.
     layout = "legacy"
     if seq is not None and loss == "mse" and supports_time_major(module):
         layout = resolve_seq_layout()
+    platform = (
+        jax.default_backend() if mesh is None else mesh.devices.flat[0].platform
+    )
+    fused_step = dense_step.resolve(module, loss, opt_name, seq, batch_size, platform)
+    if fused_step[0] is None:
+        mesh = None  # only the fused step's program depends on it
     key = (
         module, opt_name, float(lr), int(batch_size), seq, loss,
-        float(kl_weight), float(threshold_quantile), layout,
+        float(kl_weight), float(threshold_quantile), layout, fused_step, mesh,
     )
+
+    def build() -> _BucketPrograms:
+        return _BucketPrograms(
+            module, opt_name, lr, batch_size, seq, loss, kl_weight,
+            threshold_quantile, layout, fused_step, mesh,
+        )
+
     with _PROGRAM_LOCK:
         try:
             prog = _PROGRAM_CACHE.get(key)
         except TypeError:  # unhashable factory kwargs: build uncached
             _count_program_build()
-            return _BucketPrograms(
-                module, opt_name, lr, batch_size, seq, loss, kl_weight,
-                threshold_quantile, layout,
-            )
+            return build()
         if prog is None:
             # LRU bound: a long-lived gang builder cycling many configs
             # keeps its hot programs warm instead of recompiling everything
@@ -649,10 +693,7 @@ def _bucket_programs(
             while len(_PROGRAM_CACHE) >= _PROGRAM_CACHE_MAX:
                 _PROGRAM_CACHE.popitem(last=False)
             _count_program_build()
-            prog = _PROGRAM_CACHE[key] = _BucketPrograms(
-                module, opt_name, lr, batch_size, seq, loss, kl_weight,
-                threshold_quantile, layout,
-            )
+            prog = _PROGRAM_CACHE[key] = build()
         else:
             _PROGRAM_CACHE.move_to_end(key)
     return prog
@@ -1144,10 +1185,11 @@ class FleetTrainer:
                     # process's first epochs include tracing and the
                     # compile or cache load, steady-state is the rest
                     "epoch_seconds": epoch_seconds,
-                    # which sequence layout the bucket's epoch program used
-                    # ("time_major" = gang scan, members innermost;
-                    # "legacy" = vmap(epoch); dense buckets are always
-                    # legacy) — resolved per program, recorded per bucket
+                    # which epoch program the bucket used ("time_major" =
+                    # LSTM gang scan, members innermost; "fused_step" =
+                    # dense gang whose step is one Pallas program;
+                    # "legacy" = vmap(epoch)) — resolved per program,
+                    # recorded per bucket
                     "layout": self._bucket_layout,
                     # the devices the bucket's stacked state sat on
                     "device": self._bucket_device,
@@ -1255,9 +1297,14 @@ class FleetTrainer:
             progs = _bucket_programs(
                 module, self.optimizer, self.learning_rate,
                 min(bs, padded_items), seq, loss, self.kl_weight,
-                self.threshold_quantile,
+                self.threshold_quantile, mesh=mesh,
             )
             self._bucket_layout = progs.layout
+            fit_span = self._trace_span[1]
+            if fit_span is not None:
+                fit_span.attributes["layout"] = progs.layout
+                if progs.fused_step_refused is not None:
+                    fit_span.attributes["fused_step_refused"] = progs.fused_step_refused
             self._bucket_device = device_block(Xd)
             init_stacked = progs.init_stacked
             run_epoch = progs.run_epoch

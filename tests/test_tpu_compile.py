@@ -380,3 +380,115 @@ def test_trunk_layer_program_compiles_at_published_widths(v5e, B):
     assert module.program_bytes(B, T) <= 1.6 * temp
     weights = sum(np.prod(s.shape) * s.dtype.itemsize for s in layer.values())
     assert 1.24e9 < weights < 1.26e9  # 625.4 M parameters a layer in bfloat16
+
+
+# --------------------------------------------------------------------- #
+# the dense gang's training step (ops/dense_step.py) in its epoch program
+# --------------------------------------------------------------------- #
+
+# benchmarks/configs/dense300.json: the default detector on a 300-tag
+# machine, chain 300-250-200-150-150-200-250-300, batch 100, 1440 rows
+# padded to 16 batches
+_REFIT = dict(tags=300, batch=100, rows=1600, local_members=640)
+
+
+def _epoch_program(v5e, chips, n_tags=_REFIT["tags"], members=None, **module_kw):
+    """``(programs, compiled epoch program)`` of a dense bucket whose gang
+    sits on ``chips`` described chips: ``_bucket_programs`` resolves the
+    step from the mesh's own devices, as ``FleetTrainer._fit_bucket`` asks
+    it to; shapes stand in for the stacked state."""
+    from gordo_components_tpu.parallel import fleet
+
+    mesh = Mesh(np.asarray(v5e[:chips]), (MODEL_AXIS,))
+    home = NamedSharding(mesh, P(MODEL_AXIS))
+    module = lookup_factory("AutoEncoder", "feedforward_hourglass")(
+        n_tags, compute_dtype="float32", **module_kw
+    )
+    progs = fleet._bucket_programs(
+        module, "adam", 1e-3, _REFIT["batch"], None, "mse", 1.0, 1.0, mesh=mesh
+    )
+    M = members or _REFIT["local_members"] * chips
+    on = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=home), tree
+    )
+    rngs = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), M))
+    states = jax.eval_shape(
+        progs.init_stacked, rngs, jax.ShapeDtypeStruct((M, n_tags), f32)
+    )
+    compiled = progs.run_epoch.lower(
+        on(states),
+        jax.ShapeDtypeStruct((M, _REFIT["rows"], n_tags), f32, sharding=home),
+        jax.ShapeDtypeStruct((M, _REFIT["rows"]), f32, sharding=home),
+        jax.ShapeDtypeStruct((M,), f32, sharding=home),
+    ).compile()
+    fleet._PROGRAM_CACHE.clear()  # a program for described devices serves no fit
+    return progs, compiled
+
+
+def _while_bodies(text):
+    """The scheduled module's ``while`` body computations, by name."""
+    names = set(re.findall(r"\bbody=(%[\w.\-]+)", text))
+    blocks = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+    return {b.split(" ", 1)[0]: b for b in blocks if b.split(" ", 1)[0] in names}
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_dense_step_compiles_inside_the_epoch_program(v5e, chips):
+    """ISSUE 30, at the refit cell's real shapes (640 members a chip): the
+    epoch program of a default-detector gang is a scan whose body is the one
+    custom call. No state leaf is copied or laid out again inside the scan
+    (a ``[640, ...]`` copy there would stream 2.6 GB a step); at the
+    program's two ends they are, once an epoch (the device's default layout
+    of a stacked leaf is member-minor, the kernel's blocks are a member's).
+    Over four chips the gang steps under ``shard_map``: no collective."""
+    progs, compiled = _epoch_program(v5e, chips)
+    assert (progs.layout, progs.fused_step_refused) == ("fused_step", None)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    _no_collectives(text)
+    bodies = _while_bodies(text)
+    assert any("tpu_custom_call" in body for body in bodies.values())
+    for body in bodies.values():
+        # beside the kernel (and the tuples around it) a step computes only
+        # per-member scalars: counts, block indices, eight floats a member,
+        # its loss. A state leaf there, copied or computed, is a pass over it
+        for line in body.splitlines():
+            m = _INSTRUCTION.match(_LAYOUT.sub("", line))
+            if not m or re.search(r" (parameter|get-tuple-element|tuple|bitcast)\(", line):
+                continue
+            if "%dense_train_step" in line.split(" = ")[0]:
+                continue
+            for dims in re.findall(r"\w+\[%d,([\d,]+)\]" % _REFIT["local_members"], m.group(1)):
+                assert np.prod([int(d) for d in dims.split(",")]) <= 128, line.strip()[:240]
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9  # of 16 GB
+
+
+@pytest.mark.parametrize("func", ["relu", "sigmoid", "elu", "softplus", "linear"])
+def test_dense_step_lowers_every_activation(v5e, func):
+    """What interpret mode cannot say: that the chip's kernel compiler has
+    each activation and its derivative (it has no ``expm1``: ops/dense_step
+    writes ``elu`` without it)."""
+    progs, compiled = _epoch_program(v5e, 1, members=8, func=func)
+    assert progs.layout == "fused_step"
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize(
+    "tags,why", [(4000, "MiB of VMEM"), (10, "member too narrow")], ids=["too_wide", "too_narrow"]
+)
+def test_member_the_kernel_does_not_pay_for_keeps_the_vmapped_epoch(v5e, tags, why):
+    """A 4000-tag hourglass is 59 M parameters a member: four blocks of its
+    state do not fit the kernel's VMEM budget. A 10-tag one (upstream's
+    examples, ``examples/fleet.yaml``) is 5 kB that would cross as 168 kB of
+    tiles, under a grid step that costs more than its whole vmapped step.
+    Either bucket resolves to ``vmap(epoch)`` and says why, instead of
+    failing in the compiler or training slower."""
+    from gordo_components_tpu.parallel import fleet
+
+    mesh = Mesh(np.asarray(v5e[:1]), (MODEL_AXIS,))
+    module = lookup_factory("AutoEncoder", "feedforward_hourglass")(tags)
+    progs = fleet._bucket_programs(module, "adam", 1e-3, 100, mesh=mesh)
+    fleet._PROGRAM_CACHE.clear()
+    assert progs.layout == "legacy"
+    assert why in progs.fused_step_refused
