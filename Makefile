@@ -6,7 +6,7 @@ PYTHON ?= python
 IMAGE_PREFIX ?= gordo-components-tpu
 TAG ?= latest
 
-.PHONY: test test-fast chaos chaos-deadline slo rebalance stream wire replay saturate mesh fleet history gameday heat qos seqperf hotloop perf-guard trace-demo slo-demo rebalance-demo stream-demo wire-demo replay-demo saturate-demo mesh-demo fleet-demo incident-demo gameday-demo capacity-demo qos-demo bench images builder-image server-image watchman-image clean
+.PHONY: test test-fast chaos chaos-deadline slo rebalance stream wire replay saturate mesh fleet history gameday heat qos seqperf hotloop perf-guard trace-demo slo-demo rebalance-demo stream-demo wire-demo replay-demo saturate-demo mesh-demo fleet-demo incident-demo gameday-demo capacity-demo qos-demo images builder-image server-image watchman-image clean
 
 test:
 	$(PYTHON) -m pytest tests/ -q
@@ -127,7 +127,7 @@ history:
 # partitioned / slowed on purpose, every failure judged end-to-end by
 # the SLO/incident stack (tests/test_gameday.py + the gate legs in
 # tests/test_fleet_compiler.py; the full 6-scenario catalog also runs
-# via `make gameday-demo` and bench.py's `gameday` leg)
+# via `make gameday-demo`)
 gameday:
 	$(PYTHON) -m pytest tests/ -q -m gameday --continue-on-collection-errors
 
@@ -207,7 +207,7 @@ rebalance-demo:
 # live-stream loop on a small fleet: inject a mean-shift drift -> watch
 # detection flag exactly the shifted members -> recalibrate (and refit)
 # through the zero-downtime swap -> FP rate drops; prints one JSON doc
-# (tools/stream_demo.py; bench.py's `streaming` leg runs the same tool)
+# (tools/stream_demo.py)
 stream-demo:
 	$(PYTHON) tools/stream_demo.py
 
@@ -219,31 +219,27 @@ wire-demo:
 # drives the same scoring batch over tcp, uds, and the shm ring through
 # the real multi-worker pool (parity-gated) and prints per-transport
 # rows/s + bytes/row, the in-process ceiling, the end-to-end gap ratio,
-# and push-mode windows/s (tools/saturate_demo.py; bench.py's
-# `serving_saturation` leg runs the same tool)
+# and push-mode windows/s (tools/saturate_demo.py)
 saturate-demo:
 	$(PYTHON) tools/saturate_demo.py
 
 # backtests the standard incident library through the real adaptive
 # loop at 100-1000x and prints the per-scenario verdict table +
-# one JSON doc (tools/replay_demo.py; bench.py's `replay` leg runs
-# the same tool)
+# one JSON doc (tools/replay_demo.py)
 replay-demo:
 	$(PYTHON) tools/replay_demo.py
 
 # true multi-process mesh: 2 partitioned server processes + a live
 # watchman routing table; prints single-vs-mesh rows/s (with cpu_count —
 # the parallel win needs real cores), fan-out per replica, and a live
-# cross-replica migration's zero-non-200 verdict (tools/mesh_demo.py;
-# bench.py's `mesh_serving` leg runs the same tool)
+# cross-replica migration's zero-non-200 verdict (tools/mesh_demo.py)
 mesh-demo:
 	$(PYTHON) tools/mesh_demo.py
 
 # compiles a fleet spec to the typed DAG, executes it end to end against
 # a live in-process server (build gangs -> place -> canary -> promote
 # under scoring traffic), then edits one machine and re-runs to show the
-# incremental recompile ratio; prints one JSON doc (tools/fleet_demo.py;
-# bench.py's `fleet_compile` leg runs the compile-side measurements)
+# incremental recompile ratio; prints one JSON doc (tools/fleet_demo.py)
 fleet-demo:
 	$(PYTHON) tools/fleet_demo.py
 
@@ -251,8 +247,7 @@ fleet-demo:
 # (quarantine) + a queue stall vs tight deadlines (SLO burn) under live
 # load, recovers, then asks a real watchman /incidents for the
 # correlated fault -> burn -> quarantine -> recovery timeline; prints
-# one JSON doc (tools/incident_demo.py; bench.py's `history` leg runs
-# the same tool)
+# one JSON doc (tools/incident_demo.py)
 incident-demo:
 	$(PYTHON) tools/incident_demo.py
 
@@ -261,8 +256,7 @@ incident-demo:
 # game-day catalog (SIGKILL crash/restart, watchman partition,
 # migration storm, gray failure, thundering herd, correlated drift)
 # under sustained scoring load, and prints the per-scenario verdict
-# table + one JSON doc (tools/gameday_demo.py; bench.py's `gameday`
-# leg runs a 3-scenario subset of the same tool)
+# table + one JSON doc (tools/gameday_demo.py)
 gameday-demo:
 	$(PYTHON) tools/gameday_demo.py
 
@@ -270,20 +264,16 @@ gameday-demo:
 # mixed dense/LSTM bank, reads GET /heat + GET /costs + bank capacity,
 # and prints the advisor tables (tier split, per-bucket MFU league,
 # projected members per HBM budget per dtype) + one JSON doc
-# (tools/capacity_demo.py; bench.py's `heat_cost` leg runs the same tool)
+# (tools/capacity_demo.py)
 capacity-demo:
 	$(PYTHON) tools/capacity_demo.py
 
 # best_effort flood vs a steady interactive probe through the real
 # serving stack (admission + weighted-fair engine + per-class SLO);
 # prints the per-class fairness table (admitted/shed, WFQ dequeues,
-# per-tenant goodput + burn) + one JSON doc (tools/qos_demo.py;
-# bench.py's `qos` leg runs the same tool)
+# per-tenant goodput + burn) + one JSON doc (tools/qos_demo.py)
 qos-demo:
 	$(PYTHON) tools/qos_demo.py
-
-bench:
-	$(PYTHON) bench.py
 
 images: builder-image server-image watchman-image
 

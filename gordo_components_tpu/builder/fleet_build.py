@@ -798,7 +798,7 @@ def _build_fleet_group(
     # host-side data loading (the IO hot loop, SURVEY.md §3.1). One process
     # feeds the whole gang here (SURVEY.md §7 hard part 2); stage_members
     # owns worker count and thread-vs-process engine selection
-    # (utils/staging.py) so builds and the bench measure the same path.
+    # (utils/staging.py).
     if heartbeat is not None:
         heartbeat.update(phase="loading", group_members=len(pending))
     t0 = time.time()

@@ -255,7 +255,7 @@ class Client:
         self.uds_path = uds_path
         self.shm_ring = shm_ring
         # resolved per run (predict_async): which rung actually carried
-        # the scoring chunks — bench/demo report this next to rows/s
+        # the scoring chunks — the demos report this next to rows/s
         self.transport_used = "tcp"
         self._shm_client = None
         self._data_session = None  # UDS session for scoring POSTs
@@ -264,8 +264,8 @@ class Client:
         # flight on them, and an immediate close would turn their clean
         # ClientConnectionError into an unhandled "Session is closed"
         self._dead_sessions: List[Any] = []
-        # per-encoding wire accounting (bench's bytes-per-row legs +
-        # gordo_client_request_bytes_total): body bytes out and rows
+        # per-encoding wire accounting (gordo_client_request_bytes_total):
+        # body bytes out and rows
         # posted for every scoring POST that got a 2xx back
         self._wire_stats: Dict[str, Dict[str, int]] = {}
         self._metadata_all: Dict[str, Any] = {}
@@ -287,9 +287,8 @@ class Client:
 
     def _register_metrics(self) -> None:
         """Read-through exposition of the client's overload-citizenship
-        counters in the process registry (the same cells bench snapshots
-        into BENCH_DETAIL.json). Weakref: the process registry must not
-        pin a discarded client. Series are labeled by the client's rid
+        counters in the process registry. Weakref: the process registry
+        must not pin a discarded client. Series are labeled by the client's rid
         prefix and registered under a per-instance key, so two clients
         in one process (one per project, or a fresh client per run)
         neither replace each other's collectors nor emit colliding
@@ -380,8 +379,8 @@ class Client:
     @property
     def wire_stats(self) -> Dict[str, Dict[str, int]]:
         """Per-encoding wire accounting: POSTs, body bytes out, and rows
-        for every scoring chunk that succeeded — bytes/row per encoding
-        is what the bench's ``client_bulk`` leg records."""
+        for every scoring chunk that succeeded (bytes/row per encoding
+        is bytes over rows)."""
         return {enc: dict(st) for enc, st in self._wire_stats.items()}
 
     @staticmethod
@@ -1609,7 +1608,7 @@ class Client:
                     )
                     # its own bucket: mixing ingest traffic into the
                     # scoring "tensor" cell would skew the bytes-per-row
-                    # comparison the bench legs read
+                    # comparison
                     self._note_wire("ingest-tensor", len(data), len(chunk))
                 else:
                     rows = [

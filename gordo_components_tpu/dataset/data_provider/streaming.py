@@ -4,8 +4,8 @@ The reference system's real workload is a continuous sensor stream
 (InfluxDB-backed ``TimeSeriesDataset``); this repo's serving side grew a
 streaming ingestion plane (``gordo_components_tpu/streaming/``) and a
 time-compressed replay harness (``gordo_components_tpu/replay/``) that
-need a deterministic live source to drive tests, demos, and the bench
-``streaming``/``replay`` legs without a broker in the image.
+need a deterministic live source to drive tests and demos without a
+broker in the image.
 
 :class:`SimulatedLiveProvider` wraps :class:`RandomDataProvider`'s
 per-tag sine generator (so data "streamed" for a time range is the same

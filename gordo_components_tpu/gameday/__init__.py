@@ -18,8 +18,7 @@ the observability stack that production would use:
   subscribers re-attach.
 
 Verdicts share the replay harness's envelope
-(``replay/verdict.py``: ``failures``/``passed``), land in
-``BENCH_DETAIL.json`` via bench's ``gameday`` leg, and the worst
+(``replay/verdict.py``: ``failures``/``passed``), and the worst
 scenarios gate fleet promotion (``gameday/gate.py`` + the ``gameday``
 step kind in ``workflow/compiler.py``).
 

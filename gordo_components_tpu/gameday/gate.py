@@ -22,7 +22,7 @@ catalog has a gate-mode drill here, run through public surfaces only:
   land on it, and the probes must stay all-200.
 
 Verdicts use the shared envelope (``replay/verdict.py``), so the fleet
-report, BENCH_DETAIL and the full harness all read the same way. The
+report and the full harness read the same way. The
 executor maps a failed gate to a failed step, which blocks promote via
 the ordinary dependency propagation (``workflow/executor.py``).
 

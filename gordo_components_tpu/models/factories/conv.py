@@ -20,8 +20,7 @@ conv fleet's below-parity culprit, VERDICT r3 weak #1), single builds
 4.7-8.2x, across bf16/f32 and channels (16,8)..(64,32). It is also the
 MXU-native formulation: the systolic array runs matmuls, and
 tiny-channel convs tile poorly. ``conv_impl="lax"`` keeps the stock
-ops; bench.py A/Bs both on whatever backend it runs
-(``conv_matmul_impl_vs_lax``).
+ops; neither has a rate on the chip yet (ROADMAP.md, Design 5).
 """
 
 import os
@@ -185,8 +184,7 @@ def conv1d_autoencoder(
     conv_impl: Optional[str] = None,
     **_ignored,
 ) -> Conv1DAutoEncoder:
-    # default impl: the matmul formulation the bench measures at 3.55x
-    # (``conv_matmul_impl_vs_lax``). ``GORDO_CONV_IMPL=lax`` flips the
+    # default impl: the matmul formulation. ``GORDO_CONV_IMPL=lax`` flips the
     # DEFAULT back to the stock lax ops (escape hatch; parity pinned by
     # tests/test_conv_impl.py) — an explicit ``conv_impl`` kwarg always
     # wins, and a pickled estimator pins whichever impl built it.

@@ -1,7 +1,7 @@
 """Unified fleet metrics layer.
 
 One dependency-free registry abstraction shared by every long-running
-process (serving, fleet builder, watchman, bench): label-aware Counter /
+process (serving, fleet builder, watchman): label-aware Counter /
 Gauge / log-binned Histogram primitives with Prometheus text-format
 exposition and a JSON snapshot view, so the human-readable ``/stats``
 endpoint and the ``/metrics`` scrape endpoint read the same underlying

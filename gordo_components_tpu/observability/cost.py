@@ -1,7 +1,7 @@
 """Per-bucket device-cost attribution: FLOPs, MFU, and pad waste.
 
-The real-TPU bench reports one headline MFU for the whole fleet; this
-module attributes it. An analytic per-architecture forward-FLOPs model
+The benchmark reports one MFU for a whole cell (``mfu.serve``); this
+module attributes it to buckets. An analytic per-architecture forward-FLOPs model
 (dense AE / LSTM / conv1d, from the bucket's config shapes, computed
 once at bucket build) is multiplied by the goodput ledger's MEASURED
 per-bucket device seconds and real-vs-padded row split to yield, per
@@ -55,8 +55,9 @@ __all__ = [
     "merge_cost_snapshots",
 ]
 
-# Dense bf16 peak FLOP/s per chip (public spec sheets) — same table the
-# bench uses; duplicated here so the serving path never imports bench.py.
+# Dense bf16 peak FLOP/s per chip (public spec sheets). The benchmark
+# keeps its own (benchmarks/peaks.json): the serving path imports nothing
+# of it.
 PEAK_BF16_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,  # v5e

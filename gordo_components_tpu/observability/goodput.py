@@ -202,7 +202,7 @@ class GoodputLedger:
         qos_class: str = "interactive",
     ) -> None:
         """Classify one finished scoring request (event loop; the server
-        middleware calls this — bench/north-star drive it directly).
+        middleware calls this — the north-star check drives it directly).
 
         goodput: status < 400 with finite scores. expired: 504 (the
         deadline ran out — before dispatch the common case, after
@@ -265,8 +265,8 @@ class GoodputLedger:
         return (self.device_padded_s / total) if total > 0 else None
 
     def snapshot(self) -> Dict[str, Any]:
-        """JSON view (served in ``/stats`` as ``goodput``; bench and the
-        north-star check record it). The SAME derivations the registry
+        """JSON view (served in ``/stats`` as ``goodput``; the north-star
+        check records it). The SAME derivations the registry
         collector renders, so the two surfaces cannot drift."""
         device_total = self._device_total_s()
         ratio = self.goodput_ratio()

@@ -5,11 +5,10 @@ sidecars for per-pod visibility (SURVEY.md §5); the in-process bank/gang
 rebuild has to carry its own metrics instead. This module is the one
 primitive layer every long-running process threads through: the serving
 stack (per-shard router counters, per-bucket coalescing histograms), the
-fleet builder (compile counts/seconds, members-trained progress), watchman
-(fleet-wide rollup), and bench (registry snapshots into BENCH_DETAIL).
+fleet builder (compile counts/seconds, members-trained progress) and
+watchman (fleet-wide rollup).
 
-Hot-path contract (the 839k samples/s north-star serving loop must not
-notice it):
+Hot-path contract (the serving loop must not notice it):
 
 - ``Counter.inc`` / ``Gauge.set`` are plain attribute writes on a
   ``__slots__`` object — no locks, no allocation per record;
@@ -466,7 +465,7 @@ class MetricsRegistry:
         return out
 
 
-# process-default registry: builder/bench processes record here without
+# process-default registry: builder processes record here without
 # plumbing; the server builds a per-app registry instead (tests run many
 # apps per process, and their series must not bleed together)
 _DEFAULT = MetricsRegistry()

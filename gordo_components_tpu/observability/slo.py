@@ -14,8 +14,8 @@ burn rate::
 on a 5m window is the canonical "page now" fast burn. The 5m window is
 the FAST signal (reacts in minutes, noisy), 1h/6h the SLOW confirmation
 (smooth, laggy) — the standard multi-window pattern, computed here
-without a Prometheus server in the loop so bench, the north-star check,
-and the chaos suite can assert on burn rates in-process.
+without a Prometheus server in the loop so the north-star check and the
+chaos suite can assert on burn rates in-process.
 
 Objectives (env ``GORDO_SLO_OBJECTIVES``, JSON; see DEFAULT_OBJECTIVES):
 
@@ -37,7 +37,7 @@ return byte-identical numbers between samples; the acceptance test
 asserts exactly that.
 
 Threading: ``sample``/``snapshot`` take a lock (they run on the event
-loop, the registry render path, and bench's driver thread); nothing here
+loop, the registry render path, and a driver's thread); nothing here
 is on the scoring hot path.
 """
 

@@ -565,7 +565,7 @@ def _env_float(name: str, default: float) -> float:
         raise ValueError(f"{name} must be a number, got {raw!r}") from None
 
 
-# process-default tracer (builder/bench processes trace without plumbing;
+# process-default tracer (builder processes trace without plumbing;
 # the server builds a per-app tracer, same split as the metrics registry)
 _DEFAULT: Optional[Tracer] = None
 

@@ -27,8 +27,8 @@ hidden half only); gate math is the flax cell's exactly::
     h' = sigmoid(o) * tanh(c')
 
 so the time-major forward matches ``vmap(module.apply)`` to fp32 rounding
-(matmul re-association only — the parity band is pinned by
-tests/test_seq_fastpath.py).
+(matmul re-association only: bitwise for a two-layer stack, 4e-9 absolute
+for four layers; tests/test_seq_fastpath.py pins both).
 
 Two env knobs, resolved ONCE per compiled program (never per call):
 

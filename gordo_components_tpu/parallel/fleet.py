@@ -71,7 +71,7 @@ logger = logging.getLogger(__name__)
 # ---- per-bucket jit'd programs, cached process-wide -------------------- #
 # A fresh fit() must not retrace/recompile programs an earlier fit already
 # built for the same (architecture, optimizer config, batch size): repeated
-# builds (warmup -> bench, build-cache reruns, server-side refits) hit the
+# builds (warm-up, build-cache reruns, server-side refits) hit the
 # jit cache through these shared function objects. Flax modules are frozen
 # dataclasses, so equal-config modules hash equal and share an entry.
 
@@ -100,21 +100,16 @@ def _set_stacked_lr(states, lr_vec):
     return states._replace(opt_state=os_._replace(hyperparams=hp))
 
 
-def _select_improved(improved, best_tree, new_tree):
+@jax.jit
+def _merge_best(best_p, new_p, improved):
     """Per-model select: where ``improved`` (M,) is set, take the new
-    leaves; else keep the best-so-far. Shared by the per-epoch host loop
-    and the on-device chunk body so the two ES engines cannot diverge."""
+    leaves; else keep the best-so-far."""
 
     def sel(b, n):
         shape = (-1,) + (1,) * (n.ndim - 1)
         return jnp.where(improved.reshape(shape) > 0, n, b)
 
-    return jax.tree.map(sel, best_tree, new_tree)
-
-
-@jax.jit
-def _merge_best(best_p, new_p, improved):
-    return _select_improved(improved, best_p, new_p)
+    return jax.tree.map(sel, best_p, new_p)
 
 
 # Bin count for the streaming-quantile histograms of the sequence error
@@ -176,7 +171,7 @@ class _BucketPrograms:
         step_mode, self.fused_step_refused = fused_step
         # the RESOLVED epoch program (resolved by _bucket_programs so it is
         # part of the cache key). Sequence buckets: "time_major" routes
-        # run_epoch/chunk_fn through the gang epoch whose scan keeps
+        # run_epoch through the gang epoch whose scan keeps
         # members innermost (ops/seq_scan.resolve_seq_layout). Dense
         # buckets: "fused_step" through the gang epoch whose step is one
         # Pallas program over the members (ops/dense_step, run as
@@ -272,8 +267,7 @@ class _BucketPrograms:
                 loss=loss, kl_weight=kl_weight,
             )
 
-        self._vm_eval = jax.vmap(member_val_loss)
-        self.eval_stacked = jax.jit(self._vm_eval)
+        self.eval_stacked = jax.jit(jax.vmap(member_val_loss))
         self.threshold_quantile = float(threshold_quantile)
         self.fit_error_scalers = (
             self._make_error_scalers(module, threshold_quantile)
@@ -282,7 +276,6 @@ class _BucketPrograms:
                 module, batch_size, *seq, q=threshold_quantile
             )
         )
-        self._chunks: Dict[Tuple, Any] = {}
 
     @property
     def threshold_method(self) -> str:
@@ -463,85 +456,6 @@ class _BucketPrograms:
             return jax.vmap(one)(params, X, mask)
 
         return fit_error_scalers
-
-    def chunk_fn(self, K: int, es_enabled: bool, delta, use_val: bool = False):
-        """K-epoch device chunk with (optional) on-device early stopping,
-        monitoring validation loss when ``use_val`` (members without val
-        rows fall back to train loss, as BaseEstimator.fit effectively
-        does). The patience RESET value arrives as a traced (M,) vector
-        argument (``p0v``), not a static constant — members with
-        different patience share one compile, and per-member ES patience
-        costs nothing."""
-        # ES-off programs ignore delta: normalize it out of the key so
-        # trainers differing only in unused ES knobs share the compile
-        key = (
-            (K, True, float(delta), bool(use_val))
-            if es_enabled
-            else (K, False, 0.0, bool(use_val))
-        )
-        if key not in self._chunks:
-            vm_epoch = self._vm_epoch
-            vm_eval = self._vm_eval
-
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def run_chunk(carry, X, mask, val_mask, p0v):
-                # body closes over run_chunk's traced X/mask args — NOT
-                # outer device arrays, which jit would bake in as constants.
-                # Each epoch emits (loss, val_loss, pre-epoch active) so the
-                # host can tell "was inactive" apart from "active but NaN
-                # loss".
-                def epoch_losses(st2, losses, act):
-                    """(train, val, monitored) for the finished epoch."""
-                    if not use_val:
-                        return losses, jnp.full_like(losses, jnp.nan), losses
-                    vals = vm_eval(st2.params, X, val_mask)
-                    vals = jnp.where(act > 0, vals, jnp.nan)
-                    has_val = jnp.sum(val_mask, axis=1) > 0
-                    return losses, vals, jnp.where(has_val, vals, losses)
-
-                if es_enabled:
-
-                    def body(c, _):
-                        st, act, bst, pat, bp, seeded = c
-                        act_pre = act
-                        st2, losses = vm_epoch(st, X, mask, act)
-                        losses, vals, monitored = epoch_losses(st2, losses, act)
-                        improved = (monitored < bst - delta) & (act > 0)
-                        bst = jnp.where(improved, monitored, bst)
-                        # first epoch of a fresh run seeds best_params with
-                        # the post-epoch params for EVERY member (even
-                        # non-improving, e.g. NaN loss) — parity with the
-                        # per-epoch loop's unconditional first-epoch copy
-                        select = jnp.maximum(
-                            improved.astype(jnp.float32), 1.0 - seeded
-                        )
-                        bp = _select_improved(select, bp, st2.params)
-                        pat = jnp.where(
-                            improved,
-                            p0v.astype(jnp.int32),
-                            pat - (act > 0).astype(jnp.int32),
-                        )
-                        act = jnp.where(
-                            (pat <= 0) & ~improved, 0.0, act
-                        ).astype(jnp.float32)
-                        return (st2, act, bst, pat, bp, jnp.float32(1.0)), (
-                            losses,
-                            vals,
-                            act_pre,
-                        )
-
-                else:
-
-                    def body(c, _):
-                        st, act, bst, pat = c
-                        st2, losses = vm_epoch(st, X, mask, act)
-                        losses, vals, _ = epoch_losses(st2, losses, act)
-                        return (st2, act, bst, pat), (losses, vals, act)
-
-                return jax.lax.scan(body, carry, None, length=K)
-
-            self._chunks[key] = run_chunk
-        return self._chunks[key]
 
 
 def quantize_batch_count(n: int) -> int:
@@ -843,7 +757,6 @@ class FleetTrainer:
         checkpoint_dir: Optional[str] = None,
         checkpoint_every: int = 1,
         epoch_callback=None,
-        host_sync_every: int = 1,
         quantize_rows: bool = True,
         quantize_members: bool = True,
         input_scaler: str = "minmax",
@@ -863,6 +776,13 @@ class FleetTrainer:
             raise ValueError(
                 f"model_type must be one of {sorted(_MODEL_TYPES)}, "
                 f"got {model_type!r}"
+            )
+        if "host_sync_every" in factory_kwargs:
+            # the factories ignore keywords they do not know, so a caller
+            # of the removed option would otherwise train on unaware
+            raise TypeError(
+                "FleetTrainer has no option 'host_sync_every': every epoch "
+                "is one dispatch; drop the argument"
             )
         self.model_type = model_type
         default_kind, default_lb = _family_defaults(model_type)
@@ -919,17 +839,7 @@ class FleetTrainer:
         self.checkpoint_dir = checkpoint_dir
         self.checkpoint_every = max(1, int(checkpoint_every))
         # epoch_callback(info_dict) after every epoch: progress/metrics hook
-        # (with host_sync_every > 1, called once per chunk with the chunk's
-        # last epoch)
         self.epoch_callback = epoch_callback
-        # >1 = bounded-epoch chunks: K epochs per XLA dispatch with early
-        # stopping evaluated on device; the host syncs once per chunk.
-        # Early-stopped models may run up to K-1 extra (masked) epochs, ES
-        # comparisons run in f32 instead of f64, and checkpoints/callbacks
-        # can only land at chunk boundaries (an effective cadence of
-        # max(checkpoint_every, host_sync_every) epochs) — throughput for
-        # exact per-epoch host control (SURVEY.md §7 hard part 4).
-        self.host_sync_every = int(host_sync_every)
         # bucket members on the batch-count ladder (see
         # quantize_batch_count) instead of exact padded row counts
         self.quantize_rows = bool(quantize_rows)
@@ -1011,10 +921,9 @@ class FleetTrainer:
     ) -> Dict[str, FleetMemberModel]:
         t0 = time.time()
         # fleet-build progress, published to the process metrics registry
-        # (observability/): a gang builder has no HTTP surface, but bench
-        # snapshots the registry and watchman-adjacent tooling can read it
-        # from NORTH_STAR/BENCH artifacts — and the gauges cost one set()
-        # per bucket/epoch, nothing per step
+        # (observability/): a gang builder has no HTTP surface, but tools
+        # in its process snapshot the registry — and the gauges cost one
+        # set() per bucket/epoch, nothing per step
         reg = get_registry()
         self._g_members_total = reg.gauge(
             "gordo_fleet_members_total", "Members in the current fleet fit"
@@ -1444,9 +1353,10 @@ class FleetTrainer:
                         self.validation_split,
                         self.seed,
                         int(mesh.shape[MODEL_AXIS]),
-                        # sync width changes the ES decision engine (device f32
-                        # vs host f64): a resume must not mix the two
-                        max(1, int(self.host_sync_every)),
+                        # the place of an option that is gone, at the one
+                        # value the product gave it: checkpoints written
+                        # before its removal stay resumable
+                        1,
                     ],
                     # content hash per member (streamed, pre-padding): same-shaped
                     # but different data must not resume
@@ -1550,133 +1460,76 @@ class FleetTrainer:
                     saving.error = True
 
         epoch_times: List[float] = []
-        sync = max(1, int(self.host_sync_every))
-
-        def after_epochs(first_epoch, losses_rows, vals_rows, active_rows):
-            """Host bookkeeping shared by both loop shapes: histories from
-            (k, M) loss rows + pre-epoch active rows (a model that was
-            active records its loss even if that loss is NaN — divergence
-            must stay visible in the history), callback, checkpoint."""
-            for row, vrow, act_row in zip(losses_rows, vals_rows, active_rows):
-                for i in range(M):
-                    if act_row[i] > 0:
-                        histories[i].append(float(row[i]))
-                        if use_val and has_val[i]:
-                            histories_val[i].append(float(vrow[i]))
-            last = first_epoch + len(losses_rows) - 1
-            self._g_members_active.set(int((active > 0).sum()))
-            if self.epoch_callback is not None:
-                self.epoch_callback(
-                    {
-                        "n_features": n_features,
-                        "padded_rows": padded_rows,
-                        "epoch": last,
-                        "losses": np.asarray(losses_rows[-1])[: len(names)],
-                        "n_active": int((active > 0).sum()),
-                    }
+        for epoch in range(start_epoch, self.epochs):
+            with self._stage("epoch", epoch=epoch) as dispatched:
+                active_pre = active
+                states, losses = run_epoch(
+                    states, Xd, train_maskd, jnp.asarray(active)
                 )
-            crossed = (last + 1) // self.checkpoint_every > first_epoch // self.checkpoint_every
-            if ckpt is not None and crossed and last + 1 < self.epochs:
-                save_checkpoint(last)
-
-        if sync == 1:
-            for epoch in range(start_epoch, self.epochs):
-                with self._stage("epoch", epoch=epoch) as dispatched:
-                    active_pre = active
-                    states, losses = run_epoch(
-                        states, Xd, train_maskd, jnp.asarray(active)
+                losses = np.asarray(losses)
+                if use_val:
+                    vals = np.asarray(
+                        progs.eval_stacked(states.params, Xd, val_maskd)
                     )
-                    losses = np.asarray(losses)
-                    if use_val:
-                        vals = np.asarray(
-                            progs.eval_stacked(states.params, Xd, val_maskd)
-                        )
-                        vals = np.where(active_pre > 0, vals, np.nan)
-                        monitored = np.where(has_val, vals, losses)
+                    vals = np.where(active_pre > 0, vals, np.nan)
+                    monitored = np.where(has_val, vals, losses)
+                else:
+                    vals = np.full_like(losses, np.nan)
+                    monitored = losses
+            epoch_times.append(dispatched.seconds)
+            with self._stage("epoch_host", epoch=epoch):
+                if es_enabled:
+                    improved = (
+                        monitored < best - self.early_stopping_min_delta
+                    ) & (active > 0)
+                    best = np.where(improved, monitored, best)
+                    if best_params is None:
+                        best_params = jax.tree.map(jnp.copy, states.params)
                     else:
-                        vals = np.full_like(losses, np.nan)
-                        monitored = losses
-                epoch_times.append(dispatched.seconds)
-                with self._stage("epoch_host", epoch=epoch):
-                    if es_enabled:
-                        improved = (
-                            monitored < best - self.early_stopping_min_delta
-                        ) & (active > 0)
-                        best = np.where(improved, monitored, best)
-                        if best_params is None:
-                            best_params = jax.tree.map(jnp.copy, states.params)
-                        else:
-                            best_params = _merge_best(
-                                best_params, states.params,
-                                jnp.asarray(improved, jnp.float32),
-                            )
-                        patience = np.where(
-                            improved, p0_vec, patience - (active > 0)
+                        best_params = _merge_best(
+                            best_params, states.params,
+                            jnp.asarray(improved, jnp.float32),
                         )
-                        # patience=0 parity with BaseEstimator.fit: a model
-                        # stops only after a NON-improving epoch exhausts
-                        # patience — an epoch that just improved (patience
-                        # reset) keeps going.
-                        after = np.where(
-                            (patience <= 0) & ~improved, 0.0, active
-                        ).astype(np.float32)
-                        active = after
-                    after_epochs(epoch, [losses], [vals], [active_pre])
-                if es_enabled and not active.any():
-                    logger.info(
-                        "All %d models early-stopped at epoch %d", M, epoch + 1
+                    patience = np.where(
+                        improved, p0_vec, patience - (active > 0)
                     )
-                    break
-        else:
-            # ---- bounded-epoch chunks (SURVEY.md §7 hard part 4): K epochs
-            # per dispatch with early stopping evaluated ON DEVICE, so the
-            # host syncs once per chunk instead of once per epoch ----
-            delta = float(self.early_stopping_min_delta)
-            p0_dev = jnp.asarray(p0_vec, jnp.int32)
-
-            def get_chunk_fn(K: int):
-                # carry WITHOUT best-params when ES is off: carrying an
-                # alias of st.params alongside st would break donation
-                return progs.chunk_fn(K, es_enabled, delta, use_val=use_val)
-
-            seeded = jnp.float32(0.0 if best_params is None else 1.0)
-            if es_enabled and best_params is None:
-                best_params = jax.tree.map(jnp.copy, states.params)
-            carry = (
-                states,
-                jnp.asarray(active, jnp.float32),
-                jnp.asarray(best, jnp.float32),
-                jnp.asarray(patience, jnp.int32),
-            )
-            if es_enabled:
-                carry = carry + (best_params, seeded)
-            epoch = start_epoch
-            while epoch < self.epochs:
-                K = min(sync, self.epochs - epoch)
-                with self._stage("epoch", epoch=epoch, epochs=K) as dispatched:
-                    carry, (losses_k, vals_k, act_k) = get_chunk_fn(K)(
-                        carry, Xd, train_maskd, val_maskd, p0_dev
+                    # patience=0 parity with BaseEstimator.fit: a model
+                    # stops only after a NON-improving epoch exhausts
+                    # patience — an epoch that just improved (patience
+                    # reset) keeps going.
+                    active = np.where(
+                        (patience <= 0) & ~improved, 0.0, active
+                    ).astype(np.float32)
+                # histories: a model that was active records its loss even
+                # if that loss is NaN — divergence must stay visible
+                for i in range(M):
+                    if active_pre[i] > 0:
+                        histories[i].append(float(losses[i]))
+                        if use_val and has_val[i]:
+                            histories_val[i].append(float(vals[i]))
+                n_active = int((active > 0).sum())
+                self._g_members_active.set(n_active)
+                if self.epoch_callback is not None:
+                    self.epoch_callback(
+                        {
+                            "n_features": n_features,
+                            "padded_rows": padded_rows,
+                            "epoch": epoch,
+                            "losses": losses[: len(names)],
+                            "n_active": n_active,
+                        }
                     )
-                    losses_k = np.asarray(losses_k)  # (K, M)
-                    vals_k = np.asarray(vals_k)  # (K, M) val losses (NaN when off)
-                    act_k = np.asarray(act_k)  # (K, M) pre-epoch active masks
-                epoch_times.extend([dispatched.seconds / K] * K)
-                with self._stage("epoch_host", epoch=epoch):
-                    # host snapshots for checkpoint/break bookkeeping
-                    states = carry[0]
-                    active = np.asarray(carry[1])
-                    best = np.asarray(carry[2], np.float64)
-                    patience = np.asarray(carry[3], np.int64)
-                    if es_enabled:
-                        best_params = carry[4]  # (seeded flag rides at carry[5])
-                    after_epochs(epoch, list(losses_k), list(vals_k), list(act_k))
-                epoch += K
-                if es_enabled and not active.any():
-                    logger.info(
-                        "All %d models early-stopped by epoch %d", M, epoch
-                    )
-                    break
-            states = carry[0]
+                if (
+                    ckpt is not None
+                    and (epoch + 1) % self.checkpoint_every == 0
+                    and epoch + 1 < self.epochs
+                ):
+                    save_checkpoint(epoch)
+            if es_enabled and not active.any():
+                logger.info(
+                    "All %d models early-stopped at epoch %d", M, epoch + 1
+                )
+                break
 
         if ckpt is not None:
             # commit the in-flight async save: a preemption during the

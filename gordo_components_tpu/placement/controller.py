@@ -67,7 +67,7 @@ class PlacementController:
             default_threshold() if threshold is None else float(threshold)
         )
         # don't plan on noise: a handful of warm-up requests is not a
-        # traffic distribution (tests and the bench fixture set it low)
+        # traffic distribution (tests set it low)
         self.min_rows = (
             _env_num("GORDO_REBALANCE_MIN_ROWS", 4096, int)
             if min_rows is None

@@ -212,7 +212,7 @@ def swap_bank(
         elif len(new_bank) and _loop_running():
             # first generation with bankable members: the engine starts
             # here (the same path build_app's startup hook uses). Only
-            # on an event loop — bench/north-star drive the swap
+            # on an event loop — the north-star check drives the swap
             # synchronously against a bare bank and own their engines.
             cfg = app.get("bank_config") or {}
             engine = BatchingEngine(

@@ -3,9 +3,8 @@
 Two harnesses judge the system by scenario: the replay engine (PR 12 —
 recorded incident timelines against one in-process server) and the mesh
 game days (``gameday/`` — injected mesh failures against a live
-multi-process fleet). Both emit the SAME verdict envelope so
-``BENCH_DETAIL.json`` consumers, the CI lanes, and the fleet compiler's
-promotion gate read one shape:
+multi-process fleet). Both emit the SAME verdict envelope so the CI
+lanes and the fleet compiler's promotion gate read one shape:
 
 - ``schema``: :data:`VERDICT_SCHEMA`;
 - ``scenario`` / ``description``: which drill this was;

@@ -138,8 +138,8 @@ async def _stats_middleware(request, handler):
             # per-encoding data-plane accounting (stability contract:
             # gordo_server_requests_total{encoding} + request_bytes_total):
             # which wire format the fleet's clients actually negotiate, and
-            # the bytes each moves — the numbers the tensor-vs-JSON bench
-            # legs and the bytes-per-row dashboards read. ONE classification
+            # the bytes each moves — the numbers the bytes-per-row
+            # dashboards read. ONE classification
             # rule shared with the scoring handlers (utils/wire.py), so the
             # metrics can never disagree with the path a request took.
             from gordo_components_tpu.utils.wire import encoding_of

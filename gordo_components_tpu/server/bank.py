@@ -1363,8 +1363,7 @@ class ModelBank:
 
     def capacity_stats(self) -> Dict[str, Any]:
         """Operator-facing HBM capacity summary (served in ``/stats`` as
-        ``bank_capacity``; bench and the north-star check record it so
-        the models-per-GB trajectory is auditable).
+        ``bank_capacity``; the north-star check records it).
 
         ``weight_bytes`` is the stacked params' storage footprint at the
         effective dtype mix; ``fp32_bytes`` the same stack at fp32 —
@@ -1427,8 +1426,7 @@ class ModelBank:
 
     def pipeline_stats(self) -> Dict[str, Any]:
         """Operator-facing pipeline/arena summary (served in ``/stats``
-        as ``bank_pipeline``; bench and the north-star check snapshot it
-        so the overlap trajectory is auditable)."""
+        as ``bank_pipeline``; the north-star check snapshots it)."""
         pipe = self._pipe
         wall = pipe["wall_s"]
         return {

@@ -18,10 +18,9 @@ def parquet_engine() -> Optional[str]:
     Resolved ONCE and passed explicitly to every per-chunk
     ``to_parquet``/``read_parquet`` call, skipping pandas' per-call
     ``engine="auto"`` resolution (measured as a first-chunks cold-start
-    cost: ~2.4x on a cold process, noise once warm). The BENCH_r05
-    ``client_parquet_vs_json: 0.98`` regression itself root-caused to
-    the RESPONSE side staying JSON in both modes — see
-    docs/architecture.md "Wire protocol" for the measured split."""
+    cost on a cold process, noise once warm). Why parquet request
+    bodies never beat JSON is another matter, the RESPONSE side staying
+    JSON in both modes: docs/architecture.md "Wire protocol"."""
     try:
         import pyarrow  # noqa: F401
 

@@ -113,7 +113,7 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
 
 def resolve_compile_cache(knob: Optional[str] = None) -> str:
     """Place the persistent compilation cache; every entry point (CLI
-    group, ``build_app``, ``chip_smoke.py``, ``bench.py``) calls this once.
+    group, ``build_app``, ``chip_smoke.py``) calls this once.
 
     1. ``JAX_COMPILATION_CACHE_DIR`` set: JAX already uses it. Nothing is
        configured here and ``knob`` / ``GORDO_COMPILE_CACHE_DIR`` are

@@ -120,7 +120,7 @@ def unpack_envelope(payload: memoryview) -> Tuple[str, str, memoryview]:
 
 
 # segment names CREATED by this process: an in-process attach (tests,
-# bench, the demo) must not untrack them — the creator's unlink() is the
+# the demo) must not untrack them — the creator's unlink() is the
 # one legitimate unregister, and a second one makes the tracker complain
 _OWNED_NAMES: set = set()
 

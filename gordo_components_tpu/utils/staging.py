@@ -8,7 +8,7 @@ device step. This module owns the policy AND the engine:
   default of ``min(8, max(4, cores))`` — the floor matters: provider IO
   (Influx/object stores) overlaps even on small hosts, and the old
   ``min(8, cores)`` collapsed to 1 on single-core builders, silently
-  disabling concurrency (BENCH r2 showed ``threads: 1``).
+  disabling concurrency.
 - ``stage_members``: run the provider→resample→join→dropna path for many
   members. ``GORDO_LOAD_MODE`` picks the engine: ``thread`` (IO overlap;
   pandas/numpy hold the GIL for much of the join), ``process`` (true CPU
@@ -17,9 +17,6 @@ device step. This module owns the policy AND the engine:
   (process exactly when cores, workers, and member count all warrant it;
   sync on a single core when every provider is CPU-bound — threads have
   nothing to overlap there and measured 14% slower).
-
-Shared by the fleet builder and ``bench.py``'s host_pipeline metric so the
-benchmark measures the same engine a fleet build actually uses.
 """
 
 import concurrent.futures

@@ -1,9 +1,8 @@
 """Framed binary tensor wire format (``application/x-gordo-tensor``).
 
-The scoring data plane's zero-copy encoding: BENCH_r05 measured the bank
-scoring ~840k samples/s in-process while the over-the-wire client moved
-~1.8k rows/s — a ~400x gap living entirely in pandas/JSON (de)serialization
-(and parquet's per-file metadata makes it *slower* than JSON at bulk-chunk
+The scoring data plane's zero-copy encoding: between the bank's scoring
+and what a client sees over the wire lies pandas/JSON (de)serialization
+(and parquet's per-file metadata costs more than JSON at bulk-chunk
 shapes; see docs/architecture.md "Wire protocol"). A float row is already
 bytes; this module just frames those bytes so both ends can exchange
 ndarrays with one header parse and zero value-level churn:

@@ -96,7 +96,7 @@ class FleetExecutor:
     shorthand. With NO replicas the executor runs in plan-only mode:
     builds and bucket manifests are real, place/canary/promote record
     their plans without touching a server (the compile-side smoke path
-    bench and the offline tests use).
+    the offline tests use).
 
     ``traffic_hook``, if given, is called as ``hook(base_url)`` on every
     canary poll — a convenience for demos/tests that want scoring

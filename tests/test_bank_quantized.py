@@ -192,8 +192,7 @@ def test_quantized_bank_within_band_single_device(
     else:
         # int8 codes + per-member-per-tensor scales; these test models
         # are tiny (scale overhead is at its worst), so just require a
-        # real win here — bench measures the ≥3.5x floor on
-        # realistically sized stacks
+        # real win here (realistically sized stacks reach ≥3.5x)
         assert cap["capacity_ratio"] > 1.8
     assert cap["models_per_gb"] > 0
     assert not cap["quantize_fallbacks"]

@@ -13,6 +13,7 @@ from gordo_components_tpu.parallel.checkpoint import (
     bucket_checkpoint_key,
 )
 from gordo_components_tpu.parallel.fleet import FleetTrainer
+from gordo_components_tpu.parallel.mesh import fleet_mesh
 
 
 def _members(n=6, rows=64, f=3, seed=0):
@@ -178,6 +179,19 @@ def test_resume_with_early_stopping_state(tmp_path):
         assert resumed[name].history["loss"] == pytest.approx(
             reference[name].history["loss"], rel=1e-5
         )
+
+
+def test_bucket_checkpoint_key_is_pinned(tmp_path):
+    """The key names the directory an interrupted fit resumes from: an
+    edit that moves it orphans every checkpoint in flight. The digest was
+    computed on PR 30's tree (d8147d0) with the product's defaults."""
+    ckdir = str(tmp_path / "ck")
+    trainer = FleetTrainer(
+        mesh=fleet_mesh(1), checkpoint_dir=ckdir, epoch_callback=_kill_after(2)
+    )
+    with pytest.raises(_Preempt):
+        trainer.fit(_members())
+    assert os.listdir(ckdir) == ["3d9d90d954d2e65304ea58dd"]
 
 
 def test_config_change_invalidates_checkpoint(tmp_path):

@@ -1,7 +1,7 @@
 """Conv impl interchangeability: the slice+matmul formulation must be a
 numerics- and parameter-exact drop-in for the stock flax conv ops
 (models/factories/conv.py), so artifacts/checkpoints move freely between
-the two and the bench's A/B comparison is apples-to-apples."""
+the two and an A/B comparison is apples-to-apples."""
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +36,7 @@ def test_matmul_impl_matches_lax(kernel_size, lookback):
 
 
 def test_matmul_impl_matches_lax_bfloat16():
-    """bf16 is the fleet bench/production compute dtype, and matmul became
+    """bf16 is a production compute dtype, and matmul became
     the DEFAULT impl — so an artifact built under the old lax default that
     reloads under the new one must reconstruct within bf16 resolution, or
     threshold-adjacent anomaly verdicts could silently flip. The two impls

@@ -391,27 +391,6 @@ def test_sharded_gang_steps_under_shard_map(interpreted, vmapped_fit, devices):
     _assert_same_models(got, vmapped_fit[0], rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("sync", [2, 4])
-def test_chunked_fit_takes_the_kernel(interpreted, sync):
-    """``chunk_fn`` scans the same epoch function: K epochs a dispatch with
-    early stopping on the device train what the per-epoch loop trains, and
-    a member frozen inside a chunk stays as it was."""
-    common = dict(
-        epochs=6, batch_size=32, seed=1, mesh=fleet_mesh(1),
-        early_stopping_patience=1, early_stopping_min_delta=1e-3,
-    )
-    per_epoch = FleetTrainer(**common)
-    want = per_epoch.fit(_members())
-    chunked = FleetTrainer(**common, host_sync_every=sync)
-    got = chunked.fit(_members())
-    assert chunked.last_stats["buckets"][0]["layout"] == "fused_step"
-    for name in want:
-        n = min(len(want[name].history["loss"]), len(got[name].history["loss"]))
-        np.testing.assert_allclose(
-            got[name].history["loss"][:n], want[name].history["loss"][:n], rtol=1e-5
-        )
-
-
 def test_refused_bucket_says_why_in_its_fit_span(interpreted):
     from gordo_components_tpu.observability.tracing import get_tracer
 

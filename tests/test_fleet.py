@@ -2,6 +2,7 @@
 sharding exercised in CI, which the reference never did (SURVEY.md §4
 "multi-node without a cluster")."""
 
+import inspect
 import os
 
 import jax
@@ -394,3 +395,60 @@ class TestProgramCacheLRU:
         builds = fleet_mod._PROGRAM_BUILDS
         FleetTrainer(**config).fit(members)
         assert fleet_mod._PROGRAM_BUILDS == builds
+
+
+# ---- the trainer's options: each has a caller in the product -------------
+
+# who passes the options a fleet YAML cannot reach through _TRAINER_KEYS
+_PASSED_BY = {
+    "mesh": "_build_fleet_group",
+    "checkpoint_dir": "_build_fleet_group",
+    "checkpoint_every": "_build_fleet_group",
+    "epoch_callback": "_build_fleet_group",
+    "input_scaler": "extract_fleetable",
+    "model_type": "extract_fleetable",
+    "lookback_window": "extract_fleetable",
+    "threshold_quantile": "extract_fleetable",
+    "require_thresholds": "extract_fleetable",
+    # the exact-width reference of test_quantization_is_noop_for_member_results
+    "quantize_members": "tests/test_fleet.py",
+}
+
+
+def _trainer_options():
+    return [
+        name
+        for name, p in inspect.signature(FleetTrainer.__init__).parameters.items()
+        if name != "self" and p.kind is p.POSITIONAL_OR_KEYWORD
+    ]
+
+
+@pytest.mark.parametrize("option", _trainer_options())
+def test_every_trainer_option_has_a_product_caller(option):
+    """An option nobody in the product can set is a second code path that
+    only tests and tools keep alive."""
+    from gordo_components_tpu.builder.fleet_build import _TRAINER_KEYS
+
+    assert option in _TRAINER_KEYS or option in _PASSED_BY, (
+        f"FleetTrainer({option}=) is in neither _TRAINER_KEYS nor the table "
+        "of who passes it: name its caller or remove the option"
+    )
+
+
+def test_unknown_trainer_option_fails_by_name():
+    """The factories ignore keywords they do not know, so an option that
+    is gone must say so itself: the constructor does, before any training."""
+    with pytest.raises(TypeError, match="host_sync_every"):
+        FleetTrainer(epochs=1, host_sync_every=2)
+
+
+def test_epoch_callback_fires_every_epoch_and_stats_count_them():
+    seen = []
+    trainer = FleetTrainer(epochs=7, batch_size=32, epoch_callback=seen.append)
+    trainer.fit(_member_data(2, rows=70, features=3))
+    assert [info["epoch"] for info in seen] == list(range(7))
+    for info in seen:
+        assert set(info) == {"n_features", "padded_rows", "epoch", "losses", "n_active"}
+        assert info["losses"].shape == (2,)  # the real members' alone
+    (bucket,) = trainer.last_stats["buckets"]
+    assert len(bucket["epoch_seconds"]) == 7

@@ -55,21 +55,6 @@ class TestPerMemberLR:
         # and the two LRs genuinely trained differently
         assert mixed["a"].history["loss"] != mixed["b"].history["loss"]
 
-    def test_chunked_path_matches_per_epoch(self):
-        """host_sync_every > 1 (device-side ES) honors the same vectors."""
-        data = _data()
-        hp = {"a": {"learning_rate": 1e-3}, "b": {"learning_rate": 5e-3}}
-        kw = dict(kind="feedforward_symmetric", dims=[4], epochs=6, batch_size=32)
-        per_epoch = FleetTrainer(**kw).fit(dict(data), member_hparams=hp)
-        chunked = FleetTrainer(**kw, host_sync_every=3).fit(
-            dict(data), member_hparams=hp
-        )
-        for n in ("a", "b"):
-            assert np.allclose(
-                per_epoch[n].history["loss"], chunked[n].history["loss"],
-                rtol=1e-5,
-            )
-
     def test_validation(self):
         data = _data(1)
         t = FleetTrainer(kind="feedforward_symmetric", dims=[4], epochs=1)
@@ -85,7 +70,7 @@ class TestPerMemberLR:
 
 
 class TestPerMemberPatience:
-    def _fit(self, host_sync_every=1):
+    def _fit(self):
         rng = np.random.RandomState(1)
         data = {
             "impatient": rng.rand(120, 3).astype("float32"),
@@ -101,7 +86,6 @@ class TestPerMemberPatience:
             batch_size=64,
             early_stopping_patience=1,
             early_stopping_min_delta=10.0,
-            host_sync_every=host_sync_every,
         ).fit(
             data,
             member_hparams={
@@ -112,13 +96,6 @@ class TestPerMemberPatience:
 
     def test_patience_vector_host_path(self):
         out = self._fit()
-        assert len(out["impatient"].history["loss"]) == 2
-        assert len(out["patient"].history["loss"]) == 9
-
-    def test_patience_vector_chunked_path(self):
-        # chunk boundaries can only over-run by masked epochs, never
-        # change the recorded (active) history lengths
-        out = self._fit(host_sync_every=8)
         assert len(out["impatient"].history["loss"]) == 2
         assert len(out["patient"].history["loss"]) == 9
 

@@ -286,6 +286,29 @@ class TestSeqBucketing:
             assert np.isfinite(m.history["val_loss"]).all()
 
 
+    def test_seq_validation_early_stopping(self):
+        """A sequence member whose validation windows diverge from its
+        training windows stops on its validation loss, long before the
+        epochs run out and while its training loss is still falling."""
+        rng = np.random.RandomState(4)
+        rows = 120
+        X = (np.sin(0.2 * np.arange(rows))[:, None] * np.ones((1, 3))).astype("float32")
+        X[90:] = 5.0 * rng.randn(30, 3).astype("float32")
+        # the learning rate makes the stop early and the steps between
+        # epochs large (1e-3 of validation loss): 10 epochs of 40 here
+        out = FleetTrainer(
+            model_type="LSTMAutoEncoder", kind="lstm_symmetric", dims=(6,),
+            lookback_window=8, epochs=40, batch_size=32, seed=4, learning_rate=0.02,
+            validation_split=0.25, early_stopping_patience=2,
+        ).fit({"diverge": X})
+        loss, val = out["diverge"].history["loss"], out["diverge"].history["val_loss"]
+        assert len(loss) == len(val) < 20
+        # the best validation epoch is patience + 1 before the last, and
+        # the training loss fell after it: monitoring that would have gone on
+        assert int(np.argmin(val)) == len(val) - 3
+        assert min(loss[-2:]) < loss[-3]
+
+
 class TestSeqExtractFleetable:
     def _config(self, path, est_kwargs):
         return _detector_pipeline(path, est_kwargs)

@@ -10,7 +10,7 @@ verdict-table rendering, and the gate's name validation. The real
 multi-process drills — N server subprocesses + a live watchman,
 SIGKILLed / partitioned / slowed on purpose — are marked ``slow`` and
 run in the ``make gameday`` lane (the full catalog also runs as
-bench.py's ``gameday`` leg via tools/gameday_demo.py).
+tools/gameday_demo.py).
 """
 
 import asyncio

@@ -2,7 +2,7 @@
 
 Covers the whole routing stack in-process (aiohttp TestServers are
 separate apps, not separate processes — the multi-PROCESS path is
-tools/mesh_demo.py / bench's ``mesh_serving`` leg and the subprocess
+tools/mesh_demo.py and the subprocess
 perf-guard below):
 
 - the serving-side bootstrap (``parallel/distributed.py``): identity

@@ -122,9 +122,16 @@ def test_time_major_forward_matches_vmap_apply(dims):
     module, params, xb = _stacked_module(M=3, dims=dims)
     want = jax.vmap(lambda p, x: module.apply(p, x))(params, xb)
     got = lstm_time_major_forward(module, params, xb, kernel="jnp")
-    # same dot products, same accumulation order per gate: the jnp-step
-    # time-major forward is exact against the flax cell
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the same dot products as the flax cell's. dims=(5,) (two layers) comes
+    # out bitwise; in the four layers of (6, 4) the input projections,
+    # hoisted out of the scan as one wide einsum a layer, accumulate in
+    # another order than the cell's matmul a step: 4e-9 absolute here
+    if dims == (5,):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-7
+        )
 
 
 def test_extracted_weights_have_gate_order_shapes():
@@ -344,7 +351,7 @@ def test_fleet_time_major_heterogeneous_multibucket_8shard(monkeypatch):
 @pytest.mark.slow
 @pytest.mark.perfguard
 def test_perfguard_time_major_no_slower_than_legacy():
-    """No-slower guard for the leg the bench scales up: one compiled
+    """No-slower guard: one compiled
     epoch, min-of-3 walltime. On this CPU container the honest claim is
     structural (time-major must not be a pessimization here while it
     wins on TPU — the >=2x assertion is TPU/multi-core-gated per the

@@ -30,7 +30,7 @@ def _configs(n, rows_days=2, tags=3):
 class TestPolicy:
     def test_worker_floor_engages_on_small_hosts(self, monkeypatch):
         # the old min(8, cores) collapsed to 1 on single-core builders,
-        # silently disabling concurrency (BENCH r2: threads=1)
+        # silently disabling concurrency
         monkeypatch.delenv("GORDO_LOAD_WORKERS", raising=False)
         assert load_worker_count() >= 4
         assert load_worker_count(2) == 2  # still clamped to the task count
@@ -122,8 +122,7 @@ class TestEngines:
         """On a multi-core host, process-mode staging of CPU-bound
         providers must beat the sync loop at >=2 workers — the scaling
         evidence the north-star build path's throughput claim rests on.
-        The measured sweep itself lives in bench.py
-        (host_staging_worker_sweep); this asserts the direction.
+        This asserts the direction, not a rate.
 
         The workload is CALIBRATED on the running host: one warm member is
         timed, then enough members are staged that the sync leg takes

@@ -294,8 +294,7 @@ def _fit_members():
     return {f"m{i}": rng.rand(90, 4).astype("float32") for i in range(3)}
 
 
-@pytest.mark.parametrize("host_sync_every", [1, 2])
-def test_fit_without_a_caller_trace_leaves_one_fleet_fit_trace(host_sync_every):
+def test_fit_without_a_caller_trace_leaves_one_fleet_fit_trace():
     """A fit is a trace: with no caller trace it opens ``fleet_fit`` on
     the process tracer, retained whatever the head sampling says, and the
     bucket's stages are the documented ones, tiling its ``fit:<bucket>``.
@@ -303,7 +302,7 @@ def test_fit_without_a_caller_trace_leaves_one_fleet_fit_trace(host_sync_every):
     from gordo_components_tpu.parallel.fleet import FleetTrainer
 
     before = [t for t in get_tracer().recent() if t.name == "fleet_fit"]
-    trainer = FleetTrainer(epochs=4, batch_size=32, host_sync_every=host_sync_every)
+    trainer = FleetTrainer(epochs=4, batch_size=32)
     trainer.fit(_fit_members())
     fits = [t for t in get_tracer().recent() if t.name == "fleet_fit"]
     assert len(fits) == len(before) + 1
@@ -320,11 +319,9 @@ def test_fit_without_a_caller_trace_leaves_one_fleet_fit_trace(host_sync_every):
     assert fit_span.start <= stages[0].start and stages[-1].end <= fit_span.end
     assert covered_seconds(stages) >= 0.9 * fit_span.duration_s
     epochs = [s for s in stages if s.name == "epoch"]
-    per_dispatch = 4 // len(epochs)
-    assert len(epochs) == 4 // host_sync_every
-    assert all(s.attributes.get("epochs", 1) == per_dispatch for s in epochs)
+    assert [s.attributes["epoch"] for s in epochs] == [0, 1, 2, 3]
     assert trainer.last_stats["buckets"][0]["epoch_seconds"] == [
-        s.duration_s / per_dispatch for s in epochs for _ in range(per_dispatch)
+        s.duration_s for s in epochs
     ]
 
 

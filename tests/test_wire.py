@@ -601,7 +601,7 @@ async def test_client_ingest_tensor_forwarder(artifact_dir, monkeypatch):
         assert totals["accepted"] == 12
         assert totals["chunks"] == 3
         # ingest traffic lands in its OWN bucket — the scoring cells
-        # (and the bench's bytes-per-row legs) must never absorb it
+        # must never absorb it
         assert client.wire_stats["ingest-tensor"]["posts"] == 3
         assert "tensor" not in client.wire_stats
     finally:

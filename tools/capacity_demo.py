@@ -19,8 +19,7 @@ From those three it prints the ADVISOR tables: the tier split with the
 hottest members, the per-bucket MFU league, and the projected members
 per HBM budget per storage dtype (fp32 baseline vs the current mix vs a
 hypothetical int8 cold tier — the tiered-bank sizing the heat ranking
-exists to feed). Ends with one machine-readable JSON doc (``bench.py``
-parses the last ``{``-opening block).
+exists to feed). Ends with one machine-readable JSON doc.
 """
 
 import argparse

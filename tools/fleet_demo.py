@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Declarative fleet compiler demo / bench driver.
+"""Declarative fleet compiler demo.
 
 Compiles one fleet YAML spec (N machines across 2 feature-count buckets)
 into the typed build -> bucket -> place -> canary -> promote DAG, then
@@ -19,9 +19,7 @@ walks the full rollout loop against a REAL in-process server:
    the canary window: the judge auto-rolls back to the incumbent and the
    incumbent's post-rollback scoring is verified 200.
 
-Prints one JSON document. Run directly (``make fleet-demo``); bench.py's
-``fleet_compile`` leg measures the compile-side numbers (compile time,
-step counts, incremental ratio) at larger fleet widths in-process.
+Prints one JSON document. Run directly (``make fleet-demo``).
 """
 
 import argparse

@@ -10,8 +10,7 @@ storm, gray slow-replica failure, thundering-herd reconnects,
 correlated drift. Each drill's verdict is judged end-to-end by the
 observability surfaces (detection latency, burn peak, causal event
 order, non-200 containment, observed recovery) and printed as a table,
-then as one JSON doc LAST (same contract as the other demos) so
-bench.py's ``gameday`` leg can parse it.
+then as one JSON doc LAST (same contract as the other demos).
 
 Honesty note: load-level bounds (hedge-win counts under real
 parallelism) are waived on single-core hosts; structural bounds

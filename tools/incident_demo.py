@@ -17,9 +17,8 @@ Then points a real ``WatchmanState`` at the replica and asks
 ``fleet_incidents()`` the operator question: *what burned, when, and
 what else happened around it?* Prints the detected incident's rendered
 timeline — fault -> burn -> quarantine -> recovery in order — plus the
-flight-recorder cost figures the bench suite tracks (sampler ms,
-query ms, bytes/series), and a final machine-readable JSON doc
-(``bench.py`` parses the last ``{``-opening block).
+flight-recorder cost figures (sampler ms, query ms, bytes/series), and
+a final machine-readable JSON doc.
 """
 
 import argparse

@@ -21,8 +21,7 @@ What it does, in order:
    release, both banks hot-swapping); every response during the window
    is counted and the demo FAILS on any non-200.
 
-Prints one JSON doc last (same contract as the other demos) so
-bench.py's ``mesh_serving`` leg can parse it.
+Prints one JSON doc last (same contract as the other demos).
 
 Honesty note (docs/architecture.md "Multi-host serving"): the aggregate
 speedup is real process parallelism — on a multi-core box 2 replicas

@@ -10,7 +10,7 @@ concurrent continuously-batched load.
 Phases (each timed, with host RSS after):
   1. synth    — ragged member data (600-1440 rows x tags, sine+noise)
   2. train    — one FleetTrainer gang, 2 epochs (the build leg, for scale
-                context; BASELINE.md carries the full staged version)
+                context)
   3. estimators — FleetMemberModel -> DiffBasedAnomalyDetector per member
                 (the artifact-object shape the server collection holds)
   4. bank     — ModelBank.from_models over all members (the per-model
@@ -49,8 +49,8 @@ def run_check(
     request_rows: int = 64,
     devices: int = 1,
 ) -> dict:
-    """The full check as a callable (bench.py runs it as a metric; the
-    CLI below wraps it). Returns the result document.
+    """The full check as a callable (the CLI below wraps it). Returns the
+    result document.
 
     ``devices > 1`` shards the ModelBank over a ``models``-axis mesh
     (``parallel/mesh.fleet_mesh``) and serves through the routed
@@ -112,8 +112,7 @@ def run_check(
     # ---- 2. train the gang ----
     t0 = time.time()
     trainer = FleetTrainer(
-        kind="feedforward_hourglass", epochs=args.epochs, batch_size=128,
-        host_sync_every=args.epochs,
+        kind="feedforward_hourglass", epochs=args.epochs, batch_size=128
     )
     fleet = trainer.fit(members)
     phase("train", t0)
@@ -143,7 +142,7 @@ def run_check(
     t0 = time.time()
     # dedicated registry: the per-shard/per-bucket assertions below must
     # see ONLY this check's serving traffic, not whatever else the process
-    # (e.g. a full bench run) recorded into the default registry
+    # recorded into the default registry
     registry = MetricsRegistry()
     # goodput/SLO evidence at scale (ISSUE 7): the ledger accounts the
     # serve phase's device windows + request outcomes, the tracker turns
@@ -450,7 +449,7 @@ def run_check(
     # ---- 6d. metrics registry: the per-shard skew and per-bucket program
     # visibility this scale exists to prove (VERDICT r5 weak #2 — a hot
     # shard was previously invisible). Asserted sane here so every
-    # NORTH_STAR_*.json artifact carries skew evidence automatically. ----
+    # result document carries skew evidence. ----
     heat.sample(force=True)  # fold the serve phase's routed rows now
     cost.sample(force=True)  # join the ledger's device time to FLOPs
     snap = registry.snapshot()

@@ -15,9 +15,8 @@ engine queue, and drives two phases:
 
 Then prints the per-class fairness table (admitted/shed per tenant and
 class, per-class goodput, per-class burn, the interactive p99 delta)
-and ends with ONE compact JSON doc — ``bench.py``'s ``qos`` leg runs
-this tool and records interactive-p99-under-flood, per-class goodput
-ratio, and shed precision from that line.
+and ends with ONE compact JSON doc: interactive p99 under the flood,
+per-class goodput ratio, and shed precision.
 """
 
 import argparse

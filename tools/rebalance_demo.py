@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Placement rebalance demo / bench driver.
+"""Placement rebalance demo.
 
 Builds a deliberately skewed fleet on an 8-shard (virtual, CPU-safe)
 ``models`` mesh — the hot members clustered on shard 0, exactly the
@@ -9,10 +9,8 @@ the plan through the zero-downtime swap, re-drives the SAME traffic, and
 prints one JSON document: measured shard skew before/after, the planner's
 predicted improvement, and the generation-flip pause.
 
-Run directly (``make rebalance-demo``) or from bench.py's ``rebalance``
-leg (which asserts the >=2x skew cut and records the numbers into
-BENCH_DETAIL.json). ``--members 10000`` reproduces the north-star-scale
-fixture.
+Run directly (``make rebalance-demo``). ``--members 10000`` reproduces
+the north-star-scale fixture.
 """
 
 import argparse

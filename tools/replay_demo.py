@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Time-compressed replay demo / bench driver.
+"""Time-compressed replay demo.
 
 Trains a small heterogeneous fleet on the simulated provider's healthy
 signal, then backtests the STANDARD incident library
@@ -10,9 +10,7 @@ hours of event time per scenario in seconds of wall time.
 Prints a per-scenario verdict table (detection latency, FP before/after
 adaptation, adaptation count, rolled-back count, duplicates absorbed,
 non-200 count, achieved compression) followed by one JSON document.
-Run directly (``make replay-demo``) or from bench.py's ``replay`` leg,
-which records per-incident-class detection latency, FP/FN rates, and
-adaptation cost into BENCH_DETAIL.json.
+Run directly (``make replay-demo``).
 """
 
 import argparse

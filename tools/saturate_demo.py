@@ -14,8 +14,7 @@ armed, then measures:
 - push mode (``GORDO_PUSH=1``): windows scored per second as ingest
   advances watermarks, with results fanned to a long-poll subscriber.
 
-Prints one JSON doc last (same contract as the other demos) so
-bench.py's ``serving_saturation`` leg can parse it.
+Prints one JSON doc last (same contract as the other demos).
 """
 
 import argparse

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Streaming ingestion & online adaptation demo / bench driver.
+"""Streaming ingestion & online adaptation demo.
 
 Builds a small heterogeneous fleet trained on the simulated live
 provider's healthy signal, serves it with the streaming plane enabled
@@ -18,10 +18,8 @@ surface:
 5. the false-positive anomaly rate on shifted-but-healthy data is
    measured before and after: recalibration must make it drop.
 
-Prints one JSON document. Run directly (``make stream-demo``) or from
-bench.py's ``streaming`` leg, which records detection latency,
-recalibration/refit time, swap pause, and the FP-rate drop into
-BENCH_DETAIL.json.
+Prints one JSON document: detection latency, recalibration/refit time,
+swap pause, and the FP-rate drop. Run directly (``make stream-demo``).
 """
 
 import argparse

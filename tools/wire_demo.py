@@ -4,9 +4,8 @@ bodies and print rows/s + bytes/row side by side (``make wire-demo``).
 
 Trains two tiny anomaly models into a temp dir, serves them through the
 real ``build_app`` stack (bank + batching engine), then scores one fixed
-batch many times per encoding through the raw HTTP surface — the pure
-data-plane comparison the bulk bench's ``client_bulk`` leg measures
-end-to-end (dataset build included). Also verifies bitwise JSON-vs-tensor
+batch many times per encoding through the raw HTTP surface: the pure
+data-plane comparison. Also verifies bitwise JSON-vs-tensor
 score parity on the batch before timing, so the rows/s table is never a
 "fast but wrong" number, and prints the server's per-encoding
 ``gordo_server_request{,_bytes}_total`` counters at the end.
