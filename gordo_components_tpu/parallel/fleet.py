@@ -112,6 +112,86 @@ def _merge_best(best_p, new_p, improved):
     return jax.tree.map(sel, best_p, new_p)
 
 
+# A gang's block of more than STAGING_PIECE_BYTES, of members of at least
+# STAGING_MEMBER_BYTES each, is not stacked on the host: its members cross to
+# the device from their own memory, a piece of about STAGING_PIECE_BYTES a
+# call, and the device writes each piece into the block. Every other block
+# is one piece, stacked on the host. Both from tools/staging_ladder.py on a
+# v5e host (PERF.md section 6, PR 32): a whole block of 1.2 GB is ready in
+# 0.66 s, its 1.9 MB members put one by one in 0.13 s; an array costs the
+# host 0.19 ms to put whatever it holds, so members of 512 KB still win
+# (0.40 against 0.66 s) and members of 64 KB lose (0.75 against 0.07 s).
+STAGING_PIECE_BYTES = 256 << 20
+STAGING_MEMBER_BYTES = 512 << 10
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _place(X, at, *rows):
+    """Equal-length members ``rows`` written into ``X`` from slot ``at`` on,
+    in place; the rows below them stay as they are (zero)."""
+    return jax.lax.dynamic_update_slice(X, jnp.stack(rows), (at, 0, 0))
+
+
+def stage_gang(
+    members: List[np.ndarray],
+    M: int,
+    padded_rows: int,
+    n_features: int,
+    sharding: jax.sharding.NamedSharding,
+) -> Tuple[jax.Array, np.ndarray, Dict[str, int]]:
+    """``fleet_stack_pad``'s (M, padded_rows, n_features) block on the
+    devices of ``sharding`` (members over its one mesh axis), its
+    (M, padded_rows) mask on the host, and ``{"pieces", "bytes"}``.
+
+    A block of more than ``STAGING_PIECE_BYTES``, of members of
+    ``STAGING_MEMBER_BYTES`` or more, never exists on the host: stacking
+    it moves every byte through host memory twice more, which costs more
+    than the crossing itself. A piece of slots at a time, the members are
+    handed to the device as they lie, and the device writes them into a
+    zeroed block: that is the padding. Pieces end where shards do, so each
+    goes to the device that holds it. One program serves every piece, so
+    every array of a gang has the gang's longest row count: a shorter
+    member crosses from a zero-padded copy of that length, a fresh one
+    each piece (the transfer may read it after the call returns, and on
+    the CPU backend the device array IS the host array)."""
+    from gordo_components_tpu.native import fleet_stack_pad
+
+    block = (padded_rows, n_features)
+    nbytes = 4 * M * padded_rows * n_features
+    if nbytes <= STAGING_PIECE_BYTES or nbytes < M * STAGING_MEMBER_BYTES:
+        Xs, masks = fleet_stack_pad(members, M, *block)
+        return jax.device_put(Xs, sharding), masks, {"pieces": 1, "bytes": nbytes}
+    for a in members:  # what fleet_stack_pad refuses
+        if a.ndim != 2 or a.shape[1] != n_features or a.shape[0] > padded_rows:
+            raise ValueError(f"Bad member shape {a.shape} for ({padded_rows}, {n_features})")
+    n = len(members)
+    rows = np.array([a.shape[0] for a in members])
+    longest = int(rows.max())
+    masks = (np.arange(padded_rows) < rows[np.arange(M) % n, None]).astype(np.float32)
+    devices = list(sharding.mesh.devices.flat)
+    m = M // len(devices)  # slots a shard
+    per_shard = -(-nbytes // (len(devices) * STAGING_PIECE_BYTES))
+    p = -(-m // per_shard)  # slots a piece
+    shards, pieces = [], 0
+    for d, device in enumerate(devices):
+        X = jnp.zeros((m,) + block, jnp.float32, device=device)
+        for at in range(0, m, p):
+            # slots past the real members hold member i % n, as in one piece
+            piece = [members[(d * m + i) % n] for i in range(at, min(at + p, m))]
+            short = [i for i, a in enumerate(piece) if a.shape[0] < longest]
+            if short:
+                copies, _ = fleet_stack_pad(
+                    [piece[i] for i in short], len(short), longest, n_features
+                )
+                for i, a in zip(short, copies):
+                    piece[i] = a
+            X = _place(X, at, *jax.device_put(piece, device))
+            pieces += 1
+        shards.append(X)
+    Xd = jax.make_array_from_single_device_arrays((M,) + block, sharding, shards)
+    return Xd, masks, {"pieces": pieces, "bytes": nbytes}
+
+
 # Bin count for the streaming-quantile histograms of the sequence error
 # pass: absolute threshold error <= range/8192 (~1.2e-4 on the [0,1]
 # scaled-feature axis), with (f+1)*8192 int32 histogram cells per member.
@@ -814,6 +894,7 @@ class FleetTrainer:
         self.require_thresholds = bool(require_thresholds)
         self._bucket_layout = "legacy"  # layout of the last-built bucket
         self._bucket_device = None  # device block of the last-built bucket
+        self._bucket_staging = None  # stage_gang's account of the last-built bucket
         self.epochs = int(epochs)
         self.batch_size = int(batch_size)
         self.learning_rate = float(learning_rate)
@@ -1102,6 +1183,9 @@ class FleetTrainer:
                     "layout": self._bucket_layout,
                     # the devices the bucket's stacked state sat on
                     "device": self._bucket_device,
+                    # how the stacked block crossed to them: pieces (1 =
+                    # one whole-block copy) and the block's bytes
+                    "staging": self._bucket_staging,
                 }
             )
         self.last_stats = {
@@ -1135,19 +1219,21 @@ class FleetTrainer:
         warmup = 0 if seq is None else self.lookback_window - 1 + t_offset
         padded_rows = padded_items + warmup
 
-        # ---- stack + pad host-side (the one unavoidable host loop;
-        # multithreaded C++ when the native lib is available, with dummies
-        # replicating real members for mesh padding either way) ----
+        # ---- the gang's rows to the device (stage_gang): stacked and padded
+        # on the host (multithreaded C++ when the native lib is available)
+        # and put whole, or, a large block of wide members, put as they lie
+        # and padded by the device; dummies replicate real members for mesh
+        # padding either way ----
+        sharding = shard_model_axis(mesh)
+        fit_span = self._trace_span[1]
         with self._stage("stack_pad"):
-            from gordo_components_tpu.native import fleet_stack_pad
-
-            Xs, masks = fleet_stack_pad(
-                [arrays[n] for n in names], M, padded_rows, n_features
+            Xd, masks, self._bucket_staging = stage_gang(
+                [arrays[n] for n in names], M, padded_rows, n_features, sharding
             )
+            if fit_span is not None:
+                fit_span.attributes["staging"] = self._bucket_staging
 
         with self._stage("to_device"):
-            sharding = shard_model_axis(mesh)
-            Xd = jax.device_put(jnp.asarray(Xs), sharding)
             maskd = jax.device_put(jnp.asarray(masks), sharding)
 
             # ---- per-member train/validation masks in ITEM space (items ==
@@ -1209,7 +1295,6 @@ class FleetTrainer:
                 self.threshold_quantile, mesh=mesh,
             )
             self._bucket_layout = progs.layout
-            fit_span = self._trace_span[1]
             if fit_span is not None:
                 fit_span.attributes["layout"] = progs.layout
                 if progs.fused_step_refused is not None:

@@ -123,10 +123,46 @@ def test_train_reader_finds_nothing_without_fit_traces(reader, monkeypatch, metr
     assert reader(metric)({"fits": [{"wall_s": 4.3}]}) is None
 
 
+FIRST_EPOCH_WAIT = "first_epoch_wait_ms.train"
+
+
+@pytest.mark.parametrize(
+    "fits,want",
+    [
+        (None, None),
+        ([], None),
+        ([{"wall_s": 1.0, "epoch_seconds": [0.5]}], None),  # one epoch: nothing to set it against
+        ([{"wall_s": 1.0}, {"wall_s": 1.0, "epoch_seconds": []}], None),
+        # per fit, first epoch less the median of the rest: 0.30 - 0.10, 0.12 - 0.11, 0.25 - 0.15
+        # (the one-epoch fit is left out); the median of the three, in ms
+        (
+            [
+                {"wall_s": 2.0, "epoch_seconds": [0.30, 0.10, 0.10, 0.40]},
+                {"wall_s": 2.0, "epoch_seconds": [0.12, 0.11]},
+                {"wall_s": 0.5, "epoch_seconds": [0.9]},
+                {"wall_s": 2.0, "epoch_seconds": [0.25, 0.10, 0.20]},
+            ],
+            100.0,
+        ),
+        ([{"wall_s": 2.0, "epoch_seconds": [0.10, 0.15, 0.15]}], -50.0),  # no wait reads as none
+    ],
+)
+def test_first_epoch_wait_reader(reader, fits, want):
+    """It reads the driver's own ``fits`` (the trainer's ``epoch_seconds``),
+    no span: a parent without this PR's staging is read the same way."""
+    obs = {} if fits is None else {"fits": fits}
+    got = reader(FIRST_EPOCH_WAIT)(obs)
+    assert got is None if want is None else got == pytest.approx(want)
+
+
 def test_every_new_reader_is_listed_with_its_cell():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         per_layer = {m["name"]: m for m in json.load(fh)["per_layer"]}
-    for metric, cell in [(m, "dense300.live") for m in SERVE] + [(m, "dense300.refit") for m in TRAIN]:
+    assert per_layer[FIRST_EPOCH_WAIT]["layer"] == "trainer, host side"
+    for metric, cell in (
+        [(m, "dense300.live") for m in SERVE]
+        + [(m, "dense300.refit") for m in list(TRAIN) + [FIRST_EPOCH_WAIT]]
+    ):
         entry = per_layer[metric]
         # its own cell first; later configurations append theirs (PR 28)
         assert entry["source"] == "program_span" and entry["workloads"][0] == cell
