@@ -178,11 +178,11 @@ def estimate_flops_per_row(
         )
     routed = getattr(module, "forward_flops_per_row", None)
     if routed is not None:
-        # a trunk of routed experts and selected attention counts what a
-        # row meets (its top-k experts, the keys it selected), not every
-        # parameter; a row's keys depend on its request's length, taken
-        # here as five times the selection (10 240 rows at top-k 2048)
-        context = 5 * int(module.indexer_topk)
+        # a trunk of routed experts counts what a row meets (its top-k
+        # experts, or the held ones' share of them; the keys it attends
+        # to), not every parameter; a row's keys depend on its request's
+        # length, taken as the kind's own nominal one (10 240 rows)
+        context = int(module.nominal_context_rows())
         return routed(context), f"analytic:context={context}"
     if params_per_member:
         return 2.0 * float(params_per_member) * max(1, int(lookback)), "params"

@@ -1,23 +1,39 @@
 """A routed expert layer: a learned router sends each token to its
 ``top_k`` of ``E`` experts (SwiGLU feed-forwards), and the token's output
-is the renormalised-probability-weighted sum of theirs.
+is the weighted sum of theirs.
 
-    p = softmax(h W_r)                      over the E experts, float32
-    y = sum over e in top_k(p) of  p_e / (sum of the k kept)  *
+    s = softmax(h W_r)  or  sigmoid(h W_r)     over the E experts, float32
+    kept = top_k(s)                            among the experts of the
+                                               ``topk_group`` best of
+                                               ``n_group`` groups (a group's
+                                               score: its two largest s)
+    y = sum over e in kept of  scale * s_e / (sum of the k kept)  *
         W_down,e ( silu(W_gate,e h) * W_up,e h )
 
-No token is dropped, whatever the load: the (token, expert) pairs are
-sorted by expert and the experts' matmuls run as ONE grouped matmul over
-the ragged groups (``jax.experimental.pallas.ops.tpu.megablox.gmm``: each
-row tile multiplies its own group's matrix, a tile that straddles two
-groups is visited once for each), so an expert that takes every token is
-just a long group. One path: the kernel on a TPU, the same kernel in
-interpret mode elsewhere (``interpret``, decided once by the caller as the
-bank decides its epilogue kernel).
+``n_group`` 1 is plain top-k; softmax, one group and scale 1 is the
+renormalised-probability router.
+
+The layer may hold a *range* of the experts (``expert_offset`` and as many
+as ``gate`` has): one chip's share of a layer divided over chips by
+experts. It still routes over all ``E`` (the router keeps its width) and
+returns every token's ``top_k`` of ``E``; it computes the pairs that fall
+on an expert it holds, and a token none of whose experts is held gets zero.
+What the absent experts would add is another chip's to compute and add:
+nothing here stands in for it.
+
+No pair on a held expert is dropped, whatever the load: the (token, expert)
+pairs are sorted by expert, those of absent experts last, and the held
+experts' matmuls run as ONE grouped matmul over the ragged groups
+(``jax.experimental.pallas.ops.tpu.megablox.gmm``: each row tile multiplies
+its own group's matrix, a tile that straddles two groups is visited once
+for each, row tiles past the last held pair are not visited), so an expert
+that takes every token is just a long group. One path: the kernel on a
+TPU, the same kernel in interpret mode elsewhere (``interpret``, decided
+once by the caller as the bank decides its epilogue kernel).
 
 Matmuls take bfloat16 operands and accumulate in float32; the router's
-logits (float32 operands at full precision), its softmax and the combine
-are float32.
+logits (float32 operands at full precision), its scores, the group and
+expert top-k and the combine are float32.
 """
 
 from typing import Dict, Tuple
@@ -31,15 +47,33 @@ from jax.experimental.pallas.ops.tpu.megablox import gmm
 _TILE_ROWS, _TILE_K, _TILE_N = 512, 1024, 1024
 
 
-def route(h: jnp.ndarray, w_router: jnp.ndarray, top_k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def route(h: jnp.ndarray, w_router: jnp.ndarray, top_k: int, scoring: str = "softmax",
+          n_group: int = 1, topk_group: int = 1,
+          scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(weights, experts)``, each (tokens, top_k): the kept experts'
-    probabilities renormalised to sum 1, and their ids."""
+    scores renormalised to sum ``scale``, and their ids."""
     logits = jnp.dot(
         h.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    p, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-    return p / jnp.sum(p, axis=-1, keepdims=True), experts.astype(jnp.int32)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
+    choice = scores
+    if n_group > 1:
+        grouped = scores.reshape(scores.shape[0], n_group, -1)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        kept_groups = jax.lax.top_k(group_score, topk_group)[1]  # (tokens, topk_group)
+        keep = jnp.any(kept_groups[:, :, None] == jnp.arange(n_group), axis=1)
+        choice = jnp.where(keep[:, :, None], grouped, -1.0).reshape(scores.shape)
+    p, experts = jax.lax.top_k(choice, top_k)
+    weights = p / jnp.sum(p, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
+    return weights, experts.astype(jnp.int32)
 
 
 def _grouped(lhs, rhs, group_sizes, interpret):
@@ -55,26 +89,34 @@ def _grouped(lhs, rhs, group_sizes, interpret):
 
 def expert_layer(
     h: jnp.ndarray, params: Dict[str, jnp.ndarray], top_k: int, valid: jnp.ndarray,
-    interpret: bool = False,
+    interpret: bool = False, expert_offset: int = 0, **routing,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``h`` (tokens, D) float32; ``params``: ``router`` (D, E), ``gate``
-    and ``up`` (E, D, I), ``down`` (E, I, D); ``valid`` (tokens,) bool:
-    padding is routed like any token (its rows are dropped later) but left
-    out of the counts.
+    and ``up`` (held, D, I), ``down`` (held, I, D): experts
+    ``expert_offset .. expert_offset + held`` of the E; ``valid`` (tokens,)
+    bool: padding is routed like any token (its rows are dropped later) but
+    left out of the counts; ``routing``: ``route``'s.
 
-    Returns the layer's output (tokens, D) float32, the experts chosen
-    (tokens, top_k) int32 and the valid tokens routed to each expert (E,).
+    Returns the held experts' part of the layer's output (tokens, D)
+    float32, the experts chosen (tokens, top_k) int32 of E, and the valid
+    tokens routed to each held expert (held,).
     """
     n_tokens = h.shape[0]
     n_experts = params["router"].shape[-1]
+    held = params["gate"].shape[0]
+    whole = held == n_experts  # every pair is on a held expert
     with jax.named_scope("trunk/route"):
-        weights, experts = route(h, params["router"], top_k)
+        weights, experts = route(h, params["router"], top_k, **routing)
         flat = experts.reshape(-1)
+        if not whole:  # a pair on an absent expert sorts last, into a group no matmul visits
+            local = flat - expert_offset
+            flat = jnp.where((local >= 0) & (local < held), local, held)
         order = jnp.argsort(flat, stable=True)
-        sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+        groups = held if whole else held + 1
+        sizes = jnp.bincount(flat, length=groups).astype(jnp.int32)[:held]
         counts = jnp.bincount(
-            flat, weights=jnp.repeat(valid, top_k).astype(jnp.int32), length=n_experts
-        ).astype(jnp.int32)
+            flat, weights=jnp.repeat(valid, top_k).astype(jnp.int32), length=groups
+        ).astype(jnp.int32)[:held]
         x = h.astype(jnp.bfloat16)[order // top_k]  # (pairs, D), grouped by expert
     with jax.named_scope("trunk/experts"):
         gate = _grouped(x, params["gate"], sizes, interpret)
@@ -83,5 +125,7 @@ def expert_layer(
     with jax.named_scope("trunk/combine"):
         back = jnp.argsort(order)  # pair (token, slot) -> its row in the sorted order
         y = y[back].reshape(n_tokens, top_k, -1)
+        if not whole:  # rows past the last held pair were never written
+            y = jnp.where((flat < held).reshape(n_tokens, top_k, 1), y, 0.0)
         out = jnp.sum(y * weights[..., None], axis=1)
     return out, experts, counts

@@ -43,14 +43,17 @@ WITNESS_STRIDE = 64
 
 
 def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-         rotary_dim: Optional[int] = None) -> jnp.ndarray:
+         rotary_dim: Optional[int] = None, inv_freq=None) -> jnp.ndarray:
     """Rotary embedding, rotate-half convention, on the first
     ``rotary_dim`` (default: all) of the last axis. ``x``: (T, heads, d);
     ``positions``: (T,). Multimodal RoPE with its three position streams
-    equal (a one-dimensional sequence) is exactly this."""
+    equal (a one-dimensional sequence) is exactly this. ``inv_freq``
+    (d // 2,): the frequencies, where they are not ``theta``'s own (YaRN:
+    ``ops/latent_attention.py``)."""
     d = x.shape[-1] if rotary_dim is None else rotary_dim
     half = d // 2
-    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / d))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / d))
     angle = positions.astype(jnp.float32)[:, None] * inv_freq  # (T, half)
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
     x1, x2, rest = x[..., :half], x[..., half:d], x[..., d:]

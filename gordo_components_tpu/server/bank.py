@@ -50,6 +50,7 @@ from gordo_components_tpu.models.anomaly.diff import (
     DiffBasedAnomalyDetector,
     assemble_anomaly_frame,
 )
+from gordo_components_tpu.models.factories.trunk import stack_observed
 from gordo_components_tpu.models.register import lookup_factory
 from gordo_components_tpu.models.train_core import _next_pow2
 from gordo_components_tpu.observability import get_registry
@@ -619,9 +620,10 @@ class _Bucket:
 
         # A bucket with shared leaves runs as three programs (score_batch):
         # ``score_enter`` (the B selected members' input projections),
-        # ``score_layer`` once per layer of the shared trunk (every layer has
-        # the same shapes: compiled once whatever the depth, each call handed
-        # its own layer's leaves in place), and ``score`` below, which then
+        # ``score_layer`` once per layer of the shared trunk (compiled once
+        # for each kind of layer the trunk has, whatever the depth: layers
+        # of one kind have the same leaves and shapes, and each call is
+        # handed its own layer's leaves in place), and ``score`` below, which then
         # starts from the trunk's last state. All three names begin with
         # ``score``: the device trace's readers sum the bucket's programs
         # by that prefix.
@@ -776,10 +778,11 @@ class ScoreResult:
     # request's final outcome is known; 0.0 when accounting is off
     device_s: float = 0.0
     # a bucket with shared leaves: which experts each row was routed to,
-    # (layers, rows, top_k) uint8, and which keys every 64th row attended
-    # to, as packed bits (layers, sampled rows, padded rows // 8) uint8
-    # (ops/sparse_attention.py). They ride the tensor response as two more
-    # frames: what a client compares two servers' selections by.
+    # (routed layers, rows, top_k) uint8, and, where the kind selects keys,
+    # which keys every 64th row attended to, as packed bits (layers,
+    # sampled rows, padded rows // 8) uint8 (ops/sparse_attention.py).
+    # They ride the tensor response as further frames: what a client
+    # compares two servers' selections by.
     selections: Optional[Dict[str, np.ndarray]] = None
 
     def to_frame(self, index=None):
@@ -824,7 +827,20 @@ _SHARED_COUNTERS = {
     "expert_tokens": "Valid (row, expert) pairs routed, all layers",
     "expert_tokens_busiest": "Pairs routed to each layer's busiest expert",
     "key_selections": "(query, key) pairs the indexer selected, all layers",
+    "routed_pairs": "Valid (row, expert) pairs routed by layers that hold a range of their experts",
+    "held_pairs": "Those of them that fell on an expert held here",
+    "held_tokens_busiest": "Pairs routed to each such layer's busiest held expert",
 }
+
+
+def _selection_frames(observed: Dict[str, np.ndarray], slot: int, rows: int) -> Dict[str, np.ndarray]:
+    """One request's frames of what the layers observed: its rows'
+    experts in every routed layer, and its sampled rows' keys where the
+    kind selects keys."""
+    frames = {"expert-selection": observed["experts"][:, slot, :rows].copy()}
+    if "witness" in observed:
+        frames["key-selection"] = observed["witness"][:, slot].copy()
+    return frames
 
 
 def _slice_single(outs, slot, n_out: int):
@@ -2054,8 +2070,7 @@ class ModelBank:
                 slots = run.slots
                 observed = None
                 if len(outs) > 5:  # a bucket with shared leaves (_Bucket.score_batch)
-                    outs, layers = outs[:5], outs[5]
-                    observed = {k: np.stack([seen[k] for seen in layers]) for k in layers[0]}
+                    outs, observed = outs[:5], stack_observed(outs[5], np.stack)
                     self._count_observed(run, observed)
                 for ri, X_conv, cis, valids, n_out in run.req_plans:
                     if len(cis) == 1:
@@ -2072,12 +2087,8 @@ class ModelBank:
                         total_scaled=vals[4],
                         offset=run.off,
                         selections=(
-                            None if observed is None else {
-                                "expert-selection": observed["experts"][
-                                    :, slots[cis[0]], : X_conv.shape[0]
-                                ].copy(),
-                                "key-selection": observed["witness"][:, slots[cis[0]]].copy(),
-                            }
+                            None if observed is None
+                            else _selection_frames(observed, slots[cis[0]], X_conv.shape[0])
                         ),
                     )
             post.end = reassemble.end
@@ -2087,17 +2098,25 @@ class ModelBank:
 
     def _count_observed(self, run: _GroupRun, observed: Dict[str, np.ndarray]) -> None:
         """Counters from the arrays a shared-leaf bucket's program returned
-        with this dispatch (executor thread, one dispatch at a time)."""
-        tokens = observed["expert_tokens"]  # (layers, experts), valid rows only
+        with this dispatch (executor thread, one dispatch at a time). Each
+        array counts valid rows only and is stacked over the layers that
+        observed it: a dense layer routes nothing, a kind without an
+        indexer selects no keys."""
+        counted = [("dispatches", 1), ("rows", run.routed_rows), ("tokens", run.total_rows)]
+        if "expert_tokens" in observed:  # (layers, experts): every expert is held
+            tokens = observed["expert_tokens"]
+            counted += [("expert_tokens", int(tokens.sum())),
+                        ("expert_tokens_busiest", int(tokens.max(axis=-1).sum()))]
+        if "selections" in observed:
+            counted.append(("key_selections", int(observed["selections"].sum())))
+        if "held_tokens" in observed:  # (routed layers, held experts): one chip's share
+            held = observed["held_tokens"]
+            layers, top_k = observed["experts"].shape[0], observed["experts"].shape[-1]
+            counted += [("routed_pairs", run.routed_rows * top_k * layers),
+                        ("held_pairs", int(held.sum())),
+                        ("held_tokens_busiest", int(held.max(axis=-1).sum()))]
         stats = self.shared_stats
-        for name, value in (
-            ("dispatches", 1),
-            ("rows", run.routed_rows),
-            ("tokens", run.total_rows),
-            ("expert_tokens", int(tokens.sum())),
-            ("expert_tokens_busiest", int(tokens.max(axis=-1).sum())),
-            ("key_selections", int(observed["selections"].sum())),
-        ):
+        for name, value in counted:
             stats[name] = stats.get(name, 0) + value
 
     def _account_group(

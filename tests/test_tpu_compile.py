@@ -382,6 +382,55 @@ def test_trunk_layer_program_compiles_at_published_widths(v5e, B):
     assert 1.24e9 < weights < 1.26e9  # 625.4 M parameters a layer in bfloat16
 
 
+@pytest.mark.parametrize("kind,layer_index,B,kernels,gigabytes", [
+    ("dense", 0, 1, 1, (0.99, 1.00)), ("routed", 1, 1, 4, (1.34, 1.36)),
+    ("routed", 1, 2, 4, (1.34, 1.36)),
+])
+def test_latent_trunk_layer_programs_compile_at_published_widths(
+        v5e, kind, layer_index, B, kernels, gigabytes):
+    """Both kinds of layer of the latent-attention trunk (64 heads of
+    128 + 64 | 128 over ranks 1536 and 512; a dense SwiGLU of 18 432; 12 of
+    192 experts of 2048 beside a shared one, top 8 of 4 of 8 groups) over
+    B week-long requests of 10 240 padded rows, for one chip: the latent
+    attention, and in the routed layer the three grouped matmuls, are
+    Pallas kernels, and what each program needs beside its arguments stays
+    under the count the bank bounds its batch by."""
+    from gordo_components_tpu.models.factories.trunk import LatentMoEDecoder
+
+    module = LatentMoEDecoder(
+        n_features=300, num_hidden_layers=6, experts_held=12,
+        rope_scaling=dict(type="yarn", factor=32, original_max_position_embeddings=4096,
+                          beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1),
+    )
+    home = SingleDeviceSharding(v5e[0])
+    T = module.padded_rows(10080)
+    layer = {
+        name: jax.ShapeDtypeStruct(
+            shape, jnp.float32 if len(shape) == 1 else jnp.bfloat16, sharding=home
+        )
+        for name, shape in module.layer_shapes(layer_index).items()
+    }
+    assert ("router" in layer) == (kind == "routed")
+    x = jax.ShapeDtypeStruct((B, T, module.hidden_size), jnp.float32, sharding=home)
+    n_valid = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=home)
+    compiled = jax.jit(
+        lambda w, x, n: module.layer(w, x, n, interpret=False)
+    ).lower(layer, x, n_valid).compile()
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"{kind} layer, {B} request(s): temp {temp / 1e9:.2f} GB, "
+          f"the bank's count {module.program_bytes(B, T) / 1e9:.2f} GB")
+    assert temp <= module.program_bytes(B, T), (temp, module.program_bytes(B, T))
+    if kind == "routed" and B == 1:  # the wider of the two: the count is not loose against it
+        assert module.program_bytes(B, T) <= 1.6 * temp
+    # beside the 7.75 GB trunk and the 2.5 GB bank a chip of 16.9e9 bytes takes two
+    # requests a call by this count, not four
+    free = 16.9e9 - 7.75e9 - 2.5e9
+    assert module.program_bytes(2, T) < free < module.program_bytes(4, T)
+    weights = sum(np.prod(s.shape) * s.dtype.itemsize for s in layer.values())
+    assert gigabytes[0] * 1e9 < weights < gigabytes[1] * 1e9
+
+
 # --------------------------------------------------------------------- #
 # the dense gang's training step (ops/dense_step.py) in its epoch program
 # --------------------------------------------------------------------- #

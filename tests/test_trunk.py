@@ -398,6 +398,26 @@ def test_padding_is_left_out_of_the_routers_counts():
     assert int(counts.sum()) == 20 * k
 
 
+def test_the_softmax_router_with_every_expert_held_is_bitwise_what_it_was():
+    """``expert_layer`` as this kind calls it (softmax, one group, every
+    expert held, no offset) against what it returned before it could hold a
+    range or route by sigmoid (``tests/data``: the parent commit's output
+    for these seeded inputs, interpret mode on the CPU)."""
+    rng = np.random.default_rng(20261003)
+    T, D, E, I, k = 64, 32, 8, 16, 2
+    h = rng.normal(size=(T, D)).astype(np.float32)
+    params = {"router": rng.normal(size=(D, E)).astype(np.float32) * 0.3,
+              "gate": jnp.asarray(rng.normal(size=(E, D, I)) * D ** -0.5, jnp.bfloat16),
+              "up": jnp.asarray(rng.normal(size=(E, D, I)) * D ** -0.5, jnp.bfloat16),
+              "down": jnp.asarray(rng.normal(size=(E, I, D)) * I ** -0.5, jnp.bfloat16)}
+    valid = np.arange(T) < 50
+    out, experts, counts = moe.expert_layer(jnp.asarray(h), params, k, jnp.asarray(valid), True)
+    was = np.load(os.path.join(os.path.dirname(__file__), "data", "expert_layer_softmax_case.npz"))
+    np.testing.assert_array_equal(np.asarray(out), was["out"])
+    np.testing.assert_array_equal(np.asarray(experts), was["experts"])
+    np.testing.assert_array_equal(np.asarray(counts), was["counts"])
+
+
 # -------------------------------------------------- the benchmark's counts
 
 

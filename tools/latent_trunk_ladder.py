@@ -1,0 +1,111 @@
+#!/usr/bin/env python3
+"""Device time of the latent-attention trunk's pieces at a week-long
+request (run on the chip; no test and no benchmark runs this):
+
+    python3 tools/latent_trunk_ladder.py [--rows 10240] [--kernel 512x4,512x8] [--layers]
+
+``--kernel``: ``ops/latent_attention.py``'s kernel alone at the published
+head sizes (64 heads of 128 + 64 | 128) for each ``tile x heads-a-step``,
+wall time around ``block_until_ready`` over ``--repeats`` calls, beside the
+share of the chip's peak its causal operations come to. ``--layers``: the
+two layer programs (``LatentMoEDecoder.layer``, dense and routed with 12 of
+192 experts held) on random weights, the same way. It fails where JAX finds
+no TPU unless ``--interpret`` (a rehearsal of the script at a tiny size: its
+times say nothing).
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+YARN = dict(type="yarn", factor=32, original_max_position_embeddings=4096, beta_fast=32,
+            beta_slow=1, mscale=1, mscale_all_dim=1)
+PEAK = 197e12
+
+
+def timed(fn, *args, repeats: int):
+    import jax
+
+    jax.block_until_ready(fn(*args))  # compiles
+    times = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return sorted(times)[len(times) // 2]
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--rows", type=int, default=10240)
+    parser.add_argument("--kernel", default="1024x2,512x4")
+    parser.add_argument("--layers", action="store_true")
+    parser.add_argument("--repeats", type=int, default=3)
+    parser.add_argument("--interpret", action="store_true")
+    parser.add_argument("--out", default="chiprun_out/latent_trunk_ladder.jsonl")
+    args = parser.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+
+    from gordo_components_tpu.models.factories.trunk import LatentMoEDecoder
+    from gordo_components_tpu.ops import latent_attention as la
+
+    if jax.devices()[0].platform != "tpu" and not args.interpret:
+        print(f"no TPU here ({jax.devices()[0]}): a CPU timing of this says nothing", file=sys.stderr)
+        return 2
+    small = dict(hidden_size=64, num_attention_heads=4, q_lora_rank=32, kv_lora_rank=16,
+                 qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate_size=96,
+                 moe_intermediate_size=32, n_routed_experts=16, num_experts_per_tok=4, n_group=4,
+                 topk_group=2, experts_held=4, chunk_size=16) if args.interpret else {}
+    module = LatentMoEDecoder(n_features=300, num_hidden_layers=2, rope_scaling=YARN,
+                              **(small or dict(experts_held=12)))
+    T, H = args.rows, module.num_attention_heads
+    nope, rope_dim, dv = module.qk_nope_head_dim, module.qk_rope_head_dim, module.v_head_dim
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    out = open(args.out, "a")
+
+    def say(row):
+        print(json.dumps(row), flush=True)
+        out.write(json.dumps(row) + "\n")
+        out.flush()
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 8)
+    draw = lambda k, shape: jax.random.normal(k, shape, jnp.float32).astype(jnp.bfloat16)
+    flops = 2.0 * H * (nope + rope_dim + dv) * T * (T + 1) / 2
+    for spec in filter(None, args.kernel.split(",")):
+        tile, heads = (int(v) for v in spec.split("x"))
+        la._TILES = ((tile, heads),)
+        operands = (draw(keys[0], (H, T, nope)), draw(keys[1], (H, T, rope_dim)),
+                    draw(keys[2], (H, T, nope)), draw(keys[3], (T, rope_dim)), draw(keys[4], (H, T, dv)))
+        fn = jax.jit(lambda *a: la.latent_attention(*a, granule=tile, interpret=args.interpret))
+        try:
+            seconds = timed(fn, *operands, repeats=args.repeats)
+            say({"kernel": spec, "rows": T, "ms": 1e3 * seconds, "share_of_peak": flops / seconds / PEAK})
+        except Exception as exc:  # a tile the chip's lowering or its VMEM refuses
+            say({"kernel": spec, "rows": T, "refused": f"{type(exc).__name__}: {str(exc)[:300]}"})
+    if args.layers:
+        x = jax.random.normal(keys[5], (1, T, module.hidden_size), jnp.float32)
+        n_valid = jnp.asarray([T - 160], jnp.int32)
+        fn = jax.jit(lambda w, x, n: module.layer(w, x, n, interpret=args.interpret))
+        for index, kind in ((0, "dense"), (1, "routed")):
+            layer = {
+                name: (jnp.ones(shape, jnp.float32) if len(shape) == 1 else
+                       (jax.random.uniform(jax.random.fold_in(keys[6], i), shape, jnp.float32, -1, 1)
+                        * (3.0 / shape[-2]) ** 0.5).astype(jnp.bfloat16))
+                for i, (name, shape) in enumerate(module.layer_shapes(index).items())
+            }
+            seconds = timed(fn, layer, x, n_valid, repeats=args.repeats)
+            _, seen = fn(layer, x, n_valid)
+            say({"layer": kind, "rows": T, "ms": 1e3 * seconds,
+                 "held_tokens": None if not seen else [int(v) for v in seen["held_tokens"]],
+                 "memory_peak_gb": (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use", 0) / 1e9})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
