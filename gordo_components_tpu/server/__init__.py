@@ -129,6 +129,7 @@ async def _stats_middleware(request, handler):
     # worker app); absent (None) outside pool mode — no per-worker
     # series render, the stability contract's default-off rule
     worker = getattr(request.app, "gordo_worker", None)
+    enc = None
     with lock:
         stats["requests"][kind] = stats["requests"].get(kind, 0) + 1
         if worker is not None:
@@ -294,6 +295,25 @@ async def _stats_middleware(request, handler):
     if not counted and resp.status >= 400:
         with lock:
             stats["errors"] += 1
+    if enc is not None:
+        # the answer's bytes, under the REQUEST's encoding (a tensor
+        # request is answered in tensor frames; json and parquet ones in
+        # JSON), counted as the response is handed to its connection:
+        # all of them, and those the connection will write from the
+        # arrays' own memory (a tensor answer's payloads, views.TensorBody)
+        body = getattr(resp, "body", None)
+        size = getattr(body, "size", None)
+        if size is None:
+            size = len(body) if body else 0
+        by_reference = getattr(body, "by_reference", 0)
+        with lock:
+            wire = stats["wire"]
+            wire["response_bytes"][enc] = (
+                wire["response_bytes"].get(enc, 0) + size
+            )
+            wire["response_bytes_by_reference"][enc] = (
+                wire["response_bytes_by_reference"].get(enc, 0) + by_reference
+            )
     return resp
 
 
@@ -335,6 +355,21 @@ def _server_collector(app: web.Application):
             yield (
                 "gordo_server_request_bytes_total", "counter",
                 "Scoring/ingest request body bytes by wire encoding",
+                {"encoding": enc}, n,
+            )
+        for enc, n in stats["wire"]["response_bytes"].items():
+            yield (
+                "gordo_server_response_bytes_total", "counter",
+                "Scoring/ingest response body bytes by the request's "
+                "wire encoding",
+                {"encoding": enc}, n,
+            )
+        for enc, n in stats["wire"]["response_bytes_by_reference"].items():
+            yield (
+                "gordo_server_response_bytes_by_reference_total", "counter",
+                "Those of the response body bytes written to the "
+                "connection from the arrays' own memory (never copied "
+                "into a joined body)",
                 {"encoding": enc}, n,
             )
         # multi-worker accept-path balance (stability contract): which
@@ -534,7 +569,14 @@ def build_app(
         "exemplars": {},
         # per-encoding data-plane counters (json|parquet|tensor): scoring
         # /ingest POST counts + request body bytes, fed by the middleware
-        "wire": {"requests": {}, "bytes": {}},
+        # and, once a response is handed over, its body bytes and how
+        # many of them the connection writes by reference
+        "wire": {
+            "requests": {},
+            "bytes": {},
+            "response_bytes": {},
+            "response_bytes_by_reference": {},
+        },
         # per-worker request counters (server/workers.py tags each worker
         # loop's app): empty — and emitting no series — outside pool mode
         "workers": {},

@@ -9,8 +9,9 @@ still works and behaves like the reference.
 
 Also the binary scoring data plane's server half (PR 10): decode a
 ``application/x-gordo-tensor`` request body straight into the float32
-arrays the bank scores (``np.frombuffer`` view, no DataFrame), and encode
-score arrays straight into one preallocated response body (utils/wire.py).
+arrays the bank scores (``np.frombuffer`` view, no DataFrame), and frame
+score arrays for the response (utils/wire.py): as the list of buffers the
+HTTP connection writes, or joined for a transport that needs one body.
 """
 
 import io
@@ -18,7 +19,7 @@ import json
 import logging
 import os
 import tarfile
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -90,32 +91,45 @@ def _meta_frame(meta: Dict[str, Any]) -> Tuple[str, np.ndarray]:
     return "__meta__", np.frombuffer(json.dumps(meta).encode("utf-8"), np.uint8)
 
 
-def encode_prediction_response(output: np.ndarray, n_input_rows: int) -> bytes:
+def prediction_frames(
+    output: np.ndarray, n_input_rows: int
+) -> List[Tuple[str, np.ndarray]]:
     """``POST /prediction`` tensor response: a ``data`` frame plus the
     sequence-warmup ``offset`` (output row i is input row i + offset) in
     ``__meta__`` — the client trims its own index by it, replacing the
     JSON body's stringified index round-trip."""
     output = np.asarray(output)
-    return pack_frames(
-        [
-            _meta_frame({"offset": int(n_input_rows - len(output))}),
-            ("data", output),
-        ]
-    )
+    return [
+        _meta_frame({"offset": int(n_input_rows - len(output))}),
+        ("data", output),
+    ]
+
+
+def anomaly_frames(
+    tags, arrays: Dict[str, np.ndarray], offset: int
+) -> List[Tuple[str, np.ndarray]]:
+    """``POST /anomaly/prediction`` tensor response: ``__meta__`` and the
+    six score arrays (``ScoreResult.to_arrays`` order) as they are — no
+    DataFrame assembly, no per-column ``tolist``. Whatever else
+    ``arrays`` holds (a shared-trunk member's selections) follows them."""
+    meta = _meta_frame({"offset": int(offset), "tags": [str(t) for t in tags]})
+    more = [name for name in arrays if name not in ANOMALY_FRAME_NAMES]
+    return [meta] + [(name, arrays[name]) for name in (*ANOMALY_FRAME_NAMES, *more)]
+
+
+def encode_prediction_response(output: np.ndarray, n_input_rows: int) -> bytes:
+    """:func:`prediction_frames` as one body, for a transport that needs
+    one piece of bytes (the shm ring's envelope); the HTTP view writes
+    the same frames' segments to its connection."""
+    return pack_frames(prediction_frames(output, n_input_rows))
 
 
 def encode_anomaly_response(
     tags, arrays: Dict[str, np.ndarray], offset: int
 ) -> bytes:
-    """``POST /anomaly/prediction`` tensor response: the six score arrays
-    (``ScoreResult.to_arrays`` order) written into one preallocated body
-    — no DataFrame assembly, no per-column ``tolist``. Whatever else
-    ``arrays`` holds (a shared-trunk member's selections) follows them."""
-    meta = _meta_frame({"offset": int(offset), "tags": [str(t) for t in tags]})
-    more = [name for name in arrays if name not in ANOMALY_FRAME_NAMES]
-    return pack_frames(
-        [meta] + [(name, arrays[name]) for name in (*ANOMALY_FRAME_NAMES, *more)]
-    )
+    """:func:`anomaly_frames` as one body (see
+    :func:`encode_prediction_response`)."""
+    return pack_frames(anomaly_frames(tags, arrays, offset))
 
 
 def anomaly_frame_arrays(frame) -> Dict[str, np.ndarray]:

@@ -26,6 +26,7 @@ from typing import Any, Dict, Optional
 import numpy as np
 import pandas as pd
 from aiohttp import web
+from aiohttp.payload import Payload
 
 from gordo_components_tpu import __version__, serializer
 from gordo_components_tpu.observability.tracing import chrome_trace, stage
@@ -35,9 +36,9 @@ from gordo_components_tpu.resilience.deadline import DeadlineExceeded
 from gordo_components_tpu.server.bank import EngineOverloaded
 from gordo_components_tpu.server.model_io import (
     anomaly_frame_arrays,
+    anomaly_frames,
     decode_tensor_request_ex,
-    encode_anomaly_response,
-    encode_prediction_response,
+    prediction_frames,
 )
 from gordo_components_tpu.server.utils import (
     extract_x_y,
@@ -49,6 +50,7 @@ from gordo_components_tpu.utils.wire import (
     TENSOR_CONTENT_TYPE,
     WireFormatError,
     encoding_of,
+    frame_segments,
     rows_as_f32,
     unpack_frames,
 )
@@ -750,11 +752,13 @@ async def server_stats(request: web.Request) -> web.Response:
         # went (metric spike -> offending trace in two clicks)
         "exemplars": stats.get("exemplars", {}),
         # the data plane by encoding (json|parquet|tensor): scoring and
-        # ingest POST counts + request body bytes — the same cells the
-        # gordo_server_request{,_bytes}_total{encoding} series render
+        # ingest POST counts, request body bytes, response body bytes and
+        # those of them written by reference — the same cells the
+        # gordo_server_{requests,request_bytes,response_bytes,
+        # response_bytes_by_reference}_total{encoding} series render
         "wire": {
-            "requests": dict(stats.get("wire", {}).get("requests", {})),
-            "bytes": dict(stats.get("wire", {}).get("bytes", {})),
+            key: dict(cells)
+            for key, cells in stats.get("wire", {}).items()
         },
         # multi-worker accept balance (server/workers.py): requests
         # parsed per worker loop — empty outside pool mode
@@ -1751,6 +1755,32 @@ def _span_engine_edges(trace) -> None:
         trace.add_span("resolve", done, now)
 
 
+class TensorBody(Payload):
+    """A tensor answer as its connection writes it: the frames' segments
+    (``utils.wire.frame_segments``) one after another, each array from
+    its own memory. No joined body exists; ``size`` is the segments' sum,
+    so the response carries ``Content-Length`` and is not chunked. The
+    segments, and through them the arrays, are held until the last byte
+    has left: the transport keeps what a ``send`` did not take by
+    reference."""
+
+    def __init__(self, frames) -> None:
+        segments = frame_segments(frames)
+        super().__init__(segments, content_type=TENSOR_CONTENT_TYPE)
+        self._size = sum(len(seg) for seg in segments)
+        # what the middleware's response counters read (stats["wire"])
+        self.by_reference = sum(
+            len(seg) for seg in segments if isinstance(seg, memoryview)
+        )
+
+    def decode(self, encoding: str = "utf-8", errors: str = "strict") -> str:
+        return b"".join(self._value).decode(encoding, errors)
+
+    async def write(self, writer) -> None:
+        for segment in self._value:
+            await writer.write(segment)
+
+
 @routes.post("/gordo/v0/{project}/{target}/prediction")
 async def prediction(request: web.Request) -> web.Response:
     model, _ = _get_model(request)
@@ -1810,11 +1840,11 @@ async def prediction(request: web.Request) -> web.Response:
         )
     _note_scoring_result(request, target, Xf, output)
     if encoding == "tensor":
-        # binary out for binary in: the output array is framed into one
-        # preallocated body — no tolist, no index stringification (the
-        # client trims its own index by the offset in __meta__)
+        # binary out for binary in: the output array is framed, not
+        # copied — no tolist, no index stringification (the client trims
+        # its own index by the offset in __meta__)
         with stage("encode", trace, stage="to_wire"):
-            body = encode_prediction_response(output, len(Xf))
+            body = TensorBody(prediction_frames(output, len(Xf)))
         return web.Response(body=body, content_type=TENSOR_CONTENT_TYPE)
     with stage("encode", trace, stage="to_json"):
         out_index = X.index[len(X) - len(output):]
@@ -1859,11 +1889,14 @@ async def anomaly_prediction(request: web.Request) -> web.Response:
             request["device_s"] = result.device_s
             if encoding == "tensor":
                 # the banked fast path end-to-end: fetched device buffers
-                # -> ScoreResult arrays -> one preallocated response
-                # body. No DataFrame is ever constructed on this path.
+                # -> ScoreResult arrays -> the connection, each array
+                # from its own memory. No DataFrame and no joined body is
+                # ever constructed on this path.
                 with stage("encode", trace, stage="to_wire"):
-                    body = encode_anomaly_response(
-                        result.tags, result.to_arrays(), result.offset
+                    body = TensorBody(
+                        anomaly_frames(
+                            result.tags, result.to_arrays(), result.offset
+                        )
                     )
                 total_scaled = result.total_scaled
             else:
@@ -1889,10 +1922,12 @@ async def anomaly_prediction(request: web.Request) -> web.Response:
                     path="per-model",
                 )
             if encoding == "tensor":
-                body = encode_anomaly_response(
-                    frame["model-input"].columns,
-                    anomaly_frame_arrays(frame),
-                    len(Xf) - len(frame),
+                body = TensorBody(
+                    anomaly_frames(
+                        frame["model-input"].columns,
+                        anomaly_frame_arrays(frame),
+                        len(Xf) - len(frame),
+                    )
                 )
     except EngineOverloaded as exc:
         raise _http_overloaded(exc)

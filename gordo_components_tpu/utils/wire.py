@@ -9,9 +9,12 @@ ndarrays with one header parse and zero value-level churn:
 
 - server parse is ``np.frombuffer`` over the request body (a view, no copy,
   no per-value float boxing);
-- server responses are written array-by-array into ONE preallocated
-  buffer (no DataFrame, no ``tolist``, no float64 shadow copies);
-- the client serializes a chunk with one C-order memory copy.
+- an HTTP response is never assembled: the connection writes the frame
+  headers and then each score array from its own memory
+  (:func:`frame_segments`; no joined body, no DataFrame, no ``tolist``,
+  no float64 shadow copies);
+- whoever needs one piece of bytes (the client's request body, the shm
+  ring's envelope) joins the same segments once (:func:`pack_frames`).
 
 Body layout (all integers little-endian)::
 
@@ -36,7 +39,7 @@ ever APPENDED to the frame header within a version — never reordered.
 """
 
 import struct
-from typing import Dict, Iterable, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -47,6 +50,7 @@ __all__ = [
     "WIRE_VERSION",
     "WireFormatError",
     "encoding_of",
+    "frame_segments",
     "pack_frames",
     "unpack_frames",
     "rows_as_f32",
@@ -98,6 +102,11 @@ _MAX_ITEMSIZE = 8
 
 _U64 = struct.Struct("<Q")
 
+# a payload under this many bytes is copied into the header bytes beside
+# it: a write of its own (a ``send`` call, a buffer on the transport's
+# queue) costs more than copying it
+INLINE_PAYLOAD_BYTES = 64 * 1024
+
 
 class WireFormatError(ValueError):
     """A tensor body that violates the frame layout. The HTTP layer maps
@@ -117,14 +126,18 @@ def _check_dtype(dtype_str: str) -> np.dtype:
     return dtype
 
 
-def pack_frames(frames: Sequence[Tuple[str, np.ndarray]]) -> bytes:
-    """Serialize named arrays into one tensor body.
+def frame_segments(
+    frames: Sequence[Tuple[str, np.ndarray]]
+) -> List[Union[bytes, memoryview]]:
+    """The buffers of a tensor body, in wire order: what a connection
+    writes one after another, and what :func:`pack_frames` joins.
 
-    Sizes are computed first and the whole body is written into ONE
-    preallocated buffer — each array's bytes are copied exactly once
-    (the C-order normalization for a non-contiguous input is the only
-    other copy this path can make). This is the response hot path: the
-    server hands fetched device buffers straight here.
+    A payload of :data:`INLINE_PAYLOAD_BYTES` or more is NOT copied: its
+    segment is a flat ``memoryview`` of the array's own memory (of its
+    C-order copy where the input was not contiguous), which keeps the
+    array alive for as long as the segment is held. Everything between
+    two such payloads (the body's head, frame headers, and the payloads
+    too small to be worth a write of their own) is one ``bytes``.
     """
     if not frames:
         raise WireFormatError("a tensor body must carry at least one frame")
@@ -132,8 +145,9 @@ def pack_frames(frames: Sequence[Tuple[str, np.ndarray]]) -> bytes:
         raise WireFormatError(
             f"{len(frames)} frames exceeds the {_MAX_FRAMES}-frame bound"
         )
-    staged = []
-    total = len(WIRE_MAGIC) + 2
+    segments: List[Union[bytes, memoryview]] = []
+    head = bytearray(WIRE_MAGIC)
+    head += bytes((WIRE_VERSION, len(frames)))
     for name, arr in frames:
         arr = np.ascontiguousarray(arr)
         _check_dtype(arr.dtype.str)
@@ -145,36 +159,35 @@ def pack_frames(frames: Sequence[Tuple[str, np.ndarray]]) -> bytes:
             raise WireFormatError(
                 f"frame {name!r} has {arr.ndim} dims (bound {_MAX_NDIM})"
             )
-        staged.append((name_b, dtype_b, arr))
-        total += 1 + len(name_b) + 1 + len(dtype_b) + 1 + 8 * arr.ndim + 8
-        total += arr.nbytes
-    buf = bytearray(total)
-    mv = memoryview(buf)
-    pos = len(WIRE_MAGIC)
-    buf[:pos] = WIRE_MAGIC
-    buf[pos] = WIRE_VERSION
-    buf[pos + 1] = len(staged)
-    pos += 2
-    for name_b, dtype_b, arr in staged:
-        buf[pos] = len(name_b)
-        pos += 1
-        buf[pos : pos + len(name_b)] = name_b
-        pos += len(name_b)
-        buf[pos] = len(dtype_b)
-        pos += 1
-        buf[pos : pos + len(dtype_b)] = dtype_b
-        pos += len(dtype_b)
-        buf[pos] = arr.ndim
-        pos += 1
+        head.append(len(name_b))
+        head += name_b
+        head.append(len(dtype_b))
+        head += dtype_b
+        head.append(arr.ndim)
         for dim in arr.shape:
-            _U64.pack_into(buf, pos, dim)
-            pos += 8
-        _U64.pack_into(buf, pos, arr.nbytes)
-        pos += 8
-        if arr.nbytes:
-            mv[pos : pos + arr.nbytes] = memoryview(arr).cast("B")
-            pos += arr.nbytes
-    return bytes(buf)
+            head += _U64.pack(dim)
+        head += _U64.pack(arr.nbytes)
+        if not arr.nbytes:
+            continue
+        payload = memoryview(arr).cast("B")
+        if arr.nbytes < INLINE_PAYLOAD_BYTES:
+            head += payload
+        else:
+            segments.append(bytes(head))
+            segments.append(payload)
+            head = bytearray()
+    if head:
+        segments.append(bytes(head))
+    return segments
+
+
+def pack_frames(frames: Sequence[Tuple[str, np.ndarray]]) -> bytes:
+    """Serialize named arrays into one tensor body: the join of
+    :func:`frame_segments`, one allocation and one copy of each array's
+    bytes (the C-order normalization of a non-contiguous input is the
+    only other copy this path can make). For callers that need one piece
+    of bytes: request bodies, the shm ring's envelope."""
+    return b"".join(frame_segments(frames))
 
 
 def unpack_frames(data: bytes) -> "Dict[str, np.ndarray]":

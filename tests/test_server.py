@@ -11,7 +11,13 @@ from aiohttp.test_utils import TestClient, TestServer
 from gordo_components_tpu import serializer
 from gordo_components_tpu.models import AutoEncoder, DiffBasedAnomalyDetector
 from gordo_components_tpu.server import build_app
+from gordo_components_tpu.server.transport import score_tensor_blocking
 from gordo_components_tpu.server.utils import dict_to_frame, frame_to_dict
+from gordo_components_tpu.utils.wire import (
+    TENSOR_CONTENT_TYPE,
+    pack_frames,
+    unpack_frames,
+)
 
 
 @pytest.fixture(scope="module")
@@ -33,8 +39,8 @@ def artifact_dir(tmp_path_factory):
 
 
 @contextlib.asynccontextmanager
-async def make_client(artifact_dir):
-    client = TestClient(TestServer(build_app(artifact_dir)))
+async def make_client(artifact_dir, **kwargs):
+    client = TestClient(TestServer(build_app(artifact_dir, **kwargs)))
     await client.start_server()
     try:
         yield client
@@ -225,3 +231,144 @@ def test_frame_dict_roundtrip():
     rt = dict_to_frame(frame_to_dict(df))
     assert list(rt.columns) == list(df.columns)
     np.testing.assert_allclose(rt.values, df.values)
+
+
+# --------------------------------------------------------------------- #
+# a tensor answer goes to the socket as its arrays (views.TensorBody)
+# --------------------------------------------------------------------- #
+
+
+def _tensor_request(rows, seed):
+    X = np.random.RandomState(seed).rand(rows, 3).astype("float32")
+    return X, pack_frames([("X", X)])
+
+
+async def _stored_way(app, target, body, endpoint):
+    """The same request through ``score_tensor_blocking``: the joined
+    ``encode_*_response`` bytes, as the shm transport puts them in its
+    ring's envelope."""
+    import asyncio
+
+    status, want = await asyncio.get_running_loop().run_in_executor(
+        None, score_tensor_blocking, app, target, body, endpoint
+    )
+    assert status == 200
+    return want
+
+
+@pytest.mark.parametrize(
+    "target, endpoint, use_bank",
+    [
+        ("machine-a", "anomaly", True),  # the banked path: ScoreResult's arrays
+        ("machine-a", "anomaly", False),  # the per-model path: a frame's columns
+        ("machine-a", "prediction", True),
+        ("machine-b", "prediction", True),  # a bare estimator: never banked
+    ],
+)
+async def test_tensor_answer_is_sent_under_its_length_as_the_encoders_bytes(
+    artifact_dir, target, endpoint, use_bank
+):
+    """30 000 rows: every (rows, 3) array is over the small-payload rule,
+    so it leaves by reference; the body is still byte for byte what
+    ``encode_*_response`` joins, under ``Content-Length``, not chunked."""
+    _, body = _tensor_request(30_000, seed=5)
+    path = "anomaly/prediction" if endpoint == "anomaly" else "prediction"
+    async with make_client(artifact_dir, use_bank=use_bank) as client:
+        resp = await client.post(
+            f"/gordo/v0/proj/{target}/{path}", data=body,
+            headers={"Content-Type": TENSOR_CONTENT_TYPE},
+        )
+        assert resp.status == 200
+        raw = await resp.read()
+        want = await _stored_way(client.app, target, body, endpoint)
+        stats = await (await client.get("/gordo/v0/proj/stats")).json()
+    assert resp.content_type == TENSOR_CONTENT_TYPE
+    assert resp.headers["Content-Length"] == str(len(raw))
+    assert "Transfer-Encoding" not in resp.headers
+    assert raw == want
+    sent = stats["wire"]["response_bytes"]["tensor"]
+    by_reference = stats["wire"]["response_bytes_by_reference"]["tensor"]
+    assert sent == len(raw)
+    large = sum(
+        a.nbytes for a in unpack_frames(raw).values() if a.nbytes >= 64 * 1024
+    )
+    assert large >= 30_000 * 3 * 4
+    assert by_reference == large
+
+
+async def test_two_answers_in_flight_each_come_back_as_their_own(artifact_dir):
+    """The first answer's reader stalls after the headers with megabytes
+    still on the server's side of the socket, held by reference; a second
+    request is scored and answered meanwhile (the bank's staging buffers
+    are used again). Each body must be its own request's: a segment that
+    referenced anything reused would show the other's rows."""
+    import aiohttp
+
+    url = "/gordo/v0/proj/machine-a/anomaly/prediction"
+    headers = {"Content-Type": TENSOR_CONTENT_TYPE}
+    (X1, body1), (X2, body2) = _tensor_request(150_000, 11), _tensor_request(150_000, 12)
+    async with make_client(artifact_dir) as client:
+        base = str(client.make_url(""))
+        async with aiohttp.ClientSession() as one, aiohttp.ClientSession() as two:
+            first = await one.post(base + url, data=body1, headers=headers)
+            assert first.status == 200  # headers are in; the body is not read
+            # the writer is stalled mid-segment (the rest of that array
+            # sits on the transport's queue by reference, the segments
+            # after it wait in the payload)
+            held = max(
+                conn.transport.get_write_buffer_size()
+                for conn in client.server.runner.server.connections
+            )
+            assert held > 2**16
+            second = await two.post(base + url, data=body2, headers=headers)
+            raw2 = await second.read()
+            raw1 = await first.read()
+        want1 = await _stored_way(client.app, "machine-a", body1, "anomaly")
+        want2 = await _stored_way(client.app, "machine-a", body2, "anomaly")
+        stats = await (await client.get("/gordo/v0/proj/stats")).json()
+    assert len(raw1) > 4 * X1.nbytes  # more than a socket buffer holds
+    np.testing.assert_array_equal(unpack_frames(raw1)["model-input"], X1)
+    np.testing.assert_array_equal(unpack_frames(raw2)["model-input"], X2)
+    assert raw1 == want1
+    assert raw2 == want2
+    assert raw1 != raw2
+    assert stats["wire"]["response_bytes"]["tensor"] == len(raw1) + len(raw2)
+
+
+async def test_response_counters_add_up_to_the_bytes_sent(artifact_dir):
+    """``/stats`` ``wire.response_bytes`` and ``.response_bytes_by_reference``
+    (and their Prometheus series) by the REQUEST's encoding: a JSON answer
+    counts whole and nothing by reference; a small tensor answer is all
+    header bytes and copied payloads; a large one leaves by reference but
+    for its headers, totals and ``__meta__``."""
+    url = "/gordo/v0/proj/machine-a/anomaly/prediction"
+    headers = {"Content-Type": TENSOR_CONTENT_TYPE}
+    async with make_client(artifact_dir) as client:
+        resp = await client.post(url, json=_x_payload())
+        json_bytes = len(await resp.read())
+        small = await (
+            await client.post(url, data=_tensor_request(20, 1)[1], headers=headers)
+        ).read()
+        large = await (
+            await client.post(url, data=_tensor_request(30_000, 2)[1], headers=headers)
+        ).read()
+        refused = await client.post(url, data=b"NOPE", headers=headers)
+        assert refused.status == 400
+        stats = await (await client.get("/gordo/v0/proj/stats")).json()
+        text = await (await client.get("/gordo/v0/proj/metrics")).text()
+    wire = stats["wire"]
+    assert wire["response_bytes"] == {
+        "json": json_bytes, "tensor": len(small) + len(large),
+    }
+    by_reference = 4 * 30_000 * 3 * 4 + 2 * 30_000 * 4  # four arrays, two totals
+    assert wire["response_bytes_by_reference"] == {"json": 0, "tensor": by_reference}
+    assert by_reference / len(large) > 0.99
+    assert (
+        f'gordo_server_response_bytes_total{{encoding="tensor"}} '
+        f"{len(small) + len(large)}" in text
+    )
+    assert (
+        f'gordo_server_response_bytes_by_reference_total{{encoding="tensor"}} '
+        f"{by_reference}" in text
+    )
+    assert f'gordo_server_response_bytes_total{{encoding="json"}} {json_bytes}' in text

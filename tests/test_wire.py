@@ -21,6 +21,7 @@ below the JSON path it bypasses (``make perf-guard``).
 
 import contextlib
 import json
+import os
 
 import numpy as np
 import pytest
@@ -31,10 +32,12 @@ from gordo_components_tpu import resilience, serializer
 from gordo_components_tpu.models import AutoEncoder, DiffBasedAnomalyDetector
 from gordo_components_tpu.resilience import FaultInjected
 from gordo_components_tpu.server import build_app
+from gordo_components_tpu.utils import wire
 from gordo_components_tpu.utils.wire import (
     TENSOR_CONTENT_TYPE,
     WIRE_MAGIC,
     WireFormatError,
+    frame_segments,
     pack_frames,
     rows_as_f32,
     unpack_frames,
@@ -186,6 +189,145 @@ class TestFrameCodec:
             pack_frames([])
         with pytest.raises(WireFormatError, match="1..255"):
             pack_frames([("", _x())])
+
+
+# --------------------------------------------------------------------- #
+# 1b. the body as segments: the same bytes, large payloads by reference
+# --------------------------------------------------------------------- #
+
+_DTYPES = ["<f4", "<f8", "<i4", "<i8", "|u1", "|b1"]
+_SHAPES = [(3, 4), (0, 5), (7,), (2, 3, 2)]
+_RULE_AS_SHIPPED = 64 * 1024  # the stored "answer" body stands on both sides of it
+
+
+def _ramp(n, dtype="<f4"):
+    return (np.arange(n) % 251).astype(dtype)
+
+
+def _stored_cases():
+    """Seeded frames whose bodies ``tests/data/wire_bodies.npz`` holds as
+    ``pack_frames`` wrote them BEFORE it became a join of segments (one
+    preallocated buffer, every array copied in): the codec's existing
+    cases, and an answer-like body with payloads on both sides of
+    ``INLINE_PAYLOAD_BYTES``."""
+    rng = np.random.RandomState(0)
+    cases = {}
+    for dtype in _DTYPES:
+        for shape in _SHAPES:
+            arr = (rng.rand(*shape) * 100).astype(np.dtype(dtype))
+            name = f"{np.dtype(dtype).name}-{'x'.join(map(str, shape))}"
+            cases[name] = [("a", arr)]
+    cases["zero-d"] = [("s", np.float32(1.5)), ("t", np.array(7, "<i8"))]
+    cases["empty-beside-full"] = [("e", np.zeros((0, 5), "<f4")), ("x", _x(3, 2))]
+    cases["non-contiguous"] = [("t", _x(6, 4).T), ("s", _x(8, 6)[::2, ::3])]
+    cases["big-endian"] = [("X", _x(4, 2).astype(">f4"))]
+    cases["64-frames"] = [(f"f{i:02d}", _ramp(i, "<i4")) for i in range(64)]
+    at = _RULE_AS_SHIPPED // 4
+    cases["answer"] = [
+        ("__meta__", np.frombuffer(b'{"offset": 0, "tags": ["a", "b"]}', np.uint8)),
+        ("model-input", _ramp(2 * at).reshape(-1, 2)),  # twice the rule
+        ("model-output", _ramp(at)),  # at the rule: by reference
+        ("total-anomaly-scaled", _ramp(at - 1)),  # one value under it: copied
+        ("expert-selection", _ramp(8 * at, "|u1").reshape(4, -1, 8)),
+    ]
+    return cases
+
+
+def _stored_body(case):
+    path = os.path.join(os.path.dirname(__file__), "data", "wire_bodies.npz")
+    with np.load(path) as stored:
+        return stored[case].tobytes()
+
+
+@pytest.fixture(params=["rule-as-shipped", "every-payload-by-reference"])
+def inline_rule(request, monkeypatch):
+    if request.param == "every-payload-by-reference":
+        monkeypatch.setattr(wire, "INLINE_PAYLOAD_BYTES", 0)
+    return request.param
+
+
+class TestSegments:
+    @pytest.mark.parametrize("case", list(_stored_cases()))
+    def test_joined_segments_are_the_stored_body(self, case, inline_rule):
+        frames = _stored_cases()[case]
+        want = _stored_body(case)
+        segments = frame_segments(frames)
+        assert b"".join(segments) == want
+        assert pack_frames(frames) == want
+        assert sum(len(seg) for seg in segments) == len(want)
+        # a transport takes each as it is: flat bytes
+        assert all(
+            isinstance(seg, bytes) or (seg.format == "B" and seg.ndim == 1)
+            for seg in segments
+        )
+        got = unpack_frames(b"".join(segments))
+        assert list(got) == [name for name, _ in frames]
+        for name, arr in frames:
+            np.testing.assert_array_equal(got[name], np.ascontiguousarray(arr))
+
+    def test_a_large_payload_is_the_arrays_memory_a_small_one_is_not(self):
+        assert wire.INLINE_PAYLOAD_BYTES == _RULE_AS_SHIPPED
+        frames = dict(_stored_cases()["answer"])
+        segments = frame_segments(list(frames.items()))
+        views = [seg for seg in segments if isinstance(seg, memoryview)]
+        # header runs and payloads alternate: head, input, head, output,
+        # head (with the total copied in), selections
+        assert [type(seg) for seg in segments] == [bytes, memoryview] * 3
+        for seg, name in zip(views, ("model-input", "model-output", "expert-selection")):
+            assert np.shares_memory(np.frombuffer(seg, np.uint8), frames[name])
+            assert len(seg) == frames[name].nbytes
+        small = frames["total-anomaly-scaled"]
+        assert not any(
+            np.shares_memory(np.frombuffer(seg, np.uint8), small) for seg in segments
+        )
+        assert small.tobytes() in segments[4]
+
+    def test_a_segment_keeps_its_array_alive(self):
+        arr = _ramp(wire.INLINE_PAYLOAD_BYTES)
+        want = arr.tobytes()
+        segments = frame_segments([("a", arr)])
+        del arr
+        assert bytes(segments[1]) == want
+
+    def test_a_non_contiguous_payload_is_referenced_through_its_copy(self):
+        arr = _ramp(wire.INLINE_PAYLOAD_BYTES).reshape(-1, 4).T
+        segments = frame_segments([("a", arr)])
+        assert not np.shares_memory(np.frombuffer(segments[1], np.uint8), arr)
+        assert bytes(segments[1]) == np.ascontiguousarray(arr).tobytes()
+
+    def test_the_checks_stand_before_any_segment(self):
+        with pytest.raises(WireFormatError, match="at least one frame"):
+            frame_segments([])
+        with pytest.raises(WireFormatError, match="64-frame bound"):
+            frame_segments([(f"f{i}", _x(1, 1)) for i in range(65)])
+        with pytest.raises(WireFormatError, match="1..255"):
+            frame_segments([("n" * 256, _x())])
+        with pytest.raises(WireFormatError, match="not allowed"):
+            frame_segments([("c", np.zeros(2, "<c8"))])
+        with pytest.raises(WireFormatError, match="9 dims"):
+            frame_segments([("d", np.zeros((1,) * 9, "<f4"))])
+
+
+def test_the_encode_ladder_runs_at_a_tiny_size(monkeypatch, tmp_path, capsys):
+    """``tools/encode_ladder.py`` (the chip-host run behind PERF.md's
+    table for PR 34), here for its control flow: the three ways serve one
+    body, which the tool itself compares byte for byte."""
+    import importlib.util
+    import pathlib
+
+    monkeypatch.chdir(tmp_path)
+    path = pathlib.Path(__file__).parents[1] / "tools" / "encode_ladder.py"
+    spec = importlib.util.spec_from_file_location("encode_ladder", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    frames = tool.answer_frames("2048x8s", np.random.default_rng(0))
+    assert tool.stored_pack(frames) == pack_frames(frames)
+    tool.main(["--shapes", "16x4", "2048x8s", "--reps", "1"])
+    out = json.loads((tmp_path / "chiprun_out" / "encode_ladder.json").read_text())
+    for shape in ("16x4", "2048x8s"):
+        assert set(out[shape]) == {"stored", "join", "segments"}
+        assert len({way["body_bytes"] for way in out[shape].values()}) == 1
+    assert "client_ms" in capsys.readouterr().out
 
 
 # --------------------------------------------------------------------- #
