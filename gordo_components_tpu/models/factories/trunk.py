@@ -17,7 +17,15 @@ features in place of embedding rows) and leave through its own linear head
   group-limited sigmoid router over ``n_routed_experts`` beside a shared
   expert, of which this trunk holds the range ``expert_offset ..
   expert_offset + experts_held`` (one chip's share of a layer divided over
-  chips by experts: ``ops/moe.py``).
+  chips by experts: ``ops/moe.py``). Where the configuration has
+  ``indexer_types``, the attention runs under an indexer's selection of
+  keys (``ops/sparse_attention.py``'s ``select_keys``) that a ``full``
+  layer makes and the ``shared`` layers after it reuse.
+
+The walk through the layers carries the residual stream AND the selection
+in force: ``layer(w, x, n_valid, selection)`` returns the next ``x``, the
+selection it made or was handed (``None`` for a kind or a configuration
+that shares none) and what it observed.
 
 Parameters split in two:
 
@@ -43,7 +51,9 @@ import jax.numpy as jnp
 from gordo_components_tpu.models.register import register_model_builder
 from gordo_components_tpu.ops.latent_attention import latent_attention, yarn
 from gordo_components_tpu.ops.moe import expert_layer
-from gordo_components_tpu.ops.sparse_attention import WITNESS_STRIDE, rope, select_and_attend
+from gordo_components_tpu.ops.sparse_attention import (
+    WITNESS_STRIDE, rope, select_and_attend, select_keys, witness_of,
+)
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 # the routed experts of a ``LatentMoEDecoder`` take the rows in runs of at
@@ -77,7 +87,8 @@ class _Trunk:
     """What every kind of trunk shares: the member's two projections, the
     random init, and the walk through the layers. A kind adds its sizes
     (``hidden_size``, ``num_hidden_layers``, ``rms_norm_eps``,
-    ``chunk_size`` among them), ``layer_shapes`` and ``layer``."""
+    ``chunk_size`` among them), ``layer_shapes`` and ``layer``
+    (``(w, x, n_valid, selection, interpret) -> (x, selection, seen)``)."""
 
     n_features: int
 
@@ -89,6 +100,11 @@ class _Trunk:
     def padded_rows(self, rows: int) -> int:
         """A request is never cut: it is padded to whole chunks."""
         return -(-int(rows) // self.chunk_size) * self.chunk_size
+
+    def witness_stride(self) -> int:
+        """Every how-manieth query's selection a layer that selects keys
+        observes as its ``witness``."""
+        return min(WITNESS_STRIDE, self.chunk_size)
 
     # -------------------------------------------------------------- init
 
@@ -150,10 +166,10 @@ class _Trunk:
         ``(out, observed)``: (B, T, F), or the normed state (B, T, D)
         where ``member`` has no head yet, and the layers' observations
         stacked (layers that observed it, ...)."""
-        x = self.embed(member["in_proj"], xs)
+        x, selection = self.embed(member["in_proj"], xs), None
         observed = []
         for w in trunk["layers"]:
-            x, seen = self.layer(w, x, n_valid, interpret)
+            x, selection, seen = self.layer(w, x, n_valid, selection, interpret)
             observed.append(seen)
         out = self.head(trunk["final_norm"], member.get("head"), x)
         return out, stack_observed(observed, jnp.stack)
@@ -259,16 +275,20 @@ class SparseMoEDecoder(_Trunk):
 
     # ----------------------------------------------------------- forward
 
-    def layer(self, w, x, n_valid, interpret: bool = False):
+    def layer(self, w, x, n_valid, selection=None, interpret: bool = False):
         """One decoder layer over the B x T rows of ``x`` (B, T, D), T a
         multiple of ``chunk_size``; ``n_valid`` (B,): rows beyond it are
         padding. Every layer of this kind has the same shapes, so a caller
-        that jits this compiles it once whatever the depth.
+        that jits this compiles it once whatever the depth. Every layer
+        makes its own selection (``select_and_attend``: ``select_keys``,
+        then the grouped-query kernel) and hands none on: ``selection`` is
+        taken and returned as it came (``None``).
 
-        Returns the next ``x`` and what the layer observed: ``experts``
-        (B, T, top_k) uint8, ``witness`` (B, T // stride, T // 8) uint8
-        (``ops/sparse_attention.py``), ``expert_tokens`` (E,) and
-        ``selections`` (B,) int32."""
+        Returns the next ``x``, ``selection``, and what the layer observed:
+        ``experts`` (B, T, top_k) uint8, ``witness`` (B, T // stride,
+        T // 8) uint8 (``ops/sparse_attention.py``), ``expert_tokens``
+        (E,), ``selections`` (B,) int32 and ``selection_uses`` () int32,
+        1: this layer attended under a selection."""
         B, T, D = x.shape
         H, G, d = self.num_attention_heads, self.num_key_value_heads, self.head_dim
         J, dI = self.indexer_num_heads, self.indexer_head_dim
@@ -302,13 +322,11 @@ class SparseMoEDecoder(_Trunk):
         y, experts, tokens = expert_layer(
             h, w, self.num_experts_per_tok, valid.reshape(-1), interpret
         )
-        return x + y.reshape(B, T, D), {
+        return x + y.reshape(B, T, D), selection, {
             "experts": experts.reshape(B, T, -1).astype(jnp.uint8), "witness": witness,
             "expert_tokens": tokens, "selections": selections,
+            "selection_uses": jnp.ones((), jnp.int32),
         }
-
-    def witness_stride(self) -> int:
-        return min(WITNESS_STRIDE, self.chunk_size)
 
 
 @dataclass(frozen=True)
@@ -321,14 +339,30 @@ class LatentMoEDecoder(_Trunk):
     ``expert_offset .. expert_offset + experts_held`` of every routed layer
     (``experts_held=None``: all of them).
 
+    The published keys present decide what a layer holds. With
+    ``indexer_types`` (one entry a held layer, ``"full"`` or ``"shared"``,
+    the first ``"full"``) the attention runs under a selection of
+    ``index_topk`` keys a query: a ``full`` layer has an indexer
+    (``index_n_heads`` heads of ``index_head_dim``, its queries from the
+    NORMED QUERY LATENT, RoPE on the first ``qk_rope_head_dim`` of a head)
+    and makes the selection, a ``shared`` layer has none and attends under
+    the selection of the nearest ``full`` layer below it. Without the key no
+    layer selects: every causal key. ``topk_method: "noaux_tc"`` gives every
+    routed layer a ``router_bias`` that enters the choice of experts and not
+    their weights (``ops/moe.py``).
+
     A layer's leaves (``layer_shapes``): ``attn_norm`` (D,), ``q_a``
     (D, q_lora_rank), ``q_a_norm``, ``q_b`` (q_lora_rank, H*(nope+rope)),
     ``kv_a`` (D, kv_lora_rank+rope), ``kv_a_norm``, ``kv_b`` (kv_lora_rank,
-    H*(nope+v)), ``wo`` (H*v, D), ``mlp_norm`` (D,); then, a dense layer:
-    ``gate``/``up`` (D, intermediate_size), ``down``; a routed layer:
-    ``router`` (D, n_routed_experts), ``gate``/``up`` (held, D, I),
+    H*(nope+v)), ``wo`` (H*v, D), ``mlp_norm`` (D,); a ``full`` layer's
+    indexer: ``idx_wq`` (q_lora_rank, J*dI), ``idx_wk`` (D, dI),
+    ``idx_k_scale``/``idx_k_bias`` (dI,), ``idx_ww`` (D, J); then, a dense
+    layer: ``gate``/``up`` (D, intermediate_size), ``down``; a routed
+    layer: ``router`` (D, n_routed_experts), ``router_bias``
+    (n_routed_experts,) under ``noaux_tc``, ``gate``/``up`` (held, D, I),
     ``down`` (held, I, D), ``shared_gate``/``shared_up`` (D, I * shared),
-    ``shared_down``. ``layer`` tells the two by the leaves it is handed."""
+    ``shared_down``. ``layer`` tells dense from routed and ``full`` from
+    ``shared`` by the leaves it is handed."""
 
     hidden_size: int = 7168
     num_hidden_layers: int = 61
@@ -348,12 +382,28 @@ class LatentMoEDecoder(_Trunk):
     topk_group: int = 4
     routed_scaling_factor: float = 2.5
     scoring_func: str = "sigmoid"
+    topk_method: str = "greedy"
     rope_theta: float = 10000.0
     rope_scaling: Optional[dict] = None
     rms_norm_eps: float = 1e-6
+    index_n_heads: int = 32
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    indexer_types: Optional[Tuple[str, ...]] = None
     expert_offset: int = 0
     experts_held: Optional[int] = None
     chunk_size: int = 512
+
+    def __post_init__(self):
+        kinds = self.indexer_types
+        if kinds is None:
+            return
+        if (len(kinds) != self.num_hidden_layers or kinds[0] != "full"
+                or set(kinds) - {"full", "shared"}):
+            raise ValueError(
+                f"indexer_types {kinds!r}: one of 'full' or 'shared' for each of the "
+                f"{self.num_hidden_layers} layers held, the first 'full' (a shared layer "
+                "attends under the selection of a full layer below it)")
 
     # ------------------------------------------------------------ shapes
 
@@ -361,26 +411,42 @@ class LatentMoEDecoder(_Trunk):
     def held(self) -> int:
         return self.n_routed_experts if self.experts_held is None else self.experts_held
 
-    def attention_shapes(self) -> Dict[str, Tuple[int, ...]]:
+    def _full_layers(self) -> int:
+        """Layers that make a selection (0 without ``indexer_types``)."""
+        return sum(kind == "full" for kind in self.indexer_types or ())
+
+    def attention_shapes(self, full: bool = False) -> Dict[str, Tuple[int, ...]]:
+        """The latent attention's leaves; ``full``: and an indexer's."""
         D, H = self.hidden_size, self.num_attention_heads
         rq, rkv = self.q_lora_rank, self.kv_lora_rank
         nope, rope_dim, dv = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
-        return {
+        shapes = {
             "attn_norm": (D,), "q_a": (D, rq), "q_a_norm": (rq,), "q_b": (rq, H * (nope + rope_dim)),
             "kv_a": (D, rkv + rope_dim), "kv_a_norm": (rkv,), "kv_b": (rkv, H * (nope + dv)),
             "wo": (H * dv, D), "mlp_norm": (D,),
         }
+        if full:
+            J, dI = self.index_n_heads, self.index_head_dim
+            shapes.update({"idx_wq": (rq, J * dI), "idx_wk": (D, dI), "idx_k_scale": (dI,),
+                           "idx_k_bias": (dI,), "idx_ww": (D, J)})
+        return shapes
 
     def layer_shapes(self, layer: int = -1) -> Dict[str, Tuple[int, ...]]:
-        """Layer ``layer``'s leaves (default: a routed layer's)."""
+        """Layer ``layer``'s leaves (negative: from the last, the default
+        the last layer's): dense below ``first_k_dense_replace`` and routed
+        from there, with an indexer's where ``indexer_types`` calls the
+        layer ``full``."""
         D, I = self.hidden_size, self.moe_intermediate_size
-        shapes = self.attention_shapes()
-        if 0 <= layer < self.first_k_dense_replace:
+        layer = layer % self.num_hidden_layers
+        shapes = self.attention_shapes(
+            full=self.indexer_types is not None and self.indexer_types[layer] == "full")
+        if layer < self.first_k_dense_replace:
             W = self.intermediate_size
             return {**shapes, "gate": (D, W), "up": (D, W), "down": (W, D)}
         S = I * self.n_shared_experts
+        bias = {"router_bias": (self.n_routed_experts,)} if self.topk_method == "noaux_tc" else {}
         return {
-            **shapes, "router": (D, self.n_routed_experts),
+            **shapes, "router": (D, self.n_routed_experts), **bias,
             "gate": (self.held, D, I), "up": (self.held, D, I), "down": (self.held, I, D),
             "shared_gate": (D, S), "shared_up": (D, S), "shared_down": (S, D),
         }
@@ -410,33 +476,74 @@ class LatentMoEDecoder(_Trunk):
         fifth (2.52 GB at two requests: they attend one after another and
         a run of the experts is as long either way), which errs to the
         side of a smaller batch: on a v5e the benchmark's trunk and bank
-        leave 5.82e9 bytes, so one request a call and not two."""
+        leave 5.82e9 bytes, so one request a call and not two.
+
+        Under ``indexer_types`` the attention's point also holds the
+        selection: every request's (rows, rows) mask as the layer was
+        handed it and as it hands it on (int8), one request's as
+        ``select_keys`` builds it (bool), and one chunk's indexer dots
+        (float32, every indexer head against every key). These 0.99 GB are
+        ADDED to the attention's 3.36 GB as if they were live together, and
+        they are not: the compiler's analysis of the three layer programs
+        at one request of 10 240 rows reads 1.85 to 2.08 GB of temporaries
+        and 0.36 GB of outputs (``tests/test_tpu_compile.py`` prints them)
+        where this count reads 5.10 GB, so its whole need is under the
+        attention's point alone and no two of the points coincide as
+        counted. The selection at a point of its own (the kernel's
+        bfloat16 inputs, the indexer's queries and the terms above: 2.08
+        GB) would be the smaller of the two, and the count 4.11 GB a
+        request and 8.22 GB for two where the benchmark's chip has 7.70e9
+        free: one request a call either way, by 7% where the sum leaves
+        22%. The sum was kept for that margin: a bank that took two would
+        need a second warmed shape."""
         D, H, I = self.hidden_size, self.num_attention_heads, self.moe_intermediate_size
         per_head = 2 * self.qk_nope_head_dim + self.qk_rope_head_dim + self.v_head_dim
         attention = batch * rows * (6 * H * per_head + 2 * H * self.v_head_dim + 4 * D)
+        if self.indexer_types is not None:
+            attention += ((2 * batch + 1) * rows * rows
+                          + 4 * self.chunk_size * rows * self.index_n_heads)
         dense = batch * rows * 10 * self.intermediate_size if self.first_k_dense_replace else 0
         run = self._rows_a_run(batch * rows) * self.num_experts_per_tok * (10 * D + 10 * I)
         return int(max(attention, dense, run) + batch * rows * 12 * D)
 
+    def selected_pairs(self, rows: int) -> int:
+        """(query, key) pairs one layer attends over in a ``rows``-row
+        request: the indexer's ``min(t + 1, index_topk)`` a query, or every
+        causal pair where the configuration selects none."""
+        n = int(rows)
+        if self.indexer_types is None or n <= self.index_topk:
+            return n * (n + 1) // 2
+        k = self.index_topk
+        return k * (k + 1) // 2 + (n - k) * k
+
     def forward_flops_per_row(self, context_rows: int) -> float:
         """Forward FLOPs of one row of a ``context_rows``-row request,
         averaged over its positions: 2 a multiply-add of the matrices a row
-        meets (the latent attention's five, the dense layers' three, a
-        routed layer's router, shared expert and the held experts' share of
-        its ``num_experts_per_tok`` at an even load) and the causal
-        attention's scores and values."""
+        meets (the latent attention's five in every layer, an indexer's
+        three in the ``full`` ones, the ``first_k_dense_replace`` dense
+        layers' three, a routed layer's router, shared expert and the held
+        experts' share of its ``num_experts_per_tok`` at an even load), the
+        attention's scores and values over the pairs it attends over (the
+        selected ones under ``indexer_types``, else every causal pair), and
+        a ``full`` layer's indexer scores over every causal pair."""
         D, H, I = self.hidden_size, self.num_attention_heads, self.moe_intermediate_size
-        shapes = self.attention_shapes()
-        attention = sum(math.prod(shapes[n]) for n in ("q_a", "q_b", "kv_a", "kv_b", "wo"))
+        n = int(context_rows)
+        shapes = self.attention_shapes(full=True)
+        size = lambda *names: sum(math.prod(shapes[name]) for name in names)
+        attention = size("q_a", "q_b", "kv_a", "kv_b", "wo")
+        full_layers = self._full_layers()
         dense_layers = min(self.first_k_dense_replace, self.num_hidden_layers)
         routed_layers = self.num_hidden_layers - dense_layers
         routed = (D * self.n_routed_experts + 3 * D * I * self.n_shared_experts
                   + 3 * D * I * self.num_experts_per_tok * self.held / self.n_routed_experts)
-        matrices = (self.num_hidden_layers * attention + dense_layers * 3 * D * self.intermediate_size
+        matrices = (self.num_hidden_layers * attention
+                    + full_layers * size("idx_wq", "idx_wk", "idx_ww")
+                    + dense_layers * 3 * D * self.intermediate_size
                     + routed_layers * routed + 2 * self.n_features * D)
         width = self.qk_nope_head_dim + self.qk_rope_head_dim + self.v_head_dim
-        attend = 2.0 * H * width * (int(context_rows) + 1) / 2.0
-        return 2.0 * matrices + self.num_hidden_layers * attend
+        attend = 2.0 * H * width * self.selected_pairs(n) / n
+        index = 2.0 * self.index_n_heads * self.index_head_dim * (n + 1) / 2.0
+        return 2.0 * matrices + self.num_hidden_layers * attend + full_layers * index
 
     def nominal_context_rows(self) -> int:
         """The request length a per-row FLOP count is quoted at: twenty
@@ -445,8 +552,17 @@ class LatentMoEDecoder(_Trunk):
 
     # ----------------------------------------------------------- forward
 
-    def _attention(self, w, x, interpret: bool):
-        """``MLA(RMSNorm(x))``: (B, T, D) float32."""
+    def _attention(self, w, x, n_valid, selection, interpret: bool):
+        """``(MLA(RMSNorm(x)) (B, T, D) float32, the selection it ran
+        under, what it observed)``. A layer with an indexer among its
+        leaves makes the selection ((B, T, T) int8, the form the kernel
+        reads tile by tile) and observes ``selections`` (``select_keys``);
+        any other attends under the one it was handed, or over every causal
+        key where it was handed none. EVERY layer that attends under a
+        selection observes ``witness``, cut from the very array its kernel
+        is handed: a layer that attended under another selection than it
+        should have, a stale one or none of the indexer's, shows in the
+        answer."""
         B, T, _ = x.shape
         H, rq, rkv = self.num_attention_heads, self.q_lora_rank, self.kv_lora_rank
         nope, rope_dim, dv = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
@@ -455,42 +571,74 @@ class LatentMoEDecoder(_Trunk):
         scale = softmax_multiplier / math.sqrt(nope + rope_dim)
         per_head = lambda x, m: jnp.einsum(  # (B, T, r) x (r, H * k) -> heads first (B, H, T, k)
             "btr,rhk->bhtk", x.astype(BF16), m.reshape(m.shape[0], H, -1), preferred_element_type=F32)
-        turn = lambda x: rope(x[..., None, :], positions, self.rope_theta, inv_freq=inv_freq)[..., 0, :]
+        turn = lambda x: rope(x, positions, self.rope_theta, rope_dim, inv_freq)  # (..., T, heads, d)
+        one = lambda x: turn(x[..., None, :])[..., 0, :]
+        seen = {}
         with jax.named_scope("trunk/project"):
             h = _rmsnorm(x, w["attn_norm"], eps)
-            q = per_head(_rmsnorm(_mm(h, w["q_a"]), w["q_a_norm"], eps), w["q_b"]) * scale
+            c_q = _rmsnorm(_mm(h, w["q_a"]), w["q_a_norm"], eps)
+            q = per_head(c_q, w["q_b"]) * scale
             latent = _mm(h, w["kv_a"])  # (B, T, rkv + rope)
             kv = per_head(_rmsnorm(latent[..., :rkv], w["kv_a_norm"], eps), w["kv_b"])
-            args = (q[..., :nope], turn(q[..., nope:]), kv[..., :nope], turn(latent[..., rkv:]), kv[..., nope:])
+            args = (q[..., :nope], one(q[..., nope:]), kv[..., :nope], one(latent[..., rkv:]), kv[..., nope:])
             args = jax.tree.map(lambda a: a.astype(BF16), args)
+            if "idx_wq" in w:
+                J, dI = self.index_n_heads, self.index_head_dim
+                qi = turn(_mm(c_q, w["idx_wq"]).reshape(B, T, J, dI))
+                ki = one(_layernorm(_mm(h, w["idx_wk"]), w["idx_k_scale"], w["idx_k_bias"]))
+                wi = _mm(h, w["idx_ww"])
+        if "idx_wq" in w:
+
+            def select(indexer):
+                return select_keys(*indexer, topk=self.index_topk, chunk=self.chunk_size,
+                                   divisor=math.sqrt(J * dI))
+
+            # one request at a time: a chunk's indexer dots are what bounds this
+            mask, seen["selections"], _ = jax.lax.map(select, (qi, ki, wi, n_valid))
+            with jax.named_scope("trunk/select"):
+                selection = mask.astype(jnp.int8)
+        if selection is not None:
+            seen["selection_uses"] = jnp.ones((), jnp.int32)
+            with jax.named_scope("trunk/select"):
+                seen["witness"] = witness_of(selection, self.witness_stride())
 
         def attend(args):
             with jax.named_scope("trunk/attend"):
-                return latent_attention(*args, granule=self.chunk_size, interpret=interpret)
+                return latent_attention(*args[0], granule=self.chunk_size, interpret=interpret,
+                                        selection=args[1])
 
         # one request at a time, as the other kind: requests share no keys
-        out = jax.lax.map(attend, args)  # (B, H, T, dv) bfloat16
+        out = jax.lax.map(attend, (args, selection))  # (B, H, T, dv) bfloat16
         with jax.named_scope("trunk/project"):
-            return jnp.einsum("bhtv,hvd->btd", out, w["wo"].reshape(H, dv, -1), preferred_element_type=F32)
+            out = jnp.einsum("bhtv,hvd->btd", out, w["wo"].reshape(H, dv, -1), preferred_element_type=F32)
+        return out, selection, seen
 
-    def layer(self, w, x, n_valid, interpret: bool = False):
+    def layer(self, w, x, n_valid, selection=None, interpret: bool = False):
         """One decoder layer over the B x T rows of ``x`` (B, T, D), T a
         multiple of ``chunk_size``; ``n_valid`` (B,): rows beyond it are
-        padding. A layer with a ``router`` among its leaves is routed, any
-        other dense: a caller that jits this compiles it once for each
-        kind, whatever the depth.
+        padding; ``selection``: what the layer below handed on, (B, T, T)
+        int8 or ``None``. A layer with a ``router`` among its leaves is
+        routed, any other dense; one with an indexer (``idx_wq``) makes the
+        selection it attends under and hands on, any other attends under
+        the one it was handed and hands it on: a caller that jits this
+        compiles it once for each kind, whatever the depth.
 
-        Returns the next ``x`` and what the layer observed. A routed layer:
-        ``experts`` (B, T, top_k) uint8, each row's experts of
-        ``n_routed_experts``, and ``held_tokens`` (held,) int32, the valid
-        rows routed to each held expert. A dense layer: nothing."""
+        Returns the next ``x``, the selection, and what the layer observed.
+        A routed layer: ``experts`` (B, T, top_k) uint8, each row's experts
+        of ``n_routed_experts``, and ``held_tokens`` (held,) int32, the
+        valid rows routed to each held expert. A layer that made a
+        selection: ``selections`` as the other kind's; one that attended
+        under a selection, its own or one handed on: ``selection_uses`` ()
+        int32, 1, and ``witness``, the sampled queries' keys in the
+        selection its kernel read."""
         B, T, D = x.shape
         eps = self.rms_norm_eps
-        x = x + self._attention(w, x, interpret)
+        attended, selection, seen = self._attention(w, x, n_valid, selection, interpret)
+        x = x + attended
         if "router" not in w:
             with jax.named_scope("trunk/dense_mlp"):
                 h = _rmsnorm(x, w["mlp_norm"], eps)
-                return x + _swiglu(h, w["gate"], w["up"], w["down"]), {}
+                return x + _swiglu(h, w["gate"], w["up"], w["down"]), selection, seen
         with jax.named_scope("trunk/route"):
             h = _rmsnorm(x, w["mlp_norm"], eps).reshape(B * T, D)
         with jax.named_scope("trunk/shared_expert"):
@@ -511,8 +659,8 @@ class LatentMoEDecoder(_Trunk):
         else:
             y, experts, tokens = jax.lax.map(run, (h.reshape(-1, rows, D), valid.reshape(-1, rows)))
             tokens = jnp.sum(tokens, axis=0)
-        return x + (y.reshape(B * T, D) + shared).reshape(B, T, D), {
-            "experts": experts.reshape(B, T, -1).astype(jnp.uint8), "held_tokens": tokens,
+        return x + (y.reshape(B * T, D) + shared).reshape(B, T, D), selection, {
+            **seen, "experts": experts.reshape(B, T, -1).astype(jnp.uint8), "held_tokens": tokens,
         }
 
 
@@ -535,4 +683,6 @@ def latent_moe_decoder(n_features: int, compute_dtype: str = "float32", **sizes)
     shared expert; ``sizes`` are the published config's keys
     (``LatentMoEDecoder``) and the held range of experts."""
     _only_float32(compute_dtype)
+    if sizes.get("indexer_types") is not None:  # a JSON list
+        sizes["indexer_types"] = tuple(sizes["indexer_types"])
     return LatentMoEDecoder(n_features=int(n_features), **sizes)

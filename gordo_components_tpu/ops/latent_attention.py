@@ -1,13 +1,17 @@
-"""Multi-head latent attention (MLA), causal, for one request.
+"""Multi-head latent attention (MLA), causal, for one request, over every
+causal key or over a selection of them.
 
 A row's keys and values are one ``kv_lora_rank``-wide latent and one
 RoPE key that every head shares; each head's keys and values are
 expansions of the latent (``W_kvb``), and a head's score has two parts:
 
     score[t, s] = (q_nope[t] . k_nope[s] + q_rope[t] . k_rope[s]) * scale
-    out[t]      = softmax over s <= t of score[t, .]  .  v
+    out[t]      = softmax over s in S_t of score[t, .]  .  v
 
-with ``q_nope``/``k_nope`` (``qk_nope_head_dim``) and ``v``
+``S_t`` being every ``s <= t``, or, where the caller hands a *selection*
+(a (T, T) int8 mask that an indexer made: ``ops/sparse_attention.py``'s
+``select_keys``; one for all heads, causal already), the keys it keeps.
+With ``q_nope``/``k_nope`` (``qk_nope_head_dim``) and ``v``
 (``v_head_dim``) per head, ``q_rope`` per head and ``k_rope``
 (``qk_rope_head_dim``) ONE vector a row. The score width (nope + rope) and
 the value width differ.
@@ -21,8 +25,14 @@ chip at a week-long request it took 51.0 ms a layer against 24.2 at the
 same 512-row tiles, and was deleted: PERF.md, PR 33.) The Pallas
 kernel keeps the softmax online over (tile x tile) score tiles, so no
 (heads, rows, rows) logits go to HBM; a tile above the diagonal is neither
-fetched nor computed, and only the diagonal tiles build a mask. The same
-kernel runs in interpret mode off the chip.
+fetched nor computed, and only the diagonal tiles build a mask. Under a
+selection every tile at or below the diagonal reads its (tile x tile) part
+of the mask beside its keys and masks its logits by it; without one the
+program is what it was before selections existed, tile for tile. No tile
+is skipped for holding no selected key: under random weights an indexer's
+top 2048 of 10 080 fall in every causal tile, so nothing would exercise
+the skip (PERF.md section 7). The same kernel runs in interpret mode off
+the chip.
 
 Matmuls take bfloat16 operands and accumulate in float32; logits and
 softmax are float32; the caller multiplies the queries by the softmax
@@ -42,13 +52,21 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_MASKED = -1e30  # a finite "minus infinity"; every row of a diagonal tile keeps a key
+_MASKED = -1e30  # a finite "minus infinity"; every row keeps a key at or before its diagonal tile
 # (rows of a score tile, heads a grid step) taken where the tile divides the
 # request; any other request takes its granule by 4 heads. A step's heads
-# share one fetch of the k_rope tile. On a v5e at 10 240 rows
-# (tools/latent_trunk_ladder.py; PERF.md, PR 33): 1024 x 2 20.1 ms, 512 x 4
-# 24.2, 512 x 2 30.4, 256 x 8 45.9; 512 x 8 does not fit VMEM.
+# share one fetch of the k_rope tile (and of the selection's). One value at
+# both widths measured, on a v5e at 10 240 rows (tools/latent_trunk_ladder.py).
+# 128 + 64 | 128 (PERF.md, PR 33): 1024 x 2 20.1 ms, 512 x 4 24.2, 512 x 2
+# 30.4, 256 x 8 45.9; 512 x 8 does not fit VMEM. 192 + 64 | 256 under a
+# selection, and without one (PERF.md, PR 35): 1024 x 2 34.3 ms (31.4), 1024
+# x 1 34.4 (32.2), 512 x 4 35.0 (34.3), 512 x 2 35.3 (34.3); under a 64 MB
+# limit 512 x 8 35.0 (34.5), 1024 x 4 68.5 (63.9), 2048 x 1 71.1 (66.2);
+# under 96 MB 2048 x 2 55.6 (52.6).
 _TILES = ((1024, 2),)
+# 1024 x 2 asks for 16.4 MB inside the layer program at 128 + 64 | 128, over
+# the 16 MB a kernel gets unasked; a v5e core has 128
+_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
 def yarn(rotary_dim: int, theta: float, scaling: Optional[dict]) -> Tuple[np.ndarray, float]:
@@ -87,12 +105,18 @@ def yarn(rotary_dim: int, theta: float, scaling: Optional[dict]) -> Tuple[np.nda
     return inv_freq.astype(np.float32), m(all_dim) ** 2
 
 
-def _kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
+def _kernel(*refs, selected: bool):
     """Grid (block of heads, query tile i, key tile j), j innermost.
     ``qn_ref``/``kn_ref`` (heads, tile, nope), ``qr_ref`` (heads, tile,
     rope), ``kr_ref`` (tile, rope) shared by the heads, ``v_ref``/``o_ref``
-    (heads, tile, dv). Online softmax in the scratch: running max ``m``,
-    sum ``l`` (heads, tile, 1) and unnormalised output ``acc``."""
+    (heads, tile, dv); ``selected``: then ``sel_ref`` (tile, tile) int8, the
+    selection's part for this tile, shared by the heads too. Online softmax
+    in the scratch: running max ``m``, sum ``l`` (heads, tile, 1) and
+    unnormalised output ``acc``."""
+    if selected:
+        qn_ref, qr_ref, kn_ref, kr_ref, v_ref, sel_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     i, j = pl.program_id(1), pl.program_id(2)
     tile = qn_ref.shape[1]
 
@@ -105,18 +129,23 @@ def _kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, m_ref, l_ref, acc_ref)
     def _tile(diagonal: bool):
         contract_last = (((1,), (1,)), ((), ()))
         kr = kr_ref[...]
-        if diagonal:
+        if selected:  # causal already: the diagonal's rule is in it
+            keep = sel_ref[...] != 0
+        elif diagonal:
             keep = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
                     >= jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1))
         for r in range(qn_ref.shape[0]):
             logits = jax.lax.dot_general(
                 qn_ref[r], kn_ref[r], contract_last, preferred_element_type=jnp.float32
             ) + jax.lax.dot_general(qr_ref[r], kr, contract_last, preferred_element_type=jnp.float32)
-            if diagonal:
+            if selected or diagonal:
                 logits = jnp.where(keep, logits, _MASKED)
             m_prev = m_ref[r]
             m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-            p = jnp.exp(logits - m_new)  # a masked logit: exp(-1e30 - m) is 0
+            # a masked logit: exp(-1e30 - m) is 0. A row that has met no selected
+            # key yet sums ones at m = -1e30; the first key it keeps (there is one
+            # at or before its diagonal tile) multiplies that by alpha = 0
+            p = jnp.exp(logits - m_new)
             alpha = jnp.exp(m_prev - m_new)
             l_ref[r] = alpha * l_ref[r] + jnp.sum(p, axis=-1, keepdims=True)
             acc_ref[r] = alpha * acc_ref[r] + jnp.dot(
@@ -139,29 +168,37 @@ def tiling(rows: int, granule: int) -> Tuple[int, int]:
     return next((t for t in _TILES if rows % t[0] == 0 and t[0] % granule == 0), (granule, 4))
 
 
-def latent_attention(q_nope, q_rope, k_nope, k_rope, v, granule: int, interpret: bool = False):
-    """Causal MLA over one request's T rows, T a multiple of ``granule``;
-    heads first, bfloat16, the queries already multiplied by the softmax
-    scale: ``q_nope``/``k_nope`` (H, T, nope), ``q_rope`` (H, T, rope) and
-    ``k_rope`` (T, rope) after RoPE, ``v`` (H, T, dv). Returns (H, T, dv)
-    bfloat16: what the output projection's matmul reads."""
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, granule: int, interpret: bool = False,
+                     selection=None):
+    """MLA over one request's T rows, T a multiple of ``granule``; heads
+    first, bfloat16, the queries already multiplied by the softmax scale:
+    ``q_nope``/``k_nope`` (H, T, nope), ``q_rope`` (H, T, rope) and
+    ``k_rope`` (T, rope) after RoPE, ``v`` (H, T, dv). ``selection``
+    (T, T) int8: the keys each query attends to, one mask for all heads,
+    causal, at least one key a row; ``None``: every causal key. Returns
+    (H, T, dv) bfloat16: what the output projection's matmul reads."""
     H, T, nope = q_nope.shape
     rope_dim, dv = q_rope.shape[-1], v.shape[-1]
     tile, heads = tiling(T, granule)
     n = T // tile
     hb = next(b for b in range(min(H, heads), 0, -1) if H % b == 0)
     seen = lambda i, j: jnp.minimum(i, j)  # a tile above the diagonal is not fetched again
+    in_specs = [
+        pl.BlockSpec((hb, tile, nope), lambda h, i, j: (h, i, 0)),
+        pl.BlockSpec((hb, tile, rope_dim), lambda h, i, j: (h, i, 0)),
+        pl.BlockSpec((hb, tile, nope), lambda h, i, j: (h, seen(i, j), 0)),
+        pl.BlockSpec((tile, rope_dim), lambda h, i, j: (seen(i, j), 0)),
+        pl.BlockSpec((hb, tile, dv), lambda h, i, j: (h, seen(i, j), 0)),
+    ]
+    operands = (q_nope, q_rope, k_nope, k_rope, v)
+    if selection is not None:
+        in_specs.append(pl.BlockSpec((tile, tile), lambda h, i, j: (i, seen(i, j))))
+        operands += (selection,)
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, selected=selection is not None),
         out_shape=jax.ShapeDtypeStruct((H, T, dv), jnp.bfloat16),
         grid=(H // hb, n, n),
-        in_specs=[
-            pl.BlockSpec((hb, tile, nope), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((hb, tile, rope_dim), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((hb, tile, nope), lambda h, i, j: (h, seen(i, j), 0)),
-            pl.BlockSpec((tile, rope_dim), lambda h, i, j: (seen(i, j), 0)),
-            pl.BlockSpec((hb, tile, dv), lambda h, i, j: (h, seen(i, j), 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((hb, tile, dv), lambda h, i, j: (h, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((hb, tile, 1), jnp.float32),
@@ -170,10 +207,8 @@ def latent_attention(q_nope, q_rope, k_nope, k_rope, v, granule: int, interpret:
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            # 1024 x 2 asks for 16.4 MB inside the layer program, over the 16 MB a
-            # kernel gets unasked; a v5e core has 128
-            vmem_limit_bytes=32 * 1024 * 1024,
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
         name="latent_attention",
-    )(q_nope, q_rope, k_nope, k_rope, v)
+    )(*operands)

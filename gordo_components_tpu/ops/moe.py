@@ -3,15 +3,18 @@
 is the weighted sum of theirs.
 
     s = softmax(h W_r)  or  sigmoid(h W_r)     over the E experts, float32
-    kept = top_k(s)                            among the experts of the
+    kept = top_k(s + b)                        among the experts of the
                                                ``topk_group`` best of
                                                ``n_group`` groups (a group's
-                                               score: its two largest s)
-    y = sum over e in kept of  scale * s_e / (sum of the k kept)  *
+                                               score: its two largest s + b)
+    y = sum over e in kept of  scale * s_e / (sum of the k kept s)  *
         W_down,e ( silu(W_gate,e h) * W_up,e h )
 
 ``n_group`` 1 is plain top-k; softmax, one group and scale 1 is the
-renormalised-probability router.
+renormalised-probability router. ``b`` is a per-expert correction bias
+(the ``noaux_tc`` router's ``e_score_correction_bias``; a layer's
+``router_bias`` leaf): it enters the CHOICE alone, the weights come from
+the unbiased scores. No bias is ``b = 0``.
 
 The layer may hold a *range* of the experts (``expert_offset`` and as many
 as ``gate`` has): one chip's share of a layer divided over chips by
@@ -36,7 +39,7 @@ logits (float32 operands at full precision), its scores, the group and
 expert top-k and the combine are float32.
 """
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,10 +51,12 @@ _TILE_ROWS, _TILE_K, _TILE_N = 512, 1024, 1024
 
 
 def route(h: jnp.ndarray, w_router: jnp.ndarray, top_k: int, scoring: str = "softmax",
-          n_group: int = 1, topk_group: int = 1,
-          scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+          n_group: int = 1, topk_group: int = 1, scale: float = 1.0,
+          bias: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(weights, experts)``, each (tokens, top_k): the kept experts'
-    scores renormalised to sum ``scale``, and their ids."""
+    scores renormalised to sum ``scale``, and their ids. ``bias`` (E,)
+    float32 is added to the scores for the choice (groups and experts)
+    alone."""
     logits = jnp.dot(
         h.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
@@ -62,14 +67,16 @@ def route(h: jnp.ndarray, w_router: jnp.ndarray, top_k: int, scoring: str = "sof
         scores = jax.nn.sigmoid(logits)
     else:
         raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
-    choice = scores
+    choice = scores if bias is None else scores + bias
     if n_group > 1:
-        grouped = scores.reshape(scores.shape[0], n_group, -1)
+        grouped = choice.reshape(scores.shape[0], n_group, -1)
         group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
         kept_groups = jax.lax.top_k(group_score, topk_group)[1]  # (tokens, topk_group)
         keep = jnp.any(kept_groups[:, :, None] == jnp.arange(n_group), axis=1)
-        choice = jnp.where(keep[:, :, None], grouped, -1.0).reshape(scores.shape)
+        choice = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(scores.shape)
     p, experts = jax.lax.top_k(choice, top_k)
+    if bias is not None:
+        p = jnp.take_along_axis(scores, experts, axis=-1)
     weights = p / jnp.sum(p, axis=-1, keepdims=True)
     if scale != 1.0:
         weights = weights * scale
@@ -91,9 +98,10 @@ def expert_layer(
     h: jnp.ndarray, params: Dict[str, jnp.ndarray], top_k: int, valid: jnp.ndarray,
     interpret: bool = False, expert_offset: int = 0, **routing,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """``h`` (tokens, D) float32; ``params``: ``router`` (D, E), ``gate``
-    and ``up`` (held, D, I), ``down`` (held, I, D): experts
-    ``expert_offset .. expert_offset + held`` of the E; ``valid`` (tokens,)
+    """``h`` (tokens, D) float32; ``params``: ``router`` (D, E), where the
+    router has one its ``router_bias`` (E,), ``gate`` and ``up``
+    (held, D, I), ``down`` (held, I, D): experts ``expert_offset ..
+    expert_offset + held`` of the E; ``valid`` (tokens,)
     bool: padding is routed like any token (its rows are dropped later) but
     left out of the counts; ``routing``: ``route``'s.
 
@@ -106,7 +114,8 @@ def expert_layer(
     held = params["gate"].shape[0]
     whole = held == n_experts  # every pair is on a held expert
     with jax.named_scope("trunk/route"):
-        weights, experts = route(h, params["router"], top_k, **routing)
+        weights, experts = route(
+            h, params["router"], top_k, bias=params.get("router_bias"), **routing)
         flat = experts.reshape(-1)
         if not whole:  # a pair on an absent expert sorts last, into a group no matmul visits
             local = flat - expert_offset

@@ -13,7 +13,13 @@ go in chunks of ``chunk`` rows (the tiling the published config names),
 each against the keys up to its own end, so a causal half of the score
 matrix is never computed.
 
-- Indexer scores and the selection are plain XLA. The k-th largest score
+- Indexer scores and the selection are plain XLA, and a function of their
+  own (``select_keys``: scores by chunk, the exact k-th largest, the mask,
+  the count and the witness) that ``select_and_attend`` calls before its
+  grouped-query kernel and the latent-attention trunk calls before ITS
+  kernel (``ops/latent_attention.py``), in the layers that make a selection;
+  the mask it returns is what later layers that share it attend under.
+  The k-th largest score
   of a row is found exactly, by a 32-step bisection on the scores' bit
   patterns (a sort of 10 240 scores a query is the slow way to learn one
   threshold); keys that tie with the k-th are all kept.
@@ -38,7 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # every ``WITNESS_STRIDE``-th query's selection is returned as a bit mask
-# (``select_and_attend``): what a caller compares two runs' selections by
+# (``select_keys``): what a caller compares two runs' selections by
 WITNESS_STRIDE = 64
 
 
@@ -164,24 +170,29 @@ def masked_attention(q, k, v, mask, chunk: int, interpret: bool = False):
     )(q, k, v, mask)
 
 
-def select_and_attend(
-    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-    qi: jnp.ndarray, ki: jnp.ndarray, wi: jnp.ndarray,
-    n_valid: jnp.ndarray, topk: int, chunk: int, interpret: bool = False,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One request. ``q`` (T, H, d); ``k``, ``v`` (T, G, d) with H a
-    multiple of G; indexer ``qi`` (T, J, dI), ``ki`` (T, dI), ``wi``
-    (T, J); ``n_valid``: rows beyond it are padding, masked as keys.
-    T is a multiple of ``chunk``.
+def witness_of(selection: jnp.ndarray, stride: int) -> jnp.ndarray:
+    """Every ``stride``-th query's keys of a selection (..., T, T) in the
+    int8 form a kernel reads, as ``select_keys`` packs its own witness:
+    (..., T // stride, T // 8) uint8."""
+    return jnp.packbits(selection[..., stride - 1 :: stride, :] != 0, axis=-1, bitorder="little")
 
-    Returns the attention output (T, H, d) float32, the number of
-    (query, key) selections made for valid queries, and the witness: the
-    selection of every ``WITNESS_STRIDE``-th query as packed bits,
-    (T // stride, T // 8) uint8.
-    """
-    T, H, d = q.shape
-    G = k.shape[1]
-    dI = qi.shape[-1]
+
+def select_keys(
+    qi: jnp.ndarray, ki: jnp.ndarray, wi: jnp.ndarray, n_valid: jnp.ndarray,
+    topk: int, chunk: int, divisor: Optional[float] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One request's selection, with no attention after it: indexer ``qi``
+    (T, J, dI), ``ki`` (T, dI), ``wi`` (T, J); ``n_valid``: rows beyond it
+    are padding, masked as keys; T a multiple of ``chunk``. The scores are
+    ``sum_j wi relu(qi . ki) / divisor`` (default ``sqrt(dI)``).
+
+    Returns the mask (T, T) bool, causal, at least one key kept a row; the
+    number of (query, key) selections made for valid queries; and the
+    witness: the selection of every ``WITNESS_STRIDE``-th query as packed
+    bits, (T // stride, T // 8) uint8. What both kinds of trunk attend
+    under (``select_and_attend`` here, ``ops/latent_attention.py``)."""
+    T, _, dI = qi.shape
+    divisor = dI ** 0.5 if divisor is None else divisor
     stride = min(WITNESS_STRIDE, chunk)
     qib, kib = _bf16(qi), _bf16(ki)
     masks = []
@@ -198,7 +209,7 @@ def select_and_attend(
                 dots = jnp.einsum(
                     "tjd,sd->tjs", qib[start:S], kib[:S], preferred_element_type=jnp.float32
                 )
-                index = jnp.einsum("tjs,tj->ts", jax.nn.relu(dots), wi[start:S]) / (dI ** 0.5)
+                index = jnp.einsum("tjs,tj->ts", jax.nn.relu(dots), wi[start:S]) / divisor
                 index = jnp.where(keep, index, -jnp.inf)
             with jax.named_scope("trunk/select"):
                 keep &= index >= kth_largest(index, topk)[:, None]
@@ -208,6 +219,24 @@ def select_and_attend(
     with jax.named_scope("trunk/select"):
         mask = jnp.concatenate(masks)  # (T, T) bool
         witness = jnp.packbits(mask[stride - 1 :: stride], axis=-1, bitorder="little")
+    return mask, selections, witness
+
+
+def select_and_attend(
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+    qi: jnp.ndarray, ki: jnp.ndarray, wi: jnp.ndarray,
+    n_valid: jnp.ndarray, topk: int, chunk: int, interpret: bool = False,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One request. ``q`` (T, H, d); ``k``, ``v`` (T, G, d) with H a
+    multiple of G; the indexer's ``qi``, ``ki``, ``wi`` and ``n_valid`` as
+    ``select_keys`` takes them. T is a multiple of ``chunk``.
+
+    Returns the attention output (T, H, d) float32 and ``select_keys``'
+    count and witness.
+    """
+    T, H, d = q.shape
+    G = k.shape[1]
+    mask, selections, witness = select_keys(qi, ki, wi, n_valid, topk, chunk)
     with jax.named_scope("trunk/attend"):
         out = masked_attention(
             _bf16(q).reshape(T, G, H // G, d).transpose(1, 2, 0, 3),

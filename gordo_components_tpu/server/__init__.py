@@ -914,6 +914,9 @@ def build_app(
 
             with contextlib.suppress(Exception):
                 await fut
+        bank = app.get("bank")
+        if bank is not None:
+            bank.release()  # the app may outlive its clean-up; its device memory must not
 
     app.on_cleanup.append(_stop_engine)
     app.add_routes(routes)

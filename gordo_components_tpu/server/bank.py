@@ -623,7 +623,9 @@ class _Bucket:
         # ``score_layer`` once per layer of the shared trunk (compiled once
         # for each kind of layer the trunk has, whatever the depth: layers
         # of one kind have the same leaves and shapes, and each call is
-        # handed its own layer's leaves in place), and ``score`` below, which then
+        # handed its own layer's leaves in place; what one call hands the
+        # next is the residual stream and the selection of keys in force,
+        # ``None`` where the trunk shares none), and ``score`` below, which then
         # starts from the trunk's last state. All three names begin with
         # ``score``: the device trace's readers sum the bucket's programs
         # by that prefix.
@@ -636,8 +638,8 @@ class _Bucket:
             xs = (X - in_shift[:, None, :]) * in_scale[:, None, :]
             return module.embed(p["params"]["in_proj"], xs)
 
-        def score_layer(w, x, n_valid):
-            return module.layer(w, x, n_valid, interpret=kernel_mode != "pallas")
+        def score_layer(w, x, n_valid, selection=None):
+            return module.layer(w, x, n_valid, selection, interpret=kernel_mode != "pallas")
 
         # the jitted function is named ``score`` on one device and on the
         # mesh: the XLA module is then ``jit_score``, the name the device
@@ -727,10 +729,11 @@ class _Bucket:
                 jnp.asarray(Y),
             )
         idx, X, n_valid = jnp.asarray(indices), jnp.asarray(X), jnp.asarray(n_valid, jnp.int32)
-        x = self._enter(self.params, *self.scalers[:2], idx, X)
+        x, selection = self._enter(self.params, *self.scalers[:2], idx, X), None
         observed = []
         for w in self.shared["layers"]:
-            x, seen = self._layer(w, x, n_valid)
+            # the selection stays on the device, in the form the kernel reads
+            x, selection, seen = self._layer(w, x, n_valid, selection)
             observed.append(seen)
         return self._score(
             self.params, *self.scalers, idx, X, jnp.asarray(Y), x, self.shared["final_norm"]
@@ -779,8 +782,8 @@ class ScoreResult:
     device_s: float = 0.0
     # a bucket with shared leaves: which experts each row was routed to,
     # (routed layers, rows, top_k) uint8, and, where the kind selects keys,
-    # which keys every 64th row attended to, as packed bits (layers,
-    # sampled rows, padded rows // 8) uint8 (ops/sparse_attention.py).
+    # which keys every 64th row attended to, as packed bits (layers that
+    # select, sampled rows, padded rows // 8) uint8 (ops/sparse_attention.py).
     # They ride the tensor response as further frames: what a client
     # compares two servers' selections by.
     selections: Optional[Dict[str, np.ndarray]] = None
@@ -826,7 +829,9 @@ _SHARED_COUNTERS = {
     "tokens": "Rows they computed, padding included",
     "expert_tokens": "Valid (row, expert) pairs routed, all layers",
     "expert_tokens_busiest": "Pairs routed to each layer's busiest expert",
-    "key_selections": "(query, key) pairs the indexer selected, all layers",
+    "key_selections": "(query, key) pairs the indexer selected, all layers that select",
+    "selection_layers": "Layers that made a selection of keys",
+    "selection_uses": "Layers that attended under a selection, their own or one handed on",
     "routed_pairs": "Valid (row, expert) pairs routed by layers that hold a range of their experts",
     "held_pairs": "Those of them that fell on an expert held here",
     "held_tokens_busiest": "Pairs routed to each such layer's busiest held expert",
@@ -1356,6 +1361,17 @@ class ModelBank:
                 + (" ..." if len(bank.fallback) > 10 else ""),
             )
         return bank
+
+    def release(self) -> None:
+        """Drop every bucket, and with them the stacked weights, the
+        scalers and any shared trunk on the device: the bank scores nothing
+        after this. For a server that is being cleaned up: its ``app``
+        outlives ``cleanup()`` in caches the bank cannot reach (aiohttp
+        keeps the last 1024 applications in its middleware cache), and the
+        device memory must not stay with it (a week-long trunk and its bank
+        are 9.3 GB: PERF.md section 6, PR 35)."""
+        self._buckets.clear()
+        self._index.clear()
 
     def coverage(self) -> Dict[str, Any]:
         """Operator-facing bank coverage summary."""
@@ -2100,15 +2116,17 @@ class ModelBank:
         """Counters from the arrays a shared-leaf bucket's program returned
         with this dispatch (executor thread, one dispatch at a time). Each
         array counts valid rows only and is stacked over the layers that
-        observed it: a dense layer routes nothing, a kind without an
-        indexer selects no keys."""
+        observed it: a dense layer routes nothing, a layer without an
+        indexer selects no keys (it may attend under another's)."""
         counted = [("dispatches", 1), ("rows", run.routed_rows), ("tokens", run.total_rows)]
         if "expert_tokens" in observed:  # (layers, experts): every expert is held
             tokens = observed["expert_tokens"]
             counted += [("expert_tokens", int(tokens.sum())),
                         ("expert_tokens_busiest", int(tokens.max(axis=-1).sum()))]
-        if "selections" in observed:
-            counted.append(("key_selections", int(observed["selections"].sum())))
+        if "selections" in observed:  # (layers that select, batch)
+            counted += [("key_selections", int(observed["selections"].sum())),
+                        ("selection_layers", observed["selections"].shape[0]),
+                        ("selection_uses", int(observed["selection_uses"].sum()))]
         if "held_tokens" in observed:  # (routed layers, held experts): one chip's share
             held = observed["held_tokens"]
             layers, top_k = observed["experts"].shape[0], observed["experts"].shape[-1]

@@ -482,11 +482,11 @@ def test_no_row_on_held_experts_leaves_the_shared_experts_output_alone():
     w = dict(trunk["layers"][1])
     w["router"] = jnp.zeros_like(w["router"]).at[:, 0].set(1.0).at[:, 1].set(0.9)
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(13), (1, 32, 64))) + 0.1
-    got, seen = MODULE.layer(w, x, jnp.asarray([32]), interpret=True)
+    got, _, seen = MODULE.layer(w, x, jnp.asarray([32]), interpret=True)
     assert int(seen["held_tokens"].sum()) == 0
     from gordo_components_tpu.models.factories.trunk import _rmsnorm, _swiglu
 
-    x2 = x + MODULE._attention(w, x, True)
+    x2 = x + MODULE._attention(w, x, jnp.asarray([32]), None, True)[0]
     h2 = _rmsnorm(x2, w["mlp_norm"], 1e-6)
     np.testing.assert_allclose(
         got, x2 + _swiglu(h2, w["shared_gate"], w["shared_up"], w["shared_down"]), rtol=1e-6, atol=1e-6)
@@ -512,10 +512,10 @@ def test_the_runs_of_the_routed_experts_change_nothing(monkeypatch):
     x = jax.random.normal(jax.random.PRNGKey(17), (2, 48, 64))
     n_valid = jnp.asarray([48, 30])
     assert MODULE._rows_a_run(96) == 48
-    a, seen_a = MODULE.layer(trunk["layers"][2], x, n_valid, interpret=True)
+    a, _, seen_a = MODULE.layer(trunk["layers"][2], x, n_valid, interpret=True)
     monkeypatch.setattr(trunk_mod, "_CHUNKS_A_RUN", 6)
     assert MODULE._rows_a_run(96) == 96
-    b, seen_b = MODULE.layer(trunk["layers"][2], x, n_valid, interpret=True)
+    b, _, seen_b = MODULE.layer(trunk["layers"][2], x, n_valid, interpret=True)
     np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
     np.testing.assert_array_equal(seen_a["experts"], seen_b["experts"])
     np.testing.assert_array_equal(seen_a["held_tokens"], seen_b["held_tokens"])

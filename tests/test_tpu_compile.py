@@ -371,7 +371,7 @@ def test_trunk_layer_program_compiles_at_published_widths(v5e, B):
     x = jax.ShapeDtypeStruct((B, T, module.hidden_size), jnp.float32, sharding=home)
     n_valid = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=home)
     compiled = jax.jit(
-        lambda w, x, n: module.layer(w, x, n, interpret=False)
+        lambda w, x, n: module.layer(w, x, n, None, interpret=False)
     ).lower(layer, x, n_valid).compile()
     assert compiled.as_text().count("tpu_custom_call") == 4
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -414,7 +414,7 @@ def test_latent_trunk_layer_programs_compile_at_published_widths(
     x = jax.ShapeDtypeStruct((B, T, module.hidden_size), jnp.float32, sharding=home)
     n_valid = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=home)
     compiled = jax.jit(
-        lambda w, x, n: module.layer(w, x, n, interpret=False)
+        lambda w, x, n: module.layer(w, x, n, None, interpret=False)
     ).lower(layer, x, n_valid).compile()
     assert compiled.as_text().count("tpu_custom_call") == kernels
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -427,6 +427,64 @@ def test_latent_trunk_layer_programs_compile_at_published_widths(
     # requests a call by this count, not four
     free = 16.9e9 - 7.75e9 - 2.5e9
     assert module.program_bytes(2, T) < free < module.program_bytes(4, T)
+    weights = sum(np.prod(s.shape) * s.dtype.itemsize for s in layer.values())
+    assert gigabytes[0] * 1e9 < weights < gigabytes[1] * 1e9
+
+
+@pytest.mark.parametrize("kind,layer_index,handed,kernels,gigabytes", [
+    ("dense+full", 0, False, 1, (0.80, 0.81)), ("routed+shared", 1, True, 4, (1.61, 1.63)),
+    ("routed+full", 4, True, 4, (1.63, 1.65)),
+])
+def test_selected_latent_trunk_layer_programs_compile_at_published_widths(
+        v5e, kind, layer_index, handed, kernels, gigabytes):
+    """The three kinds of layer of the latent-attention trunk under a
+    shared selection (``glm52_trunk300``: 64 heads of 192 + 64 | 256 over
+    ranks 2048 and 512; an indexer of 32 x 128 that keeps 2048 keys in the
+    ``full`` layers; a dense SwiGLU of 12 288; 16 of 256 experts of 2048
+    beside a shared one, top 8 under a correction bias) over one week-long
+    request of 10 240 padded rows, for one chip: the latent attention
+    under the (rows, rows) int8 selection, and in a routed layer the three
+    grouped matmuls, are Pallas kernels; every kind hands the selection on
+    (105 MB beside the residual stream); and what each program needs
+    beside its arguments stays under the count the bank bounds its batch
+    by, which reads 2.3-2.8 times the compiler's analysis here (its points
+    are summed as if they coincided): one request a call beside the 7.29 GB
+    trunk and the bank, not two."""
+    import json
+
+    from gordo_components_tpu.models.factories.trunk import LatentMoEDecoder
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs", "glm52_trunk300.json")) as fh:
+        sizes = json.load(fh)["model"]["gordo_components_tpu.models.DiffBasedAnomalyDetector"][
+            "base_estimator"]["sklearn.pipeline.Pipeline"]["steps"][-1][
+            "gordo_components_tpu.models.TrunkForecast"]
+    sizes = {k: v for k, v in sizes.items() if k not in ("kind", "trunk")}
+    module = LatentMoEDecoder(n_features=300, **dict(sizes, indexer_types=tuple(sizes["indexer_types"])))
+    home = SingleDeviceSharding(v5e[0])
+    B, T = 1, module.padded_rows(10080)
+    layer = {
+        name: jax.ShapeDtypeStruct(
+            shape, jnp.float32 if len(shape) == 1 else jnp.bfloat16, sharding=home
+        )
+        for name, shape in module.layer_shapes(layer_index).items()
+    }
+    assert ("router" in layer, "idx_wq" in layer) == ("routed" in kind, "full" in kind)
+    x = jax.ShapeDtypeStruct((B, T, module.hidden_size), jnp.float32, sharding=home)
+    n_valid = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=home)
+    selection = jax.ShapeDtypeStruct((B, T, T), jnp.int8, sharding=home) if handed else None
+    compiled = jax.jit(
+        lambda w, x, n, s: module.layer(w, x, n, s, interpret=False)
+    ).lower(layer, x, n_valid, selection).compile()
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+    memory = compiled.memory_analysis()
+    temp = memory.temp_size_in_bytes
+    print(f"{kind} layer: temp {temp / 1e9:.2f} GB, out {memory.output_size_in_bytes / 1e9:.2f} GB, "
+          f"the bank's count {module.program_bytes(B, T) / 1e9:.2f} GB")
+    assert memory.output_size_in_bytes >= B * T * (4 * module.hidden_size + T)  # x and the selection
+    assert temp <= module.program_bytes(B, T) <= 2.8 * temp, (temp, module.program_bytes(B, T))
+    free = 16.9e9 - 7.29e9 - 2.2e9 - 0.8e9  # the trunk, the bank as stored, what else the server holds
+    assert module.program_bytes(1, T) < free < module.program_bytes(2, T)
     weights = sum(np.prod(s.shape) * s.dtype.itemsize for s in layer.values())
     assert gigabytes[0] * 1e9 < weights < gigabytes[1] * 1e9
 

@@ -3,15 +3,22 @@
 request (run on the chip; no test and no benchmark runs this):
 
     python3 tools/latent_trunk_ladder.py [--rows 10240] [--kernel 512x4,512x8] [--layers]
+    python3 tools/latent_trunk_ladder.py --config benchmarks/configs/glm52_trunk300.json \
+        --kernel 1024x2,1024x1,512x4,512x2,1024x4@64 --layers
 
 ``--kernel``: ``ops/latent_attention.py``'s kernel alone at the published
-head sizes (64 heads of 128 + 64 | 128) for each ``tile x heads-a-step``,
-wall time around ``block_until_ready`` over ``--repeats`` calls, beside the
-share of the chip's peak its causal operations come to. ``--layers``: the
-two layer programs (``LatentMoEDecoder.layer``, dense and routed with 12 of
-192 experts held) on random weights, the same way. It fails where JAX finds
-no TPU unless ``--interpret`` (a rehearsal of the script at a tiny size: its
-times say nothing).
+head sizes (64 heads of 128 + 64 | 128, or ``--config``'s) for each ``tile
+x heads-a-step`` (``@MB``: under that VMEM limit), wall time around
+``block_until_ready`` over ``--repeats`` calls, beside the share of the
+chip's peak its causal operations come to; under ``--config`` with
+``indexer_types`` the kernel runs under a selection of ``index_topk`` keys a
+query (random ones: the time does not depend on which) and again without
+one. ``--layers``: every kind of layer program the trunk has
+(``LatentMoEDecoder.layer``: dense and routed with 12 of 192 experts held,
+or ``--config``'s kinds, the selection handed from one to the next) on
+random weights, the same way. It fails where JAX finds no TPU unless
+``--interpret`` (a rehearsal of the script at a tiny size: its times say
+nothing).
 """
 
 import argparse
@@ -47,6 +54,7 @@ def main(argv=None) -> int:
     parser.add_argument("--repeats", type=int, default=3)
     parser.add_argument("--interpret", action="store_true")
     parser.add_argument("--out", default="chiprun_out/latent_trunk_ladder.jsonl")
+    parser.add_argument("--config", default=None, help="a benchmark configuration file: its TrunkForecast sizes")
     args = parser.parse_args(argv)
 
     import jax
@@ -62,8 +70,18 @@ def main(argv=None) -> int:
                  qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate_size=96,
                  moe_intermediate_size=32, n_routed_experts=16, num_experts_per_tok=4, n_group=4,
                  topk_group=2, experts_held=4, chunk_size=16) if args.interpret else {}
-    module = LatentMoEDecoder(n_features=300, num_hidden_layers=2, rope_scaling=YARN,
-                              **(small or dict(experts_held=12)))
+    sizes = dict(num_hidden_layers=2, rope_scaling=YARN, **(small or dict(experts_held=12)))
+    if args.config:
+        with open(args.config) as fh:
+            (_, det), = json.load(fh)["model"].items()
+        (_, pipe), = det["base_estimator"].items()
+        (_, sizes), = pipe["steps"][-1].items()
+        sizes = {k: v for k, v in sizes.items() if k not in ("kind", "trunk")}
+        if args.interpret:
+            sizes.update(small, index_n_heads=4, index_head_dim=16, index_topk=24)
+        if sizes.get("indexer_types"):
+            sizes["indexer_types"] = tuple(sizes["indexer_types"])
+    module = LatentMoEDecoder(n_features=300, **sizes)
     T, H = args.rows, module.num_attention_heads
     nope, rope_dim, dv = module.qk_nope_head_dim, module.qk_rope_head_dim, module.v_head_dim
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
@@ -77,33 +95,56 @@ def main(argv=None) -> int:
     keys = jax.random.split(jax.random.PRNGKey(0), 8)
     draw = lambda k, shape: jax.random.normal(k, shape, jnp.float32).astype(jnp.bfloat16)
     flops = 2.0 * H * (nope + rope_dim + dv) * T * (T + 1) / 2
+    selections = [None]
+    if module.indexer_types:  # index_topk random keys a query, its own among them: causal, none empty
+        scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), jax.random.uniform(keys[7], (T, T)), -1.0)
+        scores = scores.at[jnp.arange(T), jnp.arange(T)].set(2.0)
+        kth = jax.lax.top_k(scores, min(module.index_topk, T))[0][:, -1:]
+        selections.insert(0, ((scores >= kth) & (scores >= 0)).astype(jnp.int8))
+        del scores, kth
     for spec in filter(None, args.kernel.split(",")):
+        spec, _, vmem = spec.partition("@")
         tile, heads = (int(v) for v in spec.split("x"))
         la._TILES = ((tile, heads),)
+        la._VMEM_LIMIT_BYTES = int(vmem or 32) * 1024 * 1024
         operands = (draw(keys[0], (H, T, nope)), draw(keys[1], (H, T, rope_dim)),
                     draw(keys[2], (H, T, nope)), draw(keys[3], (T, rope_dim)), draw(keys[4], (H, T, dv)))
-        fn = jax.jit(lambda *a: la.latent_attention(*a, granule=tile, interpret=args.interpret))
-        try:
-            seconds = timed(fn, *operands, repeats=args.repeats)
-            say({"kernel": spec, "rows": T, "ms": 1e3 * seconds, "share_of_peak": flops / seconds / PEAK})
-        except Exception as exc:  # a tile the chip's lowering or its VMEM refuses
-            say({"kernel": spec, "rows": T, "refused": f"{type(exc).__name__}: {str(exc)[:300]}"})
+        for selection in selections:
+            fn = jax.jit(lambda *a: la.latent_attention(
+                *a[:5], granule=tile, interpret=args.interpret, selection=a[5] if len(a) > 5 else None))
+            row = {"kernel": spec, "vmem_mb": int(vmem or 32), "rows": T, "selected": selection is not None}
+            try:
+                seconds = timed(fn, *operands, *(() if selection is None else (selection,)),
+                                repeats=args.repeats)
+                say({**row, "ms": 1e3 * seconds, "share_of_peak_causal_operations": flops / seconds / PEAK})
+            except Exception as exc:  # a tile the chip's lowering or its VMEM refuses
+                say({**row, "refused": f"{type(exc).__name__}: {str(exc)[:300]}"})
     if args.layers:
         x = jax.random.normal(keys[5], (1, T, module.hidden_size), jnp.float32)
         n_valid = jnp.asarray([T - 160], jnp.int32)
-        fn = jax.jit(lambda w, x, n: module.layer(w, x, n, interpret=args.interpret))
-        for index, kind in ((0, "dense"), (1, "routed")):
+        fn = jax.jit(lambda w, x, n, s: module.layer(w, x, n, s, interpret=args.interpret))
+        kinds, selection = {}, None
+        for index in range(module.num_hidden_layers):  # one of each kind, in the order the trunk has them
+            shapes = module.layer_shapes(index)
+            kind = ("routed" if "router" in shapes else "dense") + (
+                "" if not module.indexer_types else "+" + module.indexer_types[index])
+            if kind in kinds:
+                continue
+            kinds[kind] = index
             layer = {
-                name: (jnp.ones(shape, jnp.float32) if len(shape) == 1 else
+                name: ((jnp.zeros if name.endswith("_bias") else jnp.ones)(shape, jnp.float32)
+                       if len(shape) == 1 else
                        (jax.random.uniform(jax.random.fold_in(keys[6], i), shape, jnp.float32, -1, 1)
                         * (3.0 / shape[-2]) ** 0.5).astype(jnp.bfloat16))
-                for i, (name, shape) in enumerate(module.layer_shapes(index).items())
+                for i, (name, shape) in enumerate(shapes.items())
             }
-            seconds = timed(fn, layer, x, n_valid, repeats=args.repeats)
-            _, seen = fn(layer, x, n_valid)
+            seconds = timed(fn, layer, x, n_valid, selection, repeats=args.repeats)
+            _, selection, seen = fn(layer, x, n_valid, selection)
             say({"layer": kind, "rows": T, "ms": 1e3 * seconds,
-                 "held_tokens": None if not seen else [int(v) for v in seen["held_tokens"]],
+                 "held_tokens": [int(v) for v in seen["held_tokens"]] if "held_tokens" in seen else None,
+                 "selections": [int(v) for v in seen["selections"]] if "selections" in seen else None,
                  "memory_peak_gb": (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use", 0) / 1e9})
+            del layer
     return 0
 
 
