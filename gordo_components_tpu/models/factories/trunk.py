@@ -319,7 +319,7 @@ class SparseMoEDecoder(_Trunk):
             x = x + _mm(attn.reshape(B, T, H * d), w["wo"])
         with jax.named_scope("trunk/route"):
             h = _rmsnorm(x, w["mlp_norm"], eps).reshape(B * T, D)
-        y, experts, tokens = expert_layer(
+        y, experts, tokens, _ = expert_layer(
             h, w, self.num_experts_per_tok, valid.reshape(-1), interpret
         )
         return x + y.reshape(B, T, D), selection, {
@@ -470,13 +470,16 @@ class LatentMoEDecoder(_Trunk):
         and up, down's output and its gathered copy, float32); plus the
         residual stream three times over. What bounds a bank's batch. The
         TPU compiler's own analysis reads 1.17 GB for the dense layer and
-        2.07 GB for a routed one at one request of 10 240 rows at the
-        published sizes (``tests/test_tpu_compile.py``); this count reads
-        3.10 GB. It doubles with the batch where the analysis grows by a
-        fifth (2.52 GB at two requests: they attend one after another and
-        a run of the experts is as long either way), which errs to the
-        side of a smaller batch: on a v5e the benchmark's trunk and bank
-        leave 5.82e9 bytes, so one request a call and not two.
+        1.01 GB for a routed one at one request of 10 240 rows at the
+        published sizes (``tests/test_tpu_compile.py``; 2.07 GB while a
+        run's passes were sized by all its pairs: since they go in blocks
+        of held pairs, ``ops/moe.py``, the run's term here is an upper
+        bound no load reaches); this count reads 3.10 GB. It doubles with
+        the batch, and the analysis reads 2.52 GB at two requests (they
+        attend one after another and a run of the experts is as long
+        either way), so it errs to the side of a smaller batch: on a v5e
+        the benchmark's trunk and bank leave 5.82e9 bytes, so one request
+        a call and not two.
 
         Under ``indexer_types`` the attention's point also holds the
         selection: every request's (rows, rows) mask as the layer was
@@ -625,12 +628,13 @@ class LatentMoEDecoder(_Trunk):
 
         Returns the next ``x``, the selection, and what the layer observed.
         A routed layer: ``experts`` (B, T, top_k) uint8, each row's experts
-        of ``n_routed_experts``, and ``held_tokens`` (held,) int32, the
-        valid rows routed to each held expert. A layer that made a
-        selection: ``selections`` as the other kind's; one that attended
-        under a selection, its own or one handed on: ``selection_uses`` ()
-        int32, 1, and ``witness``, the sampled queries' keys in the
-        selection its kernel read."""
+        of ``n_routed_experts``, ``held_tokens`` (held,) int32, the valid
+        rows routed to each held expert, and ``held_blocks`` () int32, the
+        blocks of held pairs its runs worked through (``ops/moe.py``). A
+        layer that made a selection: ``selections`` as the other kind's;
+        one that attended under a selection, its own or one handed on:
+        ``selection_uses`` () int32, 1, and ``witness``, the sampled
+        queries' keys in the selection its kernel read."""
         B, T, D = x.shape
         eps = self.rms_norm_eps
         attended, selection, seen = self._attention(w, x, n_valid, selection, interpret)
@@ -655,12 +659,14 @@ class LatentMoEDecoder(_Trunk):
 
         rows = self._rows_a_run(B * T)
         if rows == B * T:
-            y, experts, tokens = run((h, valid))
+            y, experts, tokens, blocks = run((h, valid))
         else:
-            y, experts, tokens = jax.lax.map(run, (h.reshape(-1, rows, D), valid.reshape(-1, rows)))
-            tokens = jnp.sum(tokens, axis=0)
+            y, experts, tokens, blocks = jax.lax.map(
+                run, (h.reshape(-1, rows, D), valid.reshape(-1, rows)))
+            tokens, blocks = jnp.sum(tokens, axis=0), jnp.sum(blocks)
         return x + (y.reshape(B * T, D) + shared).reshape(B, T, D), selection, {
             **seen, "experts": experts.reshape(B, T, -1).astype(jnp.uint8), "held_tokens": tokens,
+            "held_blocks": blocks,
         }
 
 

@@ -26,13 +26,29 @@ nothing here stands in for it.
 
 No pair on a held expert is dropped, whatever the load: the (token, expert)
 pairs are sorted by expert, those of absent experts last, and the held
-experts' matmuls run as ONE grouped matmul over the ragged groups
+experts' matmuls run as grouped matmuls over the ragged groups
 (``jax.experimental.pallas.ops.tpu.megablox.gmm``: each row tile multiplies
 its own group's matrix, a tile that straddles two groups is visited once
 for each, row tiles past the last held pair are not visited), so an expert
 that takes every token is just a long group. One path: the kernel on a
 TPU, the same kernel in interpret mode elsewhere (``interpret``, decided
 once by the caller as the bank decides its epilogue kernel).
+
+How much of the sorted order is worked through follows what the layer
+holds, which its leaves' shapes say. A layer that holds every expert takes
+the sorted pairs in ONE pass: gather, three grouped matmuls, the gather
+back into token order, the weighted sum over ``top_k``. A layer that holds
+a range works through the held pairs alone, which the sort has put first,
+in blocks of ``_BLOCK_PAIRS`` sorted pairs, by a loop whose trips are
+``ceil(held pairs / block)``: a trip gathers its pairs' rows, runs the three
+grouped matmuls with the group sizes clipped to the block, zeroes the rows
+at or past the held count (no matmul wrote them: they may hold anything),
+weighs each row and adds it into its token's row of the output. Nothing
+but the router, the top-k, the sort of the pairs' integers and the counts
+is sized by all the pairs; no held pair at all is zero trips and an output
+of exact zeros, every pair held is ``pairs / block`` trips and about the
+one pass's work. A token's up-to-``top_k`` float32 additions happen in
+expert order there, in slot order in the one pass.
 
 Matmuls take bfloat16 operands and accumulate in float32; the router's
 logits (float32 operands at full precision), its scores, the group and
@@ -48,6 +64,8 @@ from jax.experimental.pallas.ops.tpu.megablox import gmm
 # the grouped matmul's tiles (rows, contraction, columns); rows must
 # divide the pair count, which is a multiple of the sequence chunk
 _TILE_ROWS, _TILE_K, _TILE_N = 512, 1024, 1024
+# sorted pairs a trip of a held range's loop (a multiple of ``_TILE_ROWS``)
+_BLOCK_PAIRS = 2048
 
 
 def route(h: jnp.ndarray, w_router: jnp.ndarray, top_k: int, scoring: str = "softmax",
@@ -94,10 +112,19 @@ def _grouped(lhs, rhs, group_sizes, interpret):
     )
 
 
+def _experts(x, params, sizes, interpret):
+    """The SwiGLU of each row's own expert: ``x`` (rows, D) bfloat16 grouped
+    by expert, ``sizes`` the groups' lengths; float32. Rows past the last
+    group are not written."""
+    gate = _grouped(x, params["gate"], sizes, interpret)
+    up = _grouped(x, params["up"], sizes, interpret)
+    return _grouped((jax.nn.silu(gate) * up).astype(jnp.bfloat16), params["down"], sizes, interpret)
+
+
 def expert_layer(
     h: jnp.ndarray, params: Dict[str, jnp.ndarray], top_k: int, valid: jnp.ndarray,
     interpret: bool = False, expert_offset: int = 0, **routing,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``h`` (tokens, D) float32; ``params``: ``router`` (D, E), where the
     router has one its ``router_bias`` (E,), ``gate`` and ``up``
     (held, D, I), ``down`` (held, I, D): experts ``expert_offset ..
@@ -106,8 +133,10 @@ def expert_layer(
     left out of the counts; ``routing``: ``route``'s.
 
     Returns the held experts' part of the layer's output (tokens, D)
-    float32, the experts chosen (tokens, top_k) int32 of E, and the valid
-    tokens routed to each held expert (held,).
+    float32, the experts chosen (tokens, top_k) int32 of E, the valid
+    tokens routed to each held expert (held,), and the blocks of held pairs
+    worked through () int32: the loop's trips, padding's pairs among them;
+    0 where every expert is held (one pass, no loop).
     """
     n_tokens = h.shape[0]
     n_experts = params["router"].shape[-1]
@@ -126,15 +155,54 @@ def expert_layer(
         counts = jnp.bincount(
             flat, weights=jnp.repeat(valid, top_k).astype(jnp.int32), length=groups
         ).astype(jnp.int32)[:held]
+    if not whole:
+        out, blocks = _held_pairs_in_blocks(h, params, top_k, weights, order, sizes, interpret)
+        return out, experts, counts, blocks
+    with jax.named_scope("trunk/route"):
         x = h.astype(jnp.bfloat16)[order // top_k]  # (pairs, D), grouped by expert
     with jax.named_scope("trunk/experts"):
-        gate = _grouped(x, params["gate"], sizes, interpret)
-        up = _grouped(x, params["up"], sizes, interpret)
-        y = _grouped((jax.nn.silu(gate) * up).astype(jnp.bfloat16), params["down"], sizes, interpret)
+        y = _experts(x, params, sizes, interpret)
     with jax.named_scope("trunk/combine"):
         back = jnp.argsort(order)  # pair (token, slot) -> its row in the sorted order
         y = y[back].reshape(n_tokens, top_k, -1)
-        if not whole:  # rows past the last held pair were never written
-            y = jnp.where((flat < held).reshape(n_tokens, top_k, 1), y, 0.0)
         out = jnp.sum(y * weights[..., None], axis=1)
-    return out, experts, counts
+    return out, experts, counts, jnp.zeros((), jnp.int32)
+
+
+def _held_pairs_in_blocks(h, params, top_k, weights, order, sizes, interpret):
+    """The weighted sum of the held experts' outputs over the first
+    ``sum(sizes)`` pairs of ``order`` (pairs sorted by expert, the held
+    experts' first), a block of sorted pairs a trip; and the trips."""
+    n_tokens, pairs = h.shape[0], order.shape[0]
+    block = min(_BLOCK_PAIRS, pairs)
+    with jax.named_scope("trunk/route"):
+        ends = jnp.cumsum(sizes)
+        n_held = ends[-1]
+        order = jnp.pad(order, (0, -pairs % block))  # the last block's slice stays inside
+        h = h.astype(jnp.bfloat16)
+        weights = weights.reshape(-1)
+    with jax.named_scope("trunk/combine"):
+        # the output as (tokens, D / 128, 128): a token's row is whole (8, 128) tiles, contiguous, and
+        # a trip's scatter-add of 2048 rows of 7168 takes 1.8 ms less on a v5e than into (tokens, D)
+        wide = h.shape[1]
+        lanes = 128 if wide % 128 == 0 else wide
+        zeros = jnp.zeros((n_tokens, wide // lanes, lanes), jnp.float32)
+
+    def trip(b, out):
+        first = b * block
+        with jax.named_scope("trunk/route"):
+            at = jax.lax.dynamic_slice(order, (first,), (block,))
+            tokens = at // top_k
+            x = h[tokens]  # (block, D), grouped by expert
+            here = jnp.clip(ends - first, 0, block) - jnp.clip(ends - sizes - first, 0, block)
+        with jax.named_scope("trunk/experts"):
+            y = _experts(x, params, here, interpret)
+        with jax.named_scope("trunk/combine"):
+            live = first + jnp.arange(block) < n_held  # a row past the held pairs was never written
+            y = jnp.where(live[:, None], y * weights[at][:, None], 0.0)
+            return out.at[tokens].add(y.reshape(block, *out.shape[1:]))
+
+    blocks = (n_held + block - 1) // block
+    out = jax.lax.fori_loop(0, blocks, trip, zeros)
+    with jax.named_scope("trunk/combine"):
+        return out.reshape(n_tokens, wide), blocks

@@ -835,6 +835,7 @@ _SHARED_COUNTERS = {
     "routed_pairs": "Valid (row, expert) pairs routed by layers that hold a range of their experts",
     "held_pairs": "Those of them that fell on an expert held here",
     "held_tokens_busiest": "Pairs routed to each such layer's busiest held expert",
+    "held_pair_blocks": "Blocks of held pairs such layers worked through, padding's pairs among them",
 }
 
 
@@ -2132,7 +2133,8 @@ class ModelBank:
             layers, top_k = observed["experts"].shape[0], observed["experts"].shape[-1]
             counted += [("routed_pairs", run.routed_rows * top_k * layers),
                         ("held_pairs", int(held.sum())),
-                        ("held_tokens_busiest", int(held.max(axis=-1).sum()))]
+                        ("held_tokens_busiest", int(held.max(axis=-1).sum())),
+                        ("held_pair_blocks", int(observed["held_blocks"].sum()))]
         stats = self.shared_stats
         for name, value in counted:
             stats[name] = stats.get(name, 0) + value

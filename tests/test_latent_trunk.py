@@ -175,13 +175,15 @@ async def test_served_answer_matches_the_plain_reference(tree, machine):
     held_by_reference = int(exact["experts"][:, :, 4:8].sum())
     assert abs(shared["held_pairs"] - held_by_reference) <= 0.03 * ROWS * 4 * 2
     assert 0 < shared["held_tokens_busiest"] <= shared["held_pairs"]
+    # two routed layers, two runs of 48 rows a request: a trip for each run that held a pair
+    assert 0 < shared["held_pair_blocks"] <= 4 * shared["dispatches"]
     assert "expert_tokens" not in shared and "key_selections" not in shared
 
 
 def test_the_counters_are_scraped(bank):
     from gordo_components_tpu.server.bank import _SHARED_COUNTERS
 
-    assert {"routed_pairs", "held_pairs", "held_tokens_busiest"} <= set(_SHARED_COUNTERS)
+    assert {"routed_pairs", "held_pairs", "held_tokens_busiest", "held_pair_blocks"} <= set(_SHARED_COUNTERS)
 
 
 # ------------------------------------------------------------------- bank
@@ -203,7 +205,9 @@ def test_two_machines_batched_get_the_answers_they_get_alone(bank, pair):
 @pytest.mark.parametrize("rows", [17, 50, 90])
 def test_padding_is_left_out_of_the_counters(bank, tree, rows):
     """Any length goes as one call; the padding changes nothing and is
-    counted nowhere but in ``tokens``."""
+    counted nowhere but in ``tokens`` and, as the pairs it is routed as, in
+    the blocks of held pairs (a trip for each run of 48 rows that held a
+    pair, padding's among them: 2 routed layers x 2 runs)."""
     X = machine_rows(1, rows)
     before = dict(bank.shared_stats)
     got = bank.score("m1", X)
@@ -214,6 +218,7 @@ def test_padding_is_left_out_of_the_counters(bank, tree, rows):
     assert grew("routed_pairs") == rows * 4 * 2
     _, exact = _reference(tree[1], "m1", X)
     assert abs(grew("held_pairs") - int(exact["experts"][:, :, 4:8].sum())) <= 0.03 * rows * 8 + 1
+    assert (grew("held_pairs") > 0) <= (grew("held_pair_blocks") > 0) and grew("held_pair_blocks") <= 4
     assert got.model_output.shape == (rows - 1, F)
     assert _rel(got.model_output, exact["out"][:-1]) < 0.02
 
@@ -308,10 +313,10 @@ def test_the_shares_add_up_to_the_uncut_layer():
     routing = dict(scoring="sigmoid", n_group=4, topk_group=2, scale=2.5)
     as_program = lambda w: {k: (v if v.ndim == 1 else v.astype(jnp.bfloat16)) for k, v in w.items()}
     valid = jnp.ones((ROWS,), bool)
-    full, experts, counts = moe.expert_layer(h, as_program(w_whole), 4, valid, True, **routing)
+    full, experts, counts, _ = moe.expert_layer(h, as_program(w_whole), 4, valid, True, **routing)
     total, held = jnp.zeros_like(full), 0
     for i, c in enumerate(shares):
-        out, theirs, tokens = moe.expert_layer(
+        out, theirs, tokens, _ = moe.expert_layer(
             h, as_program(LAYOUT.trunk_layer(c, seed, 1)), 4, valid, True, expert_offset=4 * i, **routing)
         np.testing.assert_array_equal(theirs, experts)
         np.testing.assert_array_equal(tokens, counts[4 * i: 4 * i + 4])
@@ -454,7 +459,7 @@ def test_every_row_on_held_experts_drops_no_pair():
     router = jnp.zeros((D, 16)).at[:, 4].set(50.0).at[:, 5].set(40.0)
     params = _share(router)
     h = jnp.abs(jax.random.normal(jax.random.PRNGKey(10), (N, D))) + 0.1
-    out, experts, counts = moe.expert_layer(
+    out, experts, counts, _ = moe.expert_layer(
         h, params, 2, jnp.ones((N,), bool), True, expert_offset=4, scoring="sigmoid", scale=2.5)
     assert np.asarray(counts).tolist() == [N, N, 0, 0]
     weights, chosen = moe.route(h, router, 2, scoring="sigmoid", scale=2.5)
@@ -473,7 +478,7 @@ def test_no_row_on_held_experts_leaves_the_shared_experts_output_alone():
     D, N = 16, 48
     router = jnp.zeros((D, 16)).at[:, 0].set(50.0).at[:, 1].set(40.0)
     h = jnp.abs(jax.random.normal(jax.random.PRNGKey(11), (N, D))) + 0.1
-    out, experts, counts = moe.expert_layer(
+    out, experts, counts, _ = moe.expert_layer(
         h, _share(router), 2, jnp.ones((N,), bool), True, expert_offset=4, scoring="sigmoid")
     assert int(counts.sum()) == 0 and sorted(np.unique(np.asarray(experts)).tolist()) == [0, 1]
     np.testing.assert_array_equal(out, jnp.zeros((N, D)))
@@ -497,8 +502,8 @@ def test_padding_is_left_out_of_the_held_counts():
     params = _share(jax.random.normal(jax.random.PRNGKey(14), (D, 16)))
     h = jax.random.normal(jax.random.PRNGKey(15), (N, D))
     routing = dict(expert_offset=4, scoring="sigmoid", n_group=4, topk_group=2, scale=2.5)
-    _, experts, every = moe.expert_layer(h, params, 4, jnp.ones((N,), bool), True, **routing)
-    _, _, counts = moe.expert_layer(h, params, 4, jnp.arange(N) < 20, True, **routing)
+    _, experts, every, _ = moe.expert_layer(h, params, 4, jnp.ones((N,), bool), True, **routing)
+    _, _, counts, _ = moe.expert_layer(h, params, 4, jnp.arange(N) < 20, True, **routing)
     local = np.asarray(experts)[:20] - 4
     assert int(counts.sum()) == int(((local >= 0) & (local < 4)).sum()) < int(every.sum())
 
@@ -519,6 +524,114 @@ def test_the_runs_of_the_routed_experts_change_nothing(monkeypatch):
     np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
     np.testing.assert_array_equal(seen_a["experts"], seen_b["experts"])
     np.testing.assert_array_equal(seen_a["held_tokens"], seen_b["held_tokens"])
+
+
+# a block of 16 sorted pairs in the cases below (two 8-row tiles of the grouped matmul)
+_BLOCK = 16
+
+
+def _planted(per_expert, N, top_k=2, D=32, E=16, first_held=4, seed=21):
+    """``N`` rows whose routing is planted: ``per_expert[i]`` rows choose
+    held expert ``first_held + i``, every other slot goes to absent experts
+    0 and 1. The rows' first E features carry the choice, an identity
+    router reads them; the rest is noise. Returns ``(h, router, chosen)``."""
+    rng = np.random.default_rng(seed)
+    picks = np.repeat(first_held + np.arange(len(per_expert)), per_expert)
+    assert max(per_expert, default=0) <= N and picks.size <= N * top_k
+    chosen = np.tile(np.arange(top_k), (N, 1))  # absent experts 0, 1: slot by slot
+    chosen[np.arange(picks.size) % N, np.arange(picks.size) // N] = picks
+    h = rng.normal(size=(N, D)).astype(np.float32)
+    h[:, :E] = -4.0
+    np.put_along_axis(h, chosen, 4.0 - 0.1 * np.arange(top_k)[None, :], axis=1)  # slot order is score order
+    router = np.zeros((D, E), np.float32)
+    router[np.arange(E), np.arange(E)] = 1.0
+    return jnp.asarray(h), jnp.asarray(router), chosen
+
+
+def _dense_sum(h, params, weights, chosen, first_held=4):
+    """The held experts' part written out slot by slot, every row through
+    its own expert's matrices: bfloat16 operands, float32 sums."""
+    bf = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
+    held = params["gate"].shape[0]
+    want = jnp.zeros(h.shape)
+    for slot in range(chosen.shape[1]):
+        local = chosen[:, slot] - first_held
+        mine = (local >= 0) & (local < held)
+        g, u, dn = (params[n].astype(jnp.float32)[np.clip(local, 0, held - 1)] for n in ("gate", "up", "down"))
+        act = jax.nn.silu(jnp.einsum("nd,ndi->ni", bf(h), g)) * jnp.einsum("nd,ndi->ni", bf(h), u)
+        want += jnp.where(mine[:, None], weights[:, slot, None] * jnp.einsum("ni,nid->nd", bf(act), dn), 0.0)
+    return want
+
+
+@pytest.mark.parametrize("per_expert,N", [
+    pytest.param([0, 0, 0, 0], 24, id="no-pair-held"),
+    pytest.param([9, 6, 0, 0], 24, id="one-under-a-block"),
+    pytest.param([9, 7, 0, 0], 24, id="a-block-exactly"),
+    pytest.param([9, 7, 1, 0], 24, id="one-over-a-block"),
+    pytest.param([10, 12, 3, 0], 24, id="a-group-straddles-two-blocks"),
+    pytest.param([0, 0, 0, 40], 40, id="one-group-over-three-blocks"),
+    pytest.param([16, 16, 16, 16], 32, id="every-pair-held"),
+])
+def test_the_held_pairs_go_through_in_blocks_whose_count_follows_them(monkeypatch, per_expert, N):
+    """Whatever the load, every pair on a held expert is computed, in
+    ``ceil(held pairs / block)`` trips: none held is no trip and exact
+    zeros, every pair held is ``pairs / block`` trips."""
+    monkeypatch.setattr(moe, "_BLOCK_PAIRS", _BLOCK)
+    h, router, chosen = _planted(per_expert, N)
+    params = _share(router, D=32)
+    routing = dict(expert_offset=4, scoring="sigmoid", scale=2.5)
+    out, experts, counts, blocks = moe.expert_layer(h, params, 2, jnp.ones((N,), bool), True, **routing)
+    np.testing.assert_array_equal(experts, chosen)
+    assert np.asarray(counts).tolist() == per_expert
+    held = sum(per_expert)
+    assert int(blocks) == -(-held // _BLOCK)
+    weights, _ = moe.route(h, router, 2, scoring="sigmoid", scale=2.5)
+    want = _dense_sum(h, params, weights, chosen)
+    if held == 0:
+        np.testing.assert_array_equal(out, jnp.zeros_like(out))
+    assert float(jnp.abs(want).max()) > 1.0 or held == 0
+    np.testing.assert_allclose(out, want, rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("per_expert", [[9, 7, 1, 0], [3, 0, 0, 2]], ids=["a-block-and-one", "five-pairs"])
+def test_rows_no_matmul_wrote_are_masked_not_multiplied(monkeypatch, per_expert):
+    """The grouped matmul leaves rows past its last group unwritten, and
+    they may hold anything: here NaN is planted there. The output is
+    finite and what it is without the NaN: the rows are selected away
+    (``where``), not multiplied by zero."""
+    real = moe.gmm
+
+    def with_nan_past_the_groups(lhs, rhs, group_sizes, **kw):
+        out = real(lhs, rhs, group_sizes, **kw)
+        return jnp.where(jnp.arange(lhs.shape[0])[:, None] < jnp.sum(group_sizes), out, jnp.nan)
+
+    monkeypatch.setattr(moe, "_BLOCK_PAIRS", _BLOCK)
+    N = 24
+    h, router, chosen = _planted(per_expert, N)
+    params = _share(router, D=32)
+    call = lambda: moe.expert_layer(
+        h, params, 2, jnp.ones((N,), bool), True, expert_offset=4, scoring="sigmoid", scale=2.5)
+    clean = call()
+    monkeypatch.setattr(moe, "gmm", with_nan_past_the_groups)
+    planted = call()
+    assert bool(jnp.all(jnp.isfinite(planted[0])))
+    for got, want in zip(planted, clean):
+        np.testing.assert_array_equal(got, want)
+
+
+def test_a_layer_observes_the_blocks_of_all_its_runs(monkeypatch):
+    """``held_blocks`` is summed over a request's runs of rows as
+    ``held_tokens`` is: each run's ``ceil(its held pairs / block)``,
+    padding's pairs among them."""
+    monkeypatch.setattr(moe, "_BLOCK_PAIRS", _BLOCK)
+    trunk = MODULE.init_trunk(jax.random.PRNGKey(18))
+    x = jax.random.normal(jax.random.PRNGKey(19), (2, 48, 64))
+    _, _, seen = MODULE.layer(trunk["layers"][1], x, jnp.asarray([48, 30]), interpret=True)
+    local = np.asarray(seen["experts"]).astype(np.int64).reshape(2, 48 * 4) - 4  # a run is a request here
+    held_a_run = ((local >= 0) & (local < 4)).sum(axis=1)
+    assert held_a_run.min() > _BLOCK  # more than a trip each
+    assert int(seen["held_blocks"]) == int(np.ceil(held_a_run / _BLOCK).sum())
+    assert int(seen["held_tokens"].sum()) < held_a_run.sum()  # padding is in the blocks, not in the counts
 
 
 # -------------------------------------------------- the benchmark's counts

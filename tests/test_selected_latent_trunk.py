@@ -296,12 +296,12 @@ def test_the_shares_add_up_to_the_uncut_layer_with_the_bias():
     routing = dict(scoring="sigmoid", n_group=1, topk_group=1, scale=2.5)
     as_program = lambda w: {k: (v if v.ndim == 1 else v.astype(jnp.bfloat16)) for k, v in w.items()}
     valid = jnp.ones((ROWS,), bool)
-    full, experts, counts = moe.expert_layer(h, as_program(w_whole), 4, valid, True, **routing)
+    full, experts, counts, _ = moe.expert_layer(h, as_program(w_whole), 4, valid, True, **routing)
     np.testing.assert_array_equal(
         np.sort(experts, -1), np.sort(np.argsort(~np.asarray(kept), -1, kind="stable")[:, :4], -1))
     total, held = jnp.zeros_like(full), 0
     for i, c in enumerate(shares):
-        out, theirs, tokens = moe.expert_layer(
+        out, theirs, tokens, _ = moe.expert_layer(
             h, as_program(LAYOUT.trunk_layer(c, seed, 1)), 4, valid, True, expert_offset=4 * i, **routing)
         np.testing.assert_array_equal(theirs, experts)
         np.testing.assert_array_equal(tokens, counts[4 * i: 4 * i + 4])
@@ -620,7 +620,7 @@ async def test_served_answer_matches_the_plain_reference(tree, machine):
     # keys that tie with the 24th are kept: under the indexer's ReLU many scores are exactly 0
     assert by_hand <= shared["key_selections"] <= 1.25 * by_hand
     assert "expert_tokens" not in shared  # that is the kind that holds every expert
-    for name in ("key_selections", "selection_layers", "selection_uses", "held_pairs"):
+    for name in ("key_selections", "selection_layers", "selection_uses", "held_pairs", "held_pair_blocks"):
         assert f"gordo_bank_shared_{name}_total {shared[name]}" in scrape.replace(".0\n", "\n")
 
 

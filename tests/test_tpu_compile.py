@@ -418,11 +418,15 @@ def test_latent_trunk_layer_programs_compile_at_published_widths(
     ).lower(layer, x, n_valid).compile()
     assert compiled.as_text().count("tpu_custom_call") == kernels
     temp = compiled.memory_analysis().temp_size_in_bytes
-    print(f"{kind} layer, {B} request(s): temp {temp / 1e9:.2f} GB, "
-          f"the bank's count {module.program_bytes(B, T) / 1e9:.2f} GB")
+    before = {("dense", 1): 1.17, ("routed", 1): 2.07, ("routed", 2): 2.52}[kind, B]
+    print(f"{kind} layer, {B} request(s): temp {temp / 1e9:.2f} GB ({before} GB before the held pairs "
+          f"went in blocks), the bank's count {module.program_bytes(B, T) / 1e9:.2f} GB")
     assert temp <= module.program_bytes(B, T), (temp, module.program_bytes(B, T))
-    if kind == "routed" and B == 1:  # the wider of the two: the count is not loose against it
-        assert module.program_bytes(B, T) <= 1.6 * temp
+    if kind == "routed" and B == 1:
+        # 2.07 GB while a run's passes were sized by all its 20 480 pairs; in blocks of
+        # held pairs the run's point is a block's, and the widest is the attention's. The
+        # count is as it was (it decides the batch: one request a call either way)
+        assert temp < 1.2e9 and module.program_bytes(B, T) <= 3.2 * temp
     # beside the 7.75 GB trunk and the 2.5 GB bank a chip of 16.9e9 bytes takes two
     # requests a call by this count, not four
     free = 16.9e9 - 7.75e9 - 2.5e9
@@ -479,7 +483,10 @@ def test_selected_latent_trunk_layer_programs_compile_at_published_widths(
     assert compiled.as_text().count("tpu_custom_call") == kernels
     memory = compiled.memory_analysis()
     temp = memory.temp_size_in_bytes
-    print(f"{kind} layer: temp {temp / 1e9:.2f} GB, out {memory.output_size_in_bytes / 1e9:.2f} GB, "
+    before = {"dense+full": 1.96, "routed+shared": 1.85, "routed+full": 2.08}[kind]
+    print(f"{kind} layer: temp {temp / 1e9:.2f} GB ({before} GB before the held pairs went in blocks: "
+          f"the attention under its selection is the widest point either way), "
+          f"out {memory.output_size_in_bytes / 1e9:.2f} GB, "
           f"the bank's count {module.program_bytes(B, T) / 1e9:.2f} GB")
     assert memory.output_size_in_bytes >= B * T * (4 * module.hidden_size + T)  # x and the selection
     assert temp <= module.program_bytes(B, T) <= 2.8 * temp, (temp, module.program_bytes(B, T))

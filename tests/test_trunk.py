@@ -372,7 +372,7 @@ def test_no_token_is_dropped_when_one_expert_takes_every_token(hog):
         "down": jax.random.normal(keys[3], (E, I, D), jnp.bfloat16),
     }
     h = jnp.abs(jax.random.normal(keys[4], (N, D))) + 0.1
-    out, experts, counts = moe.expert_layer(h, params, k, jnp.ones((N,), bool), interpret=True)
+    out, experts, counts, _ = moe.expert_layer(h, params, k, jnp.ones((N,), bool), interpret=True)
     assert int(counts[hog]) == N and int(counts.sum()) == N * k
     assert bool((experts[:, 0] == hog).all())
     weights, chosen = moe.route(h, params["router"], k)
@@ -394,7 +394,7 @@ def test_padding_is_left_out_of_the_routers_counts():
               "down": jax.random.normal(keys[3], (E, I, D), jnp.bfloat16)}
     h = jax.random.normal(keys[4], (N, D))
     valid = jnp.arange(N) < 20
-    _, _, counts = moe.expert_layer(h, params, k, valid, interpret=True)
+    _, _, counts, _ = moe.expert_layer(h, params, k, valid, interpret=True)
     assert int(counts.sum()) == 20 * k
 
 
@@ -411,7 +411,7 @@ def test_the_softmax_router_with_every_expert_held_is_bitwise_what_it_was():
               "up": jnp.asarray(rng.normal(size=(E, D, I)) * D ** -0.5, jnp.bfloat16),
               "down": jnp.asarray(rng.normal(size=(E, I, D)) * I ** -0.5, jnp.bfloat16)}
     valid = np.arange(T) < 50
-    out, experts, counts = moe.expert_layer(jnp.asarray(h), params, k, jnp.asarray(valid), True)
+    out, experts, counts, _ = moe.expert_layer(jnp.asarray(h), params, k, jnp.asarray(valid), True)
     was = np.load(os.path.join(os.path.dirname(__file__), "data", "expert_layer_softmax_case.npz"))
     np.testing.assert_array_equal(np.asarray(out), was["out"])
     np.testing.assert_array_equal(np.asarray(experts), was["experts"])

@@ -3,6 +3,7 @@
 request (run on the chip; no test and no benchmark runs this):
 
     python3 tools/latent_trunk_ladder.py [--rows 10240] [--kernel 512x4,512x8] [--layers]
+    python3 tools/latent_trunk_ladder.py --kernel "" --experts 1024,2048,2560 [--config ...]
     python3 tools/latent_trunk_ladder.py --config benchmarks/configs/glm52_trunk300.json \
         --kernel 1024x2,1024x1,512x4,512x2,1024x4@64 --layers
 
@@ -18,7 +19,15 @@ one. ``--layers``: every kind of layer program the trunk has
 or ``--config``'s kinds, the selection handed from one to the next) on
 random weights, the same way. It fails where JAX finds no TPU unless
 ``--interpret`` (a rehearsal of the script at a tiny size: its times say
-nothing).
+nothing). ``--experts``: ``ops/moe.py::expert_layer`` alone over one run of a
+request's rows (``_rows_a_run``) at the configuration's widths and held
+range, for each block of sorted pairs (``moe._BLOCK_PAIRS``) at three
+loads: ``even`` (a row's experts uniform over the router's width: 1/16 of
+the pairs held), ``skew`` (the benchmark's: 6.5% of the pairs held, two
+thirds of them on one held expert) and ``all`` (every pair on a held
+expert). The routing is planted in the rows' first E features, which an
+identity router reads. A checkout whose ``moe`` has no blocks (a parent
+commit: copy this file into its ``tools/``) is timed once a load.
 """
 
 import argparse
@@ -46,11 +55,91 @@ def timed(fn, *args, repeats: int):
     return sorted(times)[len(times) // 2]
 
 
+def planted_rows(module, rows: int, load: str, seed: int = 0):
+    """``(h, router)`` whose routing is the load's: a row's first E
+    features are 4 on its 8 experts and -4 elsewhere, the router reads
+    them. Held experts are chosen one by one with the load's probability,
+    the rest of a row's 8 among absent experts of the three groups after
+    the held range's (so a group limit keeps them)."""
+    import numpy as np
+
+    E, k, held = module.n_routed_experts, module.num_experts_per_tok, module.held
+    rng = np.random.default_rng(seed)
+    if load == "all":
+        p = None
+    elif load == "even":
+        p = np.full(held, k / E)
+    else:  # skew: 6.5% of the pairs held, two thirds of them on the first held expert
+        per_row = 0.065 * k
+        p = np.full(held, per_row / 3 / (held - 1))
+        p[0] = per_row * 2 / 3
+    size = E // module.n_group
+    absent = np.arange(max(size, held), max(size, held) + 3 * size) if module.n_group > 1 else np.arange(held, E)
+    scores = np.full((rows, E), -1.0)  # a row's k highest are its experts
+    if p is None:
+        scores[:, :held] = rng.random((rows, held))
+    else:
+        scores[:, absent] = rng.random((rows, absent.size))
+        scores[:, :held] = np.where(rng.random((rows, held)) < p, 2.0, -1.0)
+    chosen = np.argsort(-scores, axis=1)[:, :k]
+    code = np.full((rows, E), -4.0, np.float32)
+    np.put_along_axis(code, chosen, 4.0, axis=1)
+    code += rng.normal(scale=0.01, size=code.shape).astype(np.float32)  # no ties
+    h = rng.normal(size=(rows, module.hidden_size)).astype(np.float32)
+    h[:, :E] = code
+    router = np.zeros((module.hidden_size, E), np.float32)
+    router[np.arange(E), np.arange(E)] = 1.0
+    return h, router
+
+
+def experts_ladder(module, T: int, blocks, args, say) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from gordo_components_tpu.ops import moe
+
+    rows, k = module._rows_a_run(T), module.num_experts_per_tok
+    shapes = module.layer_shapes(module.num_hidden_layers - 1)
+    key = jax.random.PRNGKey(3)
+    params = {
+        name: (jax.random.uniform(jax.random.fold_in(key, i), shapes[name], jnp.float32, -1, 1)
+               * (3.0 / shapes[name][-2]) ** 0.5).astype(jnp.bfloat16)
+        for i, name in enumerate(("gate", "up", "down"))
+    }
+    if "router_bias" in shapes:
+        params["router_bias"] = jnp.zeros(shapes["router_bias"], jnp.float32)
+    routing = dict(expert_offset=module.expert_offset, scoring=module.scoring_func,
+                   n_group=module.n_group, topk_group=module.topk_group,
+                   scale=module.routed_scaling_factor)
+    valid = jnp.ones((rows,), bool)
+    calls = 1 if args.interpret else 10
+    if not hasattr(moe, "_BLOCK_PAIRS"):
+        blocks = [None]
+    for load in ("even", "skew", "all"):
+        h, router = planted_rows(module, rows, load)
+        h, w = jnp.asarray(h), {**params, "router": jnp.asarray(router)}
+        for block in blocks:
+            if block is not None:
+                moe._BLOCK_PAIRS = block
+            fn = jax.jit(lambda h, w: moe.expert_layer(h, w, k, valid, args.interpret, **routing))
+
+            def several(h, w):
+                return [fn(h, w)[0] for _ in range(calls)]
+
+            seconds = timed(several, h, w, repeats=args.repeats) / calls
+            seen = fn(h, w)
+            say({"experts": load, "block": block, "rows": rows, "pairs": rows * k,
+                 "held": module.held, "hidden": module.hidden_size, "ms": 1e3 * seconds,
+                 "held_pairs": int(seen[2].sum()), "busiest": int(seen[2].max()),
+                 "blocks": int(seen[3]) if len(seen) > 3 else None})
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--rows", type=int, default=10240)
     parser.add_argument("--kernel", default="1024x2,512x4")
     parser.add_argument("--layers", action="store_true")
+    parser.add_argument("--experts", default="", help="blocks of sorted pairs, e.g. 1024,2048,2560")
     parser.add_argument("--repeats", type=int, default=3)
     parser.add_argument("--interpret", action="store_true")
     parser.add_argument("--out", default="chiprun_out/latent_trunk_ladder.jsonl")
@@ -119,6 +208,8 @@ def main(argv=None) -> int:
                 say({**row, "ms": 1e3 * seconds, "share_of_peak_causal_operations": flops / seconds / PEAK})
             except Exception as exc:  # a tile the chip's lowering or its VMEM refuses
                 say({**row, "refused": f"{type(exc).__name__}: {str(exc)[:300]}"})
+    if args.experts:
+        experts_ladder(module, T, [int(v) for v in args.experts.split(",")], args, say)
     if args.layers:
         x = jax.random.normal(keys[5], (1, T, module.hidden_size), jnp.float32)
         n_valid = jnp.asarray([T - 160], jnp.int32)
