@@ -439,8 +439,8 @@ def attribute_trace(trace) -> Dict[str, Any]:
     "coverage"}`` where per-stage time is the union of that stage's
     intervals (a multi-chunk request records several spans per stage;
     overlaps must not double-count), ``other`` is the residual no named
-    stage covers (the middleware's own work, the response write), and
-    ``coverage``
+    stage covers (the middleware's own work; a tensor answer's ``send``
+    lies after the root and is clamped out), and ``coverage``
     is the named-stage share of the wall. The acceptance contract
     (tests/test_goodput.py): the attribution sums to within 5% of the
     request's wall time."""

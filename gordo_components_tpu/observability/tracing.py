@@ -6,9 +6,9 @@ fleet doing"; this module answers "where did THIS request's 200 ms go".
 A :class:`Tracer` produces request-scoped :class:`Trace` objects whose
 :class:`Span` records carry monotonic timestamps, so the serving hot
 path (`parse` -> `admit` -> `queue_wait` -> `handoff` -> `coalesce` ->
-`pad` -> `device_execute` -> `postprocess` -> `resolve` -> `encode`)
-and a fit (`fit:<bucket>` over the trainer's stages) become a timeline
-instead of one histogram bucket.
+`pad` -> `device_execute` -> `postprocess` -> `resolve` -> `encode`,
+then a tensor answer's `send`) and a fit (`fit:<bucket>` over the
+trainer's stages) become a timeline instead of one histogram bucket.
 
 Design rules, mirroring the metrics layer:
 
@@ -35,7 +35,8 @@ Design rules, mirroring the metrics layer:
   (``GORDO_TRACE_SLOW_KEEP``, default 16); nothing grows with traffic.
 - **Chrome trace-event export** — ``chrome_trace(traces)`` emits the
   Trace Event Format JSON (``ph: "X"`` complete events, microsecond
-  ``ts``/``dur``) that ``chrome://tracing`` and Perfetto open directly.
+  ``ts``/``dur``) that ``chrome://tracing`` and Perfetto open directly,
+  with ``ts`` on the JAX profiler's clock (:func:`profiler_ns`).
 - **One primitive for work the host does** — :class:`stage` records a
   span and a ``jax.profiler.TraceAnnotation`` (``gordo:<name>``) from the
   same two clock reads, so an open profiler session shows the program's
@@ -73,6 +74,7 @@ __all__ = [
     "get_tracer",
     "group_span",
     "parse_traceparent",
+    "profiler_ns",
     "stage",
     "union_length",
     "use_trace",
@@ -122,6 +124,30 @@ def _new_trace_id() -> str:
 
 def _new_span_id() -> str:
     return f"{_ID_RNG.getrandbits(64):016x}"
+
+
+def _monotonic_to_profiler_ns() -> int:
+    """What to add to ``time.monotonic_ns()`` to read the clock the JAX
+    profiler stamps its host events with: the system's real-time clock in
+    nanoseconds (an ``.xplane.pb`` event lies at its trace's
+    ``profile_start_time`` plus the event's offset). Read once, between
+    two monotonic reads; the kernel slews both clocks alike, so only a
+    step of the real-time clock moves the offset."""
+    before = time.monotonic_ns()
+    wall = time.time_ns()
+    after = time.monotonic_ns()
+    return wall - (before + after) // 2
+
+
+# one anchor for the whole process: every trace's spans, and the
+# profiler's regions, on one time line
+_PROFILER_OFFSET_NS = _monotonic_to_profiler_ns()
+
+
+def profiler_ns(t: float) -> float:
+    """A ``time.monotonic()`` reading (a span's ``start``/``end``) on the
+    profiler's clock, in nanoseconds."""
+    return _PROFILER_OFFSET_NS + t * 1e9
 
 
 class Span:
@@ -199,10 +225,12 @@ class Trace:
     """All spans of one request/build, rooted at a single root span.
 
     The root opens at construction and closes at :meth:`finish`, which
-    commits the trace to its tracer's ring/reservoir. Span appends are
-    plain list appends (GIL-atomic): the event loop and the scoring
-    executor thread both record into in-flight traces. Readers only see
-    a trace after ``finish`` publishes it.
+    commits the trace to its tracer's ring/reservoir, or leaves that to
+    :meth:`publish` where work after the root (a response's ``send``)
+    still has a span to record. Span appends are plain list appends
+    (GIL-atomic): the event loop and the scoring executor thread both
+    record into in-flight traces. Readers only see a trace once it is
+    published, and a published trace gains no span.
     """
 
     __slots__ = (
@@ -215,8 +243,8 @@ class Trace:
         "retained",
         "spans",
         "root",
-        "wall_start",
         "_finished",
+        "_published",
     )
 
     def __init__(
@@ -239,10 +267,10 @@ class Trace:
         # trace id (exemplars, logs) should only be published when this
         # is True, or they dangle on a head-sample drop
         self.retained = False
-        self.wall_start = time.time()
         self.root = Span(name, None, time.monotonic())
         self.spans: List[Span] = [self.root]
         self._finished = False
+        self._published = False
 
     # --------------------------- recording ---------------------------- #
 
@@ -283,9 +311,12 @@ class Trace:
         else:
             span.close()
 
-    def finish(self, error: bool = False, **attributes: Any) -> None:
-        """Close the root and publish the trace. Idempotent: retry paths
-        and shutdown sweeps may race one request's natural completion."""
+    def finish(
+        self, error: bool = False, publish: bool = True, **attributes: Any
+    ) -> None:
+        """Close the root and, unless ``publish=False``, publish the trace.
+        Idempotent: retry paths and shutdown sweeps may race one request's
+        natural completion."""
         if self._finished:
             return
         self._finished = True
@@ -297,6 +328,15 @@ class Trace:
             if span.end is None and span is not self.root:
                 span.close(error=True)
         self.root.close(error=error)
+        if publish:
+            self.publish()
+
+    def publish(self) -> None:
+        """Commit the finished trace to its tracer's ring/reservoir, once.
+        Nothing is appended to a published trace."""
+        if self._published:
+            return
+        self._published = True
         if self.tracer is not None:
             self.tracer._commit(self)
 
@@ -305,6 +345,10 @@ class Trace:
     @property
     def finished(self) -> bool:
         return self._finished
+
+    @property
+    def published(self) -> bool:
+        return self._published
 
     @property
     def duration_s(self) -> float:
@@ -363,7 +407,7 @@ class Trace:
             "trace_id": self.trace_id,
             "name": self.name,
             "request_id": self.request_id,
-            "start_unix": round(self.wall_start, 3),
+            "start_unix": round(profiler_ns(self.root.start) * 1e-9, 3),
             "duration_ms": round(self.duration_s * 1e3, 3),
             "error": self.error,
             "n_spans": len(self.spans),
@@ -393,8 +437,10 @@ def covered_seconds(spans: Iterable[Span]) -> float:
 def chrome_trace(traces: Iterable[Trace]) -> dict:
     """Chrome trace-event JSON for one or more traces: complete events
     (``ph: "X"``) with microsecond ``ts``/``dur``, one ``pid`` per trace
-    so multiple requests render side by side in Perfetto. Timestamps are
-    wall-anchored at each trace's start so concurrent traces align."""
+    so multiple requests render side by side in Perfetto. ``ts`` is on the
+    JAX profiler's clock (:func:`profiler_ns`), one anchor for every
+    trace of the process: concurrent traces align, and a span lies where
+    the same moment lies in a profiler session's ``.xplane.pb``."""
     events: List[dict] = []
     for pid, trace in enumerate(traces, start=1):
         events.append(
@@ -408,8 +454,6 @@ def chrome_trace(traces: Iterable[Trace]) -> dict:
                 },
             }
         )
-        base = trace.root.start
-        anchor_us = trace.wall_start * 1e6
         for span in trace.spans:
             args: Dict[str, Any] = {"trace_id": trace.trace_id}
             if span.attributes:
@@ -423,7 +467,7 @@ def chrome_trace(traces: Iterable[Trace]) -> dict:
                     "tid": 1,
                     "name": span.name,
                     "cat": trace.name,
-                    "ts": round(anchor_us + (span.start - base) * 1e6, 3),
+                    "ts": round(profiler_ns(span.start) * 1e-3, 3),
                     "dur": round(span.duration_s * 1e6, 3),
                     "args": args,
                 }

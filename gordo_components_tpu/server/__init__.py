@@ -36,7 +36,7 @@ from gordo_components_tpu.resilience.deadline import (
 from gordo_components_tpu.server.bank import BatchingEngine, ModelBank
 from gordo_components_tpu.server.model_io import ModelCollection
 from gordo_components_tpu.server.stats import LatencyHistogram
-from gordo_components_tpu.server.views import routes
+from gordo_components_tpu.server.views import TensorBody, routes
 
 logger = logging.getLogger(__name__)
 
@@ -95,6 +95,31 @@ def _trace_headers(headers, rid: str, trace) -> None:
         )
 
 
+def _publish_trace(trace, stats, lock, kind: str, hist, elapsed: float) -> None:
+    """Publish a request's closed trace and, where it was retained, link
+    it from the latency bucket its root landed in: an exemplar, the LAST
+    trace to land in each bucket, keyed by the bucket's le edge
+    (formatted EXACTLY as the Prometheus exposition formats it, so the
+    strings join against the scraped histogram), bounded at O(buckets)
+    per kind and surfaced through /stats so "p99 spiked" resolves to
+    "this trace" in two clicks. Only RETAINED traces publish an exemplar
+    (``retained`` is set by the commit): a head-sample drop must not
+    leave a dangling id the /traces lookup can't resolve."""
+    trace.publish()
+    if trace.retained:
+        from gordo_components_tpu.observability.metrics import _fmt
+
+        # _fmt renders inf as "+Inf", matching the bucket labels
+        with lock:
+            stats.setdefault("exemplars", {}).setdefault(kind, {})[
+                _fmt(hist.bucket_le(elapsed))
+            ] = {
+                "trace_id": trace.trace_id,
+                "value_ms": round(elapsed * 1e3, 3),
+                "at": round(time.time(), 3),
+            }
+
+
 @web.middleware
 async def _stats_middleware(request, handler):
     """Per-endpoint-kind request/error counters + service-time histograms
@@ -108,7 +133,10 @@ async def _stats_middleware(request, handler):
     through the engine/bank stage spans, and closes with the response —
     its id echoed in ``X-Request-Id``/``traceparent`` and attached as an
     exemplar on the request-latency bucket it landed in, so a histogram
-    spike resolves to one retrievable trace. Single event-loop thread:
+    spike resolves to one retrievable trace. The root ends at the
+    handler's return; a tensor answer's trace is published once its body
+    has been written (the ``send`` span), every other trace there and
+    then. Single event-loop thread:
     plain dict/int mutation is safe. Counter keys come from the matched
     route TEMPLATE (a bounded set) — keying on raw paths would let a
     scanner probing random URLs grow the dict without bound."""
@@ -193,6 +221,7 @@ async def _stats_middleware(request, handler):
     t0 = time.monotonic()
     status = 500  # a non-HTTP handler crash surfaces as a 500
     counted = False
+    resp = None
     try:
         resp = await handler(request)
         status = resp.status
@@ -265,27 +294,21 @@ async def _stats_middleware(request, handler):
                         ),
                     )
         if trace is not None:
-            trace.finish(error=status >= 400, status=status)
-            # exemplar-style link on the latency histogram: the LAST trace
-            # to land in each bucket, keyed by the bucket's le edge
-            # (formatted EXACTLY as the Prometheus exposition formats it,
-            # so the strings join against the scraped histogram) — bounded
-            # at O(buckets) per kind, surfaced through /stats so "p99
-            # spiked" resolves to "this trace" in two clicks. Only
-            # RETAINED traces publish an exemplar: a head-sample drop must
-            # not leave a dangling id the /traces lookup can't resolve
-            if trace.retained:
-                from gordo_components_tpu.observability.metrics import _fmt
-
-                # _fmt renders inf as "+Inf", matching the bucket labels
-                with lock:
-                    stats.setdefault("exemplars", {}).setdefault(kind, {})[
-                        _fmt(hist.bucket_le(elapsed))
-                    ] = {
-                        "trace_id": trace.trace_id,
-                        "value_ms": round(elapsed * 1e3, 3),
-                        "at": round(time.time(), 3),
-                    }
+            # the root ends here, at the handler's return, as the latency
+            # histogram does
+            trace.finish(error=status >= 400, publish=False, status=status)
+            if isinstance(getattr(resp, "body", None), TensorBody):
+                # a tensor answer is written after this returns, by
+                # aiohttp's finish_response in THIS task (the body's
+                # `send` span): the trace is published when the task
+                # ends, whether the write completed, failed or never began
+                asyncio.current_task().add_done_callback(
+                    lambda _task: _publish_trace(
+                        trace, stats, lock, kind, hist, elapsed
+                    )
+                )
+            else:
+                _publish_trace(trace, stats, lock, kind, hist, elapsed)
         logger.debug(
             "access rid=%s trace=%s %s %s %d %.1fms",
             rid, trace.trace_id if trace is not None else "-",

@@ -1691,13 +1691,16 @@ async def _parse_scoring(request: web.Request):
     gone); ``X``/``y`` DataFrames exist only on the JSON/parquet paths
     (``None`` for tensor — its fast path never builds one). The ``parse``
     stage span carries the encoding, so per-encoding parse cost is
-    visible in traces (docs/observability.md)."""
+    visible in traces (docs/observability.md). A tensor body's ``parse``
+    holds ``receive``: the wait for the rest of the body and its join
+    (the handler starts once the headers are in)."""
     encoding = _request_encoding(request)
     trace = request.get("trace")
     t_parse = time.monotonic()
     X = y = yf = None
     if encoding == "tensor":
         raw = await request.read()
+        t_read = time.monotonic()
         try:
             # bytes -> frombuffer views -> float32 rows; no DataFrame,
             # no per-value boxing (server/model_io.py, utils/wire.py)
@@ -1726,7 +1729,9 @@ async def _parse_scoring(request: web.Request):
         if y is not None:
             yf = np.asarray(y.values, dtype="float32")
     if trace is not None:
-        trace.add_span("parse", t_parse, time.monotonic(), encoding=encoding)
+        parse = trace.add_span("parse", t_parse, time.monotonic(), encoding=encoding)
+        if encoding == "tensor":
+            trace.add_span("receive", t_parse, t_read, parent=parse, bytes=len(raw))
     return encoding, X, y, Xf, yf
 
 
@@ -1762,9 +1767,16 @@ class TensorBody(Payload):
     so the response carries ``Content-Length`` and is not chunked. The
     segments, and through them the arrays, are held until the last byte
     has left: the transport keeps what a ``send`` did not take by
-    reference."""
+    reference.
 
-    def __init__(self, frames) -> None:
+    Given the request's ``trace``, the write records its ``send`` span
+    there: the first segment handed to the connection's writer (the
+    headers go with it) → the last ``writer.write`` returned, with
+    ``bytes`` and ``segments`` handed; ``error: true`` and closed at the
+    failure where the connection failed first. The middleware publishes
+    such a trace only after this write (``server._stats_middleware``)."""
+
+    def __init__(self, frames, trace=None) -> None:
         segments = frame_segments(frames)
         super().__init__(segments, content_type=TENSOR_CONTENT_TYPE)
         self._size = sum(len(seg) for seg in segments)
@@ -1772,13 +1784,27 @@ class TensorBody(Payload):
         self.by_reference = sum(
             len(seg) for seg in segments if isinstance(seg, memoryview)
         )
+        self.trace = trace
 
     def decode(self, encoding: str = "utf-8", errors: str = "strict") -> str:
         return b"".join(self._value).decode(encoding, errors)
 
     async def write(self, writer) -> None:
-        for segment in self._value:
-            await writer.write(segment)
+        start = time.monotonic()
+        handed = 0
+        try:
+            for segment in self._value:
+                await writer.write(segment)
+                handed += 1
+        finally:
+            trace = self.trace
+            if trace is not None and not trace.published:
+                trace.add_span(
+                    "send", start, time.monotonic(),
+                    error=handed < len(self._value),
+                    bytes=sum(len(seg) for seg in self._value[:handed]),
+                    segments=handed,
+                )
 
 
 @routes.post("/gordo/v0/{project}/{target}/prediction")
@@ -1844,7 +1870,7 @@ async def prediction(request: web.Request) -> web.Response:
         # copied — no tolist, no index stringification (the client trims
         # its own index by the offset in __meta__)
         with stage("encode", trace, stage="to_wire"):
-            body = TensorBody(prediction_frames(output, len(Xf)))
+            body = TensorBody(prediction_frames(output, len(Xf)), trace)
         return web.Response(body=body, content_type=TENSOR_CONTENT_TYPE)
     with stage("encode", trace, stage="to_json"):
         out_index = X.index[len(X) - len(output):]
@@ -1896,7 +1922,8 @@ async def anomaly_prediction(request: web.Request) -> web.Response:
                     body = TensorBody(
                         anomaly_frames(
                             result.tags, result.to_arrays(), result.offset
-                        )
+                        ),
+                        trace,
                     )
                 total_scaled = result.total_scaled
             else:
@@ -1927,7 +1954,8 @@ async def anomaly_prediction(request: web.Request) -> web.Response:
                         frame["model-input"].columns,
                         anomaly_frame_arrays(frame),
                         len(Xf) - len(frame),
-                    )
+                    ),
+                    trace,
                 )
     except EngineOverloaded as exc:
         raise _http_overloaded(exc)

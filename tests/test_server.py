@@ -2,7 +2,9 @@
 models trained in a fixture (reference strategy: Flask test_client, SURVEY.md
 §4). Async tests are run by the conftest ``pytest_pyfunc_call`` hook."""
 
+import asyncio
 import contextlib
+import time
 
 import numpy as np
 import pytest
@@ -10,7 +12,11 @@ from aiohttp.test_utils import TestClient, TestServer
 
 from gordo_components_tpu import serializer
 from gordo_components_tpu.models import AutoEncoder, DiffBasedAnomalyDetector
+from gordo_components_tpu.observability import Tracer
+from gordo_components_tpu.observability.tracing import format_traceparent
 from gordo_components_tpu.server import build_app
+from gordo_components_tpu.server.model_io import anomaly_frames
+from gordo_components_tpu.server.views import TensorBody
 from gordo_components_tpu.server.transport import score_tensor_blocking
 from gordo_components_tpu.server.utils import dict_to_frame, frame_to_dict
 from gordo_components_tpu.utils.wire import (
@@ -39,8 +45,10 @@ def artifact_dir(tmp_path_factory):
 
 
 @contextlib.asynccontextmanager
-async def make_client(artifact_dir, **kwargs):
-    client = TestClient(TestServer(build_app(artifact_dir, **kwargs)))
+async def make_client(artifact_dir, on_prepare=(), **kwargs):
+    app = build_app(artifact_dir, **kwargs)
+    app.on_response_prepare.extend(on_prepare)
+    client = TestClient(TestServer(app))
     await client.start_server()
     try:
         yield client
@@ -372,3 +380,291 @@ async def test_response_counters_add_up_to_the_bytes_sent(artifact_dir):
         f"{by_reference}" in text
     )
     assert f'gordo_server_response_bytes_total{{encoding="json"}} {json_bytes}' in text
+
+
+# --------------------------------------------------------------------- #
+# a tensor answer's bytes on the socket, in its trace: `receive` under
+# `parse`, `send` after the root, the trace published once the body left
+# --------------------------------------------------------------------- #
+
+_ANOMALY = "/gordo/v0/proj/machine-a/anomaly/prediction"
+
+
+def _traced(tid, **headers):
+    """Headers of a request whose trace the server keeps (sampled flag)."""
+    return {"traceparent": format_traceparent(tid, "cd" * 8), **headers}
+
+
+async def _published(tracer, tid, timeout=10.0):
+    """``tid``'s retained traces once no trace is in flight: a tensor
+    answer's trace is published when the task that wrote it has ended."""
+    deadline = time.monotonic() + timeout
+    while tracer.inflight and time.monotonic() < deadline:
+        await asyncio.sleep(0.01)
+    assert tracer.inflight == 0
+    return tracer.find(tid)
+
+
+def _span(trace, name):
+    (span,) = [s for s in trace.spans if s.name == name]
+    return span
+
+
+class _Writer:
+    """A connection's writer that takes segments, and fails at the
+    ``fail_at``-th as a connection whose peer has gone does."""
+
+    def __init__(self, fail_at=None):
+        self.got, self.fail_at = [], fail_at
+
+    async def write(self, chunk):
+        if len(self.got) == self.fail_at:
+            raise ConnectionResetError("Cannot write to closing transport")
+        self.got.append(bytes(chunk))
+
+
+def _answer_frames(rows=30_000):
+    rng = np.random.RandomState(3)
+    arrays = {
+        name: rng.rand(rows, 3).astype("float32")
+        for name in ("model-input", "model-output", "tag-anomaly-scaled",
+                     "tag-anomaly-unscaled")
+    }
+    arrays["total-anomaly-scaled"] = rng.rand(rows).astype("float32")
+    arrays["total-anomaly-unscaled"] = rng.rand(rows).astype("float32")
+    return anomaly_frames(["a", "b", "c"], arrays, 0)
+
+
+async def test_tensor_body_write_records_send_until_published():
+    """``TensorBody.write`` records ``send`` on the trace it was given:
+    every segment's bytes when the write completes; those handed before
+    the failure, ``error`` set, when the connection fails mid-write (the
+    error still propagates); nothing once the trace is published."""
+    tracer = Tracer(sample=1.0)
+    trace = tracer.start_trace("anomaly")
+    trace.finish(publish=False)
+    body = TensorBody(_answer_frames(), trace)
+    segments = list(body._value)
+    assert len(segments) > 2
+    writer = _Writer()
+    await body.write(writer)
+    send = _span(trace, "send")
+    assert writer.got == [bytes(seg) for seg in segments]
+    assert send.attributes == {"bytes": body.size, "segments": len(segments)}
+    assert not send.error and trace.root.end <= send.start <= send.end
+    assert tracer.recent() == [] and tracer.inflight == 1
+    trace.publish()
+    trace.publish()  # once
+    assert tracer.recent() == [trace] and tracer.inflight == 0
+    await body.write(_Writer())
+    assert [s.name for s in trace.spans].count("send") == 1
+
+    failed = tracer.start_trace("anomaly")
+    failed.finish(publish=False)
+    with pytest.raises(ConnectionResetError):
+        await TensorBody(_answer_frames(), failed).write(_Writer(fail_at=1))
+    send = _span(failed, "send")
+    assert send.error
+    assert send.attributes == {"bytes": len(segments[0]), "segments": 1}
+
+
+async def test_tensor_answer_trace_holds_receive_and_send(artifact_dir, monkeypatch):
+    """A banked tensor request's trace: ``receive`` (the body's bytes)
+    under ``parse`` and inside it, ``send`` under the root from at or after
+    the root's end, carrying the response's ``Content-Length`` and its
+    segments; the exemplar, published with the trace, holds the root's
+    duration."""
+    monkeypatch.setenv("GORDO_TRACE_SAMPLE", "0.1")
+    tid = "5e" * 16
+    X, body = _tensor_request(30_000, seed=7)
+    async with make_client(artifact_dir) as client:
+        resp = await client.post(
+            _ANOMALY, data=body,
+            headers=_traced(tid, **{"Content-Type": TENSOR_CONTENT_TYPE}),
+        )
+        assert resp.status == 200
+        raw = await resp.read()
+        (trace,) = await _published(client.app["tracer"], tid)
+        stats = await (await client.get("/gordo/v0/proj/stats")).json()
+    parse, receive, send = (_span(trace, n) for n in ("parse", "receive", "send"))
+    assert receive.parent is parse and send.parent is None
+    assert parse.start == receive.start <= receive.end <= parse.end
+    assert receive.attributes == {"bytes": len(body)}
+    assert trace.root.end <= send.start <= send.end
+    assert send.attributes["bytes"] == int(resp.headers["Content-Length"]) == len(raw)
+    assert send.attributes["segments"] > 2 and not send.error
+    assert not trace.error
+    (exemplar,) = [
+        e for e in stats["exemplars"]["anomaly"].values() if e["trace_id"] == tid
+    ]
+    assert exemplar["value_ms"] <= trace.root.duration_s * 1e3 + 1.0
+
+
+async def test_tensor_answer_trace_is_published_after_its_body(artifact_dir, monkeypatch):
+    """While a stalled reader holds the answer's write open (megabytes on
+    the server's side of the socket), the request's trace is in flight
+    and absent from every read; once the client has read the body it is
+    published, ``send`` and all. A JSON answer's trace is published at
+    the handler's return, before aiohttp prepares its response."""
+    import aiohttp
+
+    monkeypatch.setenv("GORDO_TRACE_SAMPLE", "1")
+    tid = "a1" * 16
+    _, body = _tensor_request(150_000, 21)
+    published_at_prepare = {}
+
+    async def on_prepare(request, response):
+        trace = request.get("trace")
+        if trace is not None and trace.name == "anomaly":
+            published_at_prepare[trace.trace_id] = trace.published
+
+    async with make_client(artifact_dir, on_prepare=[on_prepare]) as client:
+        tracer = client.app["tracer"]
+        base = str(client.make_url(""))
+        async with aiohttp.ClientSession() as http:
+            held = await http.post(
+                base + _ANOMALY, data=body,
+                headers=_traced(tid, **{"Content-Type": TENSOR_CONTENT_TYPE}),
+            )
+            assert held.status == 200  # headers in, the body not read
+            assert max(
+                conn.transport.get_write_buffer_size()
+                for conn in client.server.runner.server.connections
+            ) > 2**16
+            assert tracer.find(tid) == [] and tracer.inflight == 1
+            assert all(t.trace_id != tid for t in tracer.recent() + tracer.slow())
+            raw = await held.read()
+        (trace,) = await _published(tracer, tid)
+        json_tid = "b2" * 16
+        resp = await client.post(_ANOMALY, json=_x_payload(), headers=_traced(json_tid))
+        assert resp.status == 200
+        assert tracer.find(json_tid)  # before the body is read
+        (json_trace,) = await _published(tracer, json_tid)
+    assert _span(trace, "send").attributes["bytes"] == len(raw)
+    assert published_at_prepare == {tid: False, json_tid: True}
+    names = {s.name for s in json_trace.spans}
+    assert "parse" in names and not names & {"receive", "send"}
+
+
+async def test_client_gone_mid_write_leaves_one_trace_with_failed_send(
+    artifact_dir, monkeypatch
+):
+    """The client reads the headers and closes its connection while the
+    answer is being written: one trace is published, its ``send`` flagged
+    ``error`` and closed at the failure with fewer bytes than the body,
+    and nothing is left in flight."""
+    import aiohttp
+
+    monkeypatch.setenv("GORDO_TRACE_SAMPLE", "1")
+    tid = "c3" * 16
+    _, body = _tensor_request(150_000, 22)
+    async with make_client(artifact_dir) as client:
+        base = str(client.make_url(""))
+        async with aiohttp.ClientSession() as http:
+            held = await http.post(
+                base + _ANOMALY, data=body,
+                headers=_traced(tid, **{"Content-Type": TENSOR_CONTENT_TYPE}),
+            )
+            assert held.status == 200
+            length = int(held.headers["Content-Length"])
+            held.close()  # the connection goes with it
+            (trace,) = await _published(client.app["tracer"], tid)
+    send = _span(trace, "send")
+    assert send.error and trace.error
+    assert send.attributes["bytes"] < length
+    assert trace.root.end <= send.start <= send.end
+    assert [s.name for s in trace.spans].count("send") == 1
+
+
+async def test_prepare_failing_leaves_one_trace_without_send(artifact_dir, monkeypatch):
+    """The connection is gone when aiohttp prepares the tensor answer
+    (the failure surfaces in ``prepare``, before any byte is handed to
+    the writer): one trace, published, with no ``send``."""
+    monkeypatch.setenv("GORDO_TRACE_SAMPLE", "1")
+    tid = "d4" * 16
+
+    async def gone(request, response):
+        if request.get("trace") is not None:
+            request.transport.abort()
+            raise ConnectionResetError("Connection lost")
+
+    async with make_client(artifact_dir, on_prepare=[gone]) as client:
+        with pytest.raises(Exception):
+            resp = await client.post(
+                _ANOMALY, data=_tensor_request(64, 23)[1],
+                headers=_traced(tid, **{"Content-Type": TENSOR_CONTENT_TYPE}),
+            )
+            await resp.read()
+        (trace,) = await _published(client.app["tracer"], tid)
+    names = [s.name for s in trace.spans]
+    assert "receive" in names and "send" not in names
+    assert trace.root.attributes["status"] == 200
+
+
+async def test_tensor_request_the_handler_refuses_publishes_at_return(
+    artifact_dir, monkeypatch
+):
+    """A tensor request the handler raises on (a body that is no tensor
+    body: 400) has no answer to write: its trace is published at the
+    handler's return, once, with neither new span."""
+    monkeypatch.setenv("GORDO_TRACE_SAMPLE", "1")
+    tid = "e5" * 16
+    async with make_client(artifact_dir) as client:
+        resp = await client.post(
+            _ANOMALY, data=b"NOPE",
+            headers=_traced(tid, **{"Content-Type": TENSOR_CONTENT_TYPE}),
+        )
+        assert resp.status == 400
+        (trace,) = client.app["tracer"].find(tid)
+        assert client.app["tracer"].inflight == 0
+    assert trace.error
+    assert not {s.name for s in trace.spans} & {"receive", "send"}
+
+
+async def test_trace_is_published_by_the_task_that_writes_the_answer(
+    artifact_dir, monkeypatch
+):
+    """aiohttp runs the middleware and ``finish_response`` (which writes
+    the body) in one task, and the trace is published when that task
+    ends. An aiohttp that moved the write elsewhere fails here instead of
+    dropping ``send``."""
+    from aiohttp import web, web_protocol
+
+    monkeypatch.setenv("GORDO_TRACE_SAMPLE", "1")
+    tid = "f6" * 16
+    seen = {}
+    finish_response = web_protocol.RequestHandler.finish_response
+
+    async def watched(self, request, resp, start_time):
+        trace = request.get("trace")
+        seen["finish"] = asyncio.current_task()
+        seen["published_before_write"] = trace.published
+        out = await finish_response(self, request, resp, start_time)
+        seen["send_at_write_end"] = any(s.name == "send" for s in trace.spans)
+        seen["published_at_write_end"] = trace.published
+        return out
+
+    @web.middleware
+    async def handler_task(request, handler):
+        seen["handler"] = asyncio.current_task()
+        return await handler(request)
+
+    monkeypatch.setattr(web_protocol.RequestHandler, "finish_response", watched)
+    app = build_app(artifact_dir)
+    app.middlewares.append(handler_task)  # innermost: the handler's own task
+    client = TestClient(TestServer(app))
+    await client.start_server()
+    try:
+        resp = await client.post(
+            _ANOMALY, data=_tensor_request(64, 24)[1],
+            headers=_traced(tid, **{"Content-Type": TENSOR_CONTENT_TYPE}),
+        )
+        await resp.read()
+        (trace,) = await _published(client.app["tracer"], tid)
+    finally:
+        await client.close()
+    assert seen["finish"] is seen["handler"]
+    assert seen["published_before_write"] is False
+    assert seen["send_at_write_end"] is True
+    assert seen["published_at_write_end"] is False  # the task's end does it
+    assert trace.published

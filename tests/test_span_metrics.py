@@ -26,6 +26,8 @@ SERVE = {
     "device_wait_ms.serve": 9.0,
     "resolve_ms.serve": 1.0,
     "encode_ms.serve": 2.0,
+    "receive_ms.serve": 0.5,          # under parse: in no sum of the top level
+    "send_ms.serve": 4.0,             # after the root: in no sum of the top level
 }
 TRAIN = {
     "prepare_ms.train": 1e3 * 1.5,        # median of 1.0 and 2.0 s
@@ -57,6 +59,7 @@ def _serve_obs():
         "device_execute": [18.0, 22.0], "enqueue": [6.0, 7.0, 8.0], "device_wait": [9.0],
         "postprocess": [3.0], "fetch": [2.0], "reassemble": [1.0],
         "resolve": [0.5, 1.0, 1.5], "encode": [2.0, 2.0],
+        "receive": [0.25, 0.5, 0.75], "send": [3.0, 5.0],
     }}
 
 

@@ -377,6 +377,70 @@ def test_profiler_session_holds_the_programs_stages(tmp_path, artifact_dir):
         assert "gordo:" + name in regions, sorted(regions)
 
 
+def test_exported_span_lies_on_its_profiler_region(tmp_path):
+    """A span's Chrome ``ts`` is on the profiler's clock: the ``gordo:x``
+    region that the same ``stage`` opened lies at the trace's
+    ``profile_start_time`` plus its ``start_ns``, and the exported span
+    starts within 0.5 ms of it and lasts as long."""
+    import jax
+    from jax.profiler import ProfileData
+
+    trace = Tracer(sample=1.0).start_trace("request")
+    stage("x", trace)  # binds JAX's profiler outside the session
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        time.sleep(0.01)
+        with stage("x", trace):
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    trace.finish()
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")
+    )
+    data = ProfileData.from_file(path)
+    (session_start_ns,) = [
+        value
+        for plane in data.planes
+        for name, value in plane.stats
+        if name == "profile_start_time"
+    ]
+    (region,) = [
+        e
+        for plane in data.planes
+        if not plane.name.startswith("/device:")
+        for line in plane.lines
+        for e in line.events
+        if e.name == "gordo:x"
+    ]
+    (span,) = [e for e in chrome_trace([trace])["traceEvents"] if e["name"] == "x"]
+    assert abs(span["ts"] - (session_start_ns + region.start_ns) * 1e-3) <= 500
+    assert abs(span["dur"] - region.duration_ns * 1e-3) <= 500
+
+
+def test_traces_share_one_clock_anchor():
+    """Every trace of the process is exported against one anchor: two
+    traces' exported starts lie as far apart as their monotonic starts,
+    and ``start_unix`` is the root's start on the same clock."""
+    tracer = Tracer(sample=1.0)
+    first = tracer.start_trace("request")
+    time.sleep(0.02)
+    second = tracer.start_trace("request")
+    first.finish()
+    second.finish()
+    roots = [
+        e for e in chrome_trace([first, second])["traceEvents"]
+        if e["ph"] == "X" and e["name"] == "request"
+    ]
+    apart_us = (second.root.start - first.root.start) * 1e6
+    assert roots[1]["ts"] - roots[0]["ts"] == pytest.approx(apart_us, abs=1.0)
+    assert roots[0]["ts"] * 1e-6 == pytest.approx(first.summary()["start_unix"], abs=1e-3)
+    assert first.summary()["start_unix"] == pytest.approx(time.time(), abs=5.0)
+
+
 # ------------------------------------------------------------------ #
 # live server: the acceptance round-trip
 # ------------------------------------------------------------------ #
@@ -494,7 +558,9 @@ async def test_top_level_spans_tile_the_request(artifact_dir, monkeypatch, encod
     where the stages are short and the middleware's own work is not);
     each child lies inside its parent; ``postprocess`` is the bank's
     alone, once per request per group, and the view's framing is
-    ``encode``."""
+    ``encode``. A tensor request's ``parse`` holds ``receive``, and its
+    answer's ``send`` hangs under the root after the root's end: the
+    body's bytes leave once the handler has returned."""
     from gordo_components_tpu.observability.goodput import attribute_trace
     from gordo_components_tpu.utils.wire import TENSOR_CONTENT_TYPE, pack_frames
 
@@ -512,10 +578,17 @@ async def test_top_level_spans_tile_the_request(artifact_dir, monkeypatch, encod
             await resp.read()
         traces = [t for t in client.app["tracer"].recent() if t.name == "anomaly"]
     assert len(traces) == 6
+    children = dict(_CHILDREN)
+    if encoding == "tensor":
+        children["parse"] = ("receive",)
     coverages = []
     for trace in traces:
         root = trace.root
         top = trace.children()
+        sends = [s for s in top if s.name == "send"]
+        assert len(sends) == (encoding == "tensor")
+        assert all(root.end <= s.start <= s.end for s in sends)
+        top = [s for s in top if s.name != "send"]
         names = [s.name for s in top]
         assert set(names) == set(_STAGES), names
         # once each, but for the two framing steps of the JSON path
@@ -525,10 +598,12 @@ async def test_top_level_spans_tile_the_request(artifact_dir, monkeypatch, encod
             assert prev.end <= nxt.start, (prev.name, nxt.name)
         for parent in top:
             kids = trace.children(parent)
-            assert [s.name for s in kids] == list(_CHILDREN.get(parent.name, ()))
+            assert [s.name for s in kids] == list(children.get(parent.name, ()))
             for kid in kids:
                 assert parent.start <= kid.start and kid.end <= parent.end
-        assert len(trace.spans) == 1 + len(top) + sum(map(len, _CHILDREN.values()))
+        assert len(trace.spans) == (
+            1 + len(top) + len(sends) + sum(map(len, children.values()))
+        )
         encodes = [s.attributes["stage"] for s in top if s.name == "encode"]
         assert encodes == (["to_wire"] if encoding == "tensor" else ["to_frame", "to_json"])
         coverages.append(covered_seconds(top) / root.duration_s)
