@@ -218,7 +218,7 @@ def swap_bank(
             engine = BatchingEngine(
                 new_bank,
                 max_batch=cfg.get("max_batch", 64),
-                flush_ms=cfg.get("flush_ms", 2.0),
+                flush_ms=cfg.get("flush_ms", 0.0),
                 max_queue=cfg.get("max_queue"),
             )
             engine.start()

@@ -19,7 +19,7 @@ Inside a class, requests pop in deadline order (earliest
 ``expires_at`` first; requests without a deadline keep FIFO order after
 all deadlined ones with earlier expiry) — the "class-aware deadline
 ordering inside a batch window" half of the tentpole: when the engine
-can only fit part of a backlog into a flush window, it takes the
+can only fit part of a backlog into one batch, it takes the
 entries closest to timing out first instead of whatever arrived first.
 
 With every request in one class (the no-config default) behavior is

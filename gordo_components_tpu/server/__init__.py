@@ -479,7 +479,7 @@ def build_app(
     model_dir: str,
     target_name: Optional[str] = None,
     use_bank: Optional[bool] = None,
-    bank_flush_ms: float = 2.0,
+    bank_flush_ms: float = 0.0,
     bank_max_batch: int = 64,
     bank_max_queue: Optional[int] = None,
     devices: Optional[int] = None,

@@ -289,7 +289,7 @@ class ServerPool:
         engine = BatchingEngine(
             bank,
             max_batch=cfg.get("max_batch", 64),
-            flush_ms=cfg.get("flush_ms", 2.0),
+            flush_ms=cfg.get("flush_ms", 0.0),
             max_queue=cfg.get("max_queue"),
             registry=False,
             dispatch_lock=lock,

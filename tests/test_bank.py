@@ -210,10 +210,12 @@ def test_bank_max_rows_cap_not_pow2():
     assert _prev_pow2(1) == 1
 
 
-async def test_batching_engine_coalesces(fleet_models):
+@pytest.mark.parametrize("flush_ms", [0.0, 20.0])
+async def test_batching_engine_coalesces(fleet_models, flush_ms):
+    """Requests queued together ride one call, with or without a window."""
     models, data = fleet_models
     bank = ModelBank.from_models(models)
-    engine = BatchingEngine(bank, max_batch=8, flush_ms=20.0)
+    engine = BatchingEngine(bank, max_batch=8, flush_ms=flush_ms)
     try:
         names = ["plain", "jax-scaled", "sk-scaled", "wide"] * 3
         results = await asyncio.gather(

@@ -437,6 +437,7 @@ async def test_metrics_endpoint_sharded_format_and_monotonic(sharded_artifact_di
         for family in (
             "gordo_engine_queue_depth",
             "gordo_engine_requests_total",
+            "gordo_engine_requests_behind_total",
             "gordo_server_requests_total",
             "gordo_server_request_seconds",
             "gordo_server_uptime_seconds",
