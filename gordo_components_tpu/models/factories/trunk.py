@@ -5,7 +5,7 @@ The trunk is a stack of pre-norm decoder layers. It has no tokens: a
 machine's scaled sensor rows enter through that machine's own linear
 projection ``tags -> hidden`` (as a multimodal decoder takes continuous
 features in place of embedding rows) and leave through its own linear head
-``hidden -> tags``. A request's rows are ONE causal sequence. Two kinds:
+``hidden -> tags``. A request's rows are ONE causal sequence. Three kinds:
 
 - ``sparse_moe_decoder`` (``SparseMoEDecoder``): every layer a
   grouped-query attention over an indexer's selection of keys
@@ -21,6 +21,12 @@ features in place of embedding rows) and leave through its own linear head
   ``indexer_types``, the attention runs under an indexer's selection of
   keys (``ops/sparse_attention.py``'s ``select_keys``) that a ``full``
   layer makes and the ``shared`` layers after it reuse.
+- ``hybrid_moe_decoder`` (``HybridMoEDecoder``): every layer ONE mixer, as
+  the published ``hybrid_override_pattern`` spells the layers out: a
+  Mamba-2 state-space mixer scanned in chunks (``ops/ssd.py``), a routed
+  layer of squared-ReLU experts beside a shared one (``ops/moe.py``, a held
+  range as above), or grouped-query attention over every causal key
+  (``ops/sparse_attention.py``'s kernel with no mask).
 
 The walk through the layers carries the residual stream AND the selection
 in force: ``layer(w, x, n_valid, selection)`` returns the next ``x``, the
@@ -52,8 +58,9 @@ from gordo_components_tpu.models.register import register_model_builder
 from gordo_components_tpu.ops.latent_attention import latent_attention, yarn
 from gordo_components_tpu.ops.moe import expert_layer
 from gordo_components_tpu.ops.sparse_attention import (
-    WITNESS_STRIDE, rope, select_and_attend, select_keys, witness_of,
+    WITNESS_STRIDE, masked_attention, rope, select_and_attend, select_keys, witness_of,
 )
+from gordo_components_tpu.ops.ssd import ssd_scan
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 # the routed experts of a ``LatentMoEDecoder`` take the rows in runs of at
@@ -120,14 +127,16 @@ class _Trunk:
             layer = {}
             shapes = self.layer_shapes(index)
             for leaf_key, (name, shape) in zip(jax.random.split(layer_key, len(shapes)), shapes.items()):
-                if name.endswith(("_norm", "_scale")):
-                    layer[name] = jnp.ones(shape, F32)
-                elif name.endswith("_bias"):
-                    layer[name] = jnp.zeros(shape, F32)
-                else:
-                    layer[name] = self._uniform(leaf_key, shape, shape[-2], BF16)
+                layer[name] = self._init_leaf(name, leaf_key, shape)
             layers.append(layer)
         return {"layers": layers, "final_norm": jnp.ones((self.hidden_size,), F32)}
+
+    def _init_leaf(self, name: str, key, shape):
+        if name.endswith(("_norm", "_scale")):
+            return jnp.ones(shape, F32)
+        if name.endswith("_bias"):
+            return jnp.zeros(shape, F32)
+        return self._uniform(key, shape, shape[-2], BF16)
 
     def init_member(self, key) -> Dict[str, Any]:
         k_in, k_out = jax.random.split(key)
@@ -670,6 +679,284 @@ class LatentMoEDecoder(_Trunk):
         }
 
 
+@dataclass(frozen=True)
+class HybridMoEDecoder(_Trunk):
+    """A hybrid decoder whose layers are each ONE mixer, ``x + mixer(
+    RMSNorm(x))``: a Mamba-2 state-space mixer (``M``), a routed layer of
+    squared-ReLU experts beside a shared one (``E``) or grouped-query
+    attention over every causal key (``*``), as the published
+    ``hybrid_override_pattern`` spells the layers out. Key names are the
+    published config's (``nemotron_h``), but for the share:
+    ``n_routed_experts`` is the router's width, and the trunk holds experts
+    ``expert_offset .. expert_offset + experts_held`` of every routed layer
+    (``experts_held=None``: all of them). ``held_layers``: which of the
+    published layers the trunk holds (default the first
+    ``num_hidden_layers``), and so which kind each held layer is.
+
+    A layer's leaves (``layer_shapes``), ``input_norm`` (D,) in every kind:
+
+    - ``M``: ``in_proj`` (D, 2 d_in + 2 G N + H), splitting into the gate
+      ``z`` (d_in), ``xBC`` (d_in + 2 G N) and ``dt`` (H); ``conv`` (K, d_in
+      + 2 G N) and ``conv_bias``, a causal depthwise convolution of ``xBC``
+      followed by SiLU; ``dt_bias``, ``A_log`` and ``D`` (H,); the scan
+      (``ops/ssd.py``); ``mixer_norm`` (d_in,), an RMSNorm over each of the
+      G groups of ``y * silu(z)``; ``out_proj`` (d_in, D). d_in is
+      ``mamba_num_heads * mamba_head_dim``, G ``n_groups``, N
+      ``ssm_state_size``, K ``conv_kernel``.
+    - ``E``: ``router`` (D, n_routed_experts), ``router_bias``
+      (n_routed_experts,) (the published ``e_score_correction_bias``),
+      ``up`` (held, D, I), ``down`` (held, I, D), ``shared_up`` (D, S),
+      ``shared_down`` (S, D); every expert ``down(relu(up h)^2)``.
+    - ``*``: ``wq`` (D, H d), ``wk``/``wv`` (D, G_kv d), ``wo`` (H d, D); no
+      rotary embedding, scale d^-1/2.
+
+    ``layer`` tells the kinds apart by the leaves it is handed (``A_log``:
+    a mixer; ``router``: routed; any other: attention), so a caller that
+    jits it compiles it once for each kind, whatever the depth."""
+
+    hidden_size: int = 2688
+    num_hidden_layers: int = 52
+    hybrid_override_pattern: str = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+    held_layers: Optional[Tuple[int, ...]] = None
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    moe_intermediate_size: int = 1856
+    moe_shared_expert_intermediate_size: int = 3712
+    n_routed_experts: int = 128
+    num_experts_per_tok: int = 6
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 2.5
+    rms_norm_eps: float = 1e-5
+    expert_offset: int = 0
+    experts_held: Optional[int] = None
+    chunk_size: int = 128
+
+    def __post_init__(self):
+        held = self.published_layers
+        if (len(held) != self.num_hidden_layers
+                or any(not 0 <= l < len(self.hybrid_override_pattern) for l in held)
+                or set(self.hybrid_override_pattern) - set("ME*")):
+            raise ValueError(
+                f"held_layers {held!r}: one published layer for each of the "
+                f"{self.num_hidden_layers} held, each a position of a pattern of 'M', 'E' and '*' "
+                f"({self.hybrid_override_pattern!r})")
+
+    # ------------------------------------------------------------ shapes
+
+    @property
+    def published_layers(self) -> Tuple[int, ...]:
+        """The published layers held, in order."""
+        if self.held_layers is None:
+            return tuple(range(self.num_hidden_layers))
+        return tuple(self.held_layers)
+
+    @property
+    def experts_here(self) -> int:
+        return self.n_routed_experts if self.experts_held is None else self.experts_held
+
+    def kind(self, layer: int) -> str:
+        """``M``, ``E`` or ``*``: held layer ``layer``'s published kind."""
+        return self.hybrid_override_pattern[self.published_layers[layer]]
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_width(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.ssm_state_size
+
+    def layer_shapes(self, layer: int = 0) -> Dict[str, Tuple[int, ...]]:
+        """Held layer ``layer``'s leaves, by its kind."""
+        D, kind = self.hidden_size, self.kind(layer)
+        if kind == "M":
+            H, d_in, conv = self.mamba_num_heads, self.d_inner, self.conv_width
+            return {
+                "input_norm": (D,), "in_proj": (D, d_in + conv + H),
+                "conv": (self.conv_kernel, conv), "conv_bias": (conv,),
+                "dt_bias": (H,), "A_log": (H,), "D": (H,), "mixer_norm": (d_in,),
+                "out_proj": (d_in, D),
+            }
+        if kind == "E":
+            I, S = self.moe_intermediate_size, self.moe_shared_expert_intermediate_size
+            return {
+                "input_norm": (D,), "router": (D, self.n_routed_experts),
+                "router_bias": (self.n_routed_experts,),
+                "up": (self.experts_here, D, I), "down": (self.experts_here, I, D),
+                "shared_up": (D, S), "shared_down": (S, D),
+            }
+        H, G, d = self.num_attention_heads, self.num_key_value_heads, self.head_dim
+        return {"input_norm": (D,), "wq": (D, H * d), "wk": (D, G * d), "wv": (D, G * d),
+                "wo": (H * d, D)}
+
+    def _init_leaf(self, name: str, key, shape):
+        """The mixer's three vectors as the published initialisation draws
+        them: ``A`` uniform on [1, 16), ``dt`` log-uniform between the time
+        step's bounds (floored) and ``dt_bias`` its inverse softplus,
+        ``D`` one."""
+        if name == "A_log":
+            return jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0))
+        if name == "dt_bias":
+            lo, hi = math.log(self.time_step_min), math.log(self.time_step_max)
+            dt = jnp.maximum(jnp.exp(jax.random.uniform(key, shape, F32, lo, hi)), self.time_step_floor)
+            return dt + jnp.log(-jnp.expm1(-dt))
+        if name == "D":
+            return jnp.ones(shape, F32)
+        return super()._init_leaf(name, key, shape)
+
+    @property
+    def attention_tile(self) -> int:
+        """Rows of the attention kernel's score tile: two chunks (256 at the
+        published 128; 512 rows with 32 query heads of 128 over-run a
+        v5e's scoped VMEM)."""
+        return 2 * self.chunk_size
+
+    def padded_rows(self, rows: int) -> int:
+        """A request is never cut: it is padded to whole attention tiles,
+        each two whole chunks of the scan (10 080 rows -> 10 240)."""
+        return -(-int(rows) // self.attention_tile) * self.attention_tile
+
+    def program_bytes(self, batch: int, rows: int) -> int:
+        """Device bytes ``layer`` needs beside its arguments for ``batch``
+        requests of ``rows`` padded rows, counted at the mixer, the widest
+        of the three kinds: per row its input projection, the convolution's
+        padded input and output, the scan's output and the gated output
+        (float32), the scan's head-major operands (bfloat16); plus the
+        residual stream twice. What bounds a bank's batch. The TPU
+        compiler's own analysis of the mixer reads 0.92 and 1.83 GB at 1 and
+        2 requests of 10 240 rows at the published sizes, the routed layer
+        0.83 and 1.02, attention 0.17 and 0.35 (``tests/test_tpu_compile.py``);
+        this count reads 1.76 times the mixer's."""
+        H, d_in, conv = self.mamba_num_heads, self.d_inner, self.conv_width
+        mixer = 4 * (d_in + conv + H) + 10 * conv + 8 * d_in
+        return int(batch * rows * (mixer + 8 * self.hidden_size))
+
+    def scan_flops_per_row(self) -> float:
+        """One mixer layer's scan, one row, in whole chunks: the chunk's
+        ``C B^T`` a group, its decayed matrix times x, the carried state's
+        term and the state's update a head."""
+        Q, N, P = self.chunk_size, self.ssm_state_size, self.mamba_head_dim
+        return 2.0 * Q * self.n_groups * N + 2.0 * Q * self.d_inner + 4.0 * self.d_inner * N
+
+    def forward_flops_per_row(self, context_rows: int) -> float:
+        """Forward FLOPs of one row of a ``context_rows``-row request,
+        averaged over its positions: 2 a multiply-add of the matrices a row
+        meets (a mixer's two projections, a routed layer's router, shared
+        expert and the held experts' share of its ``num_experts_per_tok`` at
+        an even load, attention's four), each mixer's scan in whole chunks,
+        and attention's scores and values over every causal key."""
+        n, D = int(context_rows), self.hidden_size
+        kinds = [self.kind(l) for l in range(self.num_hidden_layers)]
+        shapes = {kind: self.layer_shapes(kinds.index(kind)) for kind in set(kinds)}
+        size = lambda kind, *names: sum(math.prod(shapes[kind][name]) for name in names)
+        per_kind = {
+            "M": 2.0 * size("M", "in_proj", "out_proj") + self.scan_flops_per_row(),
+            "E": 2.0 * (size("E", "router", "shared_up", "shared_down")
+                        + 2 * D * self.moe_intermediate_size * self.num_experts_per_tok
+                        * self.experts_here / self.n_routed_experts),
+            "*": 2.0 * size("*", "wq", "wk", "wv", "wo")
+                 + 4.0 * self.num_attention_heads * self.head_dim * (n + 1) / 2.0,
+        }
+        return sum(per_kind[kind] for kind in kinds) + 2.0 * 2 * self.n_features * D
+
+    def nominal_context_rows(self) -> int:
+        """The request length a per-row FLOP count is quoted at: eighty
+        chunks (10 240 rows at 128: a week of minutes, padded)."""
+        return 80 * self.chunk_size
+
+    # ----------------------------------------------------------- forward
+
+    def _mixer(self, w, x, n_valid, interpret: bool):
+        B, T, _ = x.shape
+        H, P, G, N, K = (self.mamba_num_heads, self.mamba_head_dim, self.n_groups,
+                         self.ssm_state_size, self.conv_kernel)
+        d_in, eps = self.d_inner, self.rms_norm_eps
+        with jax.named_scope("trunk/mamba/in_proj"):
+            proj = _mm(_rmsnorm(x, w["input_norm"], eps), w["in_proj"])
+            z, xBC, dt = proj[..., :d_in], proj[..., d_in:d_in + self.conv_width], proj[..., -H:]
+        with jax.named_scope("trunk/mamba/conv"):
+            padded = jnp.pad(xBC, ((0, 0), (K - 1, 0), (0, 0)))  # causal: row t sees t - K + 1 .. t
+            kernel = w["conv"].astype(F32)
+            xBC = jax.nn.silu(sum(padded[:, k:k + T] * kernel[k] for k in range(K)) + w["conv_bias"])
+            xs = xBC[..., :d_in].reshape(B, T, H, P)
+            Bm = xBC[..., d_in:d_in + G * N].reshape(B, T, G, N)
+            Cm = xBC[..., d_in + G * N:].reshape(B, T, G, N)
+        with jax.named_scope("trunk/mamba/scan"):
+            delta = jax.nn.softplus(dt + w["dt_bias"])
+            y = ssd_scan(xs, delta, -jnp.exp(w["A_log"]), Bm, Cm, w["D"], self.chunk_size, interpret)
+        with jax.named_scope("trunk/mamba/norm"):
+            y = (y.reshape(B, T, d_in) * jax.nn.silu(z)).reshape(B, T, G, d_in // G)
+            y = _rmsnorm(y, 1.0, eps).reshape(B, T, d_in) * w["mixer_norm"]
+        with jax.named_scope("trunk/mamba/out_proj"):
+            out = x + _mm(y, w["out_proj"])
+        chunks = (n_valid + self.chunk_size - 1) // self.chunk_size
+        return out, {"ssm_chunks": chunks.astype(jnp.int32)}
+
+    def _routed(self, w, x, n_valid, interpret: bool):
+        B, T, D = x.shape
+        with jax.named_scope("trunk/route"):
+            h = _rmsnorm(x, w["input_norm"], self.rms_norm_eps).reshape(B * T, D)
+        with jax.named_scope("trunk/shared_expert"):
+            shared = _mm(jnp.square(jax.nn.relu(_mm(h, w["shared_up"]))), w["shared_down"])
+        valid = (jnp.arange(T)[None, :] < n_valid[:, None]).reshape(-1)
+        y, experts, tokens, blocks = expert_layer(
+            h, w, self.num_experts_per_tok, valid, interpret, expert_offset=self.expert_offset,
+            scoring="sigmoid", n_group=self.n_group, topk_group=self.topk_group,
+            scale=self.routed_scaling_factor,
+        )
+        return x + (y + shared).reshape(B, T, D), {
+            "experts": experts.reshape(B, T, -1).astype(jnp.uint8), "held_tokens": tokens,
+            "held_blocks": blocks,
+        }
+
+    def _attention(self, w, x, n_valid, interpret: bool):
+        B, T, _ = x.shape
+        H, G, d = self.num_attention_heads, self.num_key_value_heads, self.head_dim
+        heads_first = lambda a, n: a.reshape(B, T, n, d).transpose(0, 2, 1, 3).astype(BF16)
+        with jax.named_scope("trunk/project"):
+            h = _rmsnorm(x, w["input_norm"], self.rms_norm_eps)
+            q = heads_first(_mm(h, w["wq"]), H).reshape(B, G, H // G, T, d)
+            k, v = heads_first(_mm(h, w["wk"]), G), heads_first(_mm(h, w["wv"]), G)
+        def attend(args):
+            with jax.named_scope("trunk/attention"):
+                return masked_attention(*args, None, self.attention_tile, interpret)
+
+        # one request at a time, as the other kinds: requests share no keys
+        out = jax.lax.map(attend, (q, k, v))  # (B, G, H / G, T, d)
+        with jax.named_scope("trunk/project"):
+            out = out.reshape(B, H, T, d).transpose(0, 2, 1, 3).reshape(B, T, H * d)
+            return x + _mm(out, w["wo"]), {}
+
+    def layer(self, w, x, n_valid, selection=None, interpret: bool = False):
+        """One layer over the B x T rows of ``x`` (B, T, D), T a multiple
+        of ``attention_tile``; ``n_valid`` (B,): rows beyond it are padding,
+        and every mixer is causal, so they reach no valid row. No kind
+        selects keys: ``selection`` is taken and returned as it came.
+
+        Returns the next ``x``, ``selection``, and what the layer observed:
+        a mixer ``ssm_chunks`` (B,) int32, the chunks of valid rows it
+        scanned a request; a routed layer ``experts`` (B, T, top_k) uint8,
+        ``held_tokens`` (held,) int32 and ``held_blocks`` () int32, as
+        ``LatentMoEDecoder``'s; attention nothing."""
+        if "A_log" in w:
+            x, seen = self._mixer(w, x, n_valid, interpret)
+        elif "router" in w:
+            x, seen = self._routed(w, x, n_valid, interpret)
+        else:
+            x, seen = self._attention(w, x, n_valid, interpret)
+        return x, selection, seen
+
+
 def _only_float32(compute_dtype: str) -> None:
     if compute_dtype != "float32":
         raise ValueError("the trunk fixes its own dtypes (bfloat16 operands, float32 accumulation)")
@@ -692,3 +979,15 @@ def latent_moe_decoder(n_features: int, compute_dtype: str = "float32", **sizes)
     if sizes.get("indexer_types") is not None:  # a JSON list
         sizes["indexer_types"] = tuple(sizes["indexer_types"])
     return LatentMoEDecoder(n_features=int(n_features), **sizes)
+
+
+@register_model_builder(type="TrunkForecast")
+def hybrid_moe_decoder(n_features: int, compute_dtype: str = "float32", **sizes) -> HybridMoEDecoder:
+    """Decoder trunk of one-mixer layers (Mamba-2, routed squared-ReLU
+    experts beside a shared one, causal attention) as the published pattern
+    spells them; ``sizes`` are the published config's keys
+    (``HybridMoEDecoder``), the held layers and the held range of experts."""
+    _only_float32(compute_dtype)
+    if sizes.get("held_layers") is not None:  # a JSON list
+        sizes["held_layers"] = tuple(sizes["held_layers"])
+    return HybridMoEDecoder(n_features=int(n_features), **sizes)

@@ -1,6 +1,7 @@
 """A routed expert layer: a learned router sends each token to its
-``top_k`` of ``E`` experts (SwiGLU feed-forwards), and the token's output
-is the weighted sum of theirs.
+``top_k`` of ``E`` experts (SwiGLU feed-forwards, or squared-ReLU ones
+``W_down,e relu(W_up,e h)^2`` where the layer has no ``gate``), and the
+token's output is the weighted sum of theirs.
 
     s = softmax(h W_r)  or  sigmoid(h W_r)     over the E experts, float32
     kept = top_k(s + b)                        among the experts of the
@@ -17,7 +18,7 @@ renormalised-probability router. ``b`` is a per-expert correction bias
 the unbiased scores. No bias is ``b = 0``.
 
 The layer may hold a *range* of the experts (``expert_offset`` and as many
-as ``gate`` has): one chip's share of a layer divided over chips by
+as ``up`` has): one chip's share of a layer divided over chips by
 experts. It still routes over all ``E`` (the router keeps its width) and
 returns every token's ``top_k`` of ``E``; it computes the pairs that fall
 on an expert it holds, and a token none of whose experts is held gets zero.
@@ -36,11 +37,12 @@ once by the caller as the bank decides its epilogue kernel).
 
 How much of the sorted order is worked through follows what the layer
 holds, which its leaves' shapes say. A layer that holds every expert takes
-the sorted pairs in ONE pass: gather, three grouped matmuls, the gather
-back into token order, the weighted sum over ``top_k``. A layer that holds
-a range works through the held pairs alone, which the sort has put first,
-in blocks of ``_BLOCK_PAIRS`` sorted pairs, by a loop whose trips are
-``ceil(held pairs / block)``: a trip gathers its pairs' rows, runs the three
+the sorted pairs in ONE pass: gather, the grouped matmuls (three, or two
+for a squared-ReLU expert), the gather back into token order, the
+weighted sum over ``top_k``. A layer that holds a range works through
+the held pairs alone, which the sort has put first, in blocks of
+``_BLOCK_PAIRS`` sorted pairs, by a loop whose trips are
+``ceil(held pairs / block)``: a trip gathers its pairs' rows, runs the
 grouped matmuls with the group sizes clipped to the block, zeroes the rows
 at or past the held count (no matmul wrote them: they may hold anything),
 weighs each row and adds it into its token's row of the output. Nothing
@@ -113,12 +115,17 @@ def _grouped(lhs, rhs, group_sizes, interpret):
 
 
 def _experts(x, params, sizes, interpret):
-    """The SwiGLU of each row's own expert: ``x`` (rows, D) bfloat16 grouped
-    by expert, ``sizes`` the groups' lengths; float32. Rows past the last
-    group are not written."""
-    gate = _grouped(x, params["gate"], sizes, interpret)
-    up = _grouped(x, params["up"], sizes, interpret)
-    return _grouped((jax.nn.silu(gate) * up).astype(jnp.bfloat16), params["down"], sizes, interpret)
+    """Each row's own expert: ``x`` (rows, D) bfloat16 grouped by expert,
+    ``sizes`` the groups' lengths; float32. The leaves say the expert's
+    form: ``gate``, ``up`` and ``down`` a SwiGLU, ``up`` and ``down`` alone
+    ``down(relu(up x)^2)``. Rows past the last group are not written."""
+    if "gate" in params:
+        gate = _grouped(x, params["gate"], sizes, interpret)
+        up = _grouped(x, params["up"], sizes, interpret)
+        hidden = jax.nn.silu(gate) * up
+    else:
+        hidden = jnp.square(jax.nn.relu(_grouped(x, params["up"], sizes, interpret)))
+    return _grouped(hidden.astype(jnp.bfloat16), params["down"], sizes, interpret)
 
 
 def expert_layer(
@@ -126,9 +133,9 @@ def expert_layer(
     interpret: bool = False, expert_offset: int = 0, **routing,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``h`` (tokens, D) float32; ``params``: ``router`` (D, E), where the
-    router has one its ``router_bias`` (E,), ``gate`` and ``up``
-    (held, D, I), ``down`` (held, I, D): experts ``expert_offset ..
-    expert_offset + held`` of the E; ``valid`` (tokens,)
+    router has one its ``router_bias`` (E,), ``up`` and, for a SwiGLU
+    expert, ``gate`` (held, D, I), ``down`` (held, I, D): experts
+    ``expert_offset .. expert_offset + held`` of the E; ``valid`` (tokens,)
     bool: padding is routed like any token (its rows are dropped later) but
     left out of the counts; ``routing``: ``route``'s.
 
@@ -140,7 +147,7 @@ def expert_layer(
     """
     n_tokens = h.shape[0]
     n_experts = params["router"].shape[-1]
-    held = params["gate"].shape[0]
+    held = params["up"].shape[0]
     whole = held == n_experts  # every pair is on a held expert
     with jax.named_scope("trunk/route"):
         weights, experts = route(

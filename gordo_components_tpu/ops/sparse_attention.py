@@ -24,11 +24,12 @@ matrix is never computed.
   patterns (a sort of 10 240 scores a query is the slow way to learn one
   threshold); keys that tie with the k-th are all kept.
 - The attention under the mask is a Pallas kernel (``masked_attention``):
-  one (chunk x chunk) tile of logits at a time, all the query heads of a
-  key-value head against it, the softmax accumulated online, so no
-  (heads, chunk, keys) tile of float32 logits ever goes to HBM. As XLA
-  ops over such tiles the softmax's reductions alone took 1.3 s of a
-  week-long request's 1.4 s on the chip (PERF.md, PR 28). The same kernel
+  one (chunk x chunk) tile of logits at a time (with no mask handed, every
+  causal key, read from the tiles' own indices: no mask array exists), all
+  the query heads of a key-value head against it, the softmax accumulated
+  online, so no (heads, chunk, keys) tile of float32 logits ever goes to
+  HBM. As XLA ops over such tiles the softmax's reductions alone took 1.3 s
+  of a week-long request's 1.4 s on the chip (PERF.md, PR 28). The same kernel
   runs in interpret mode off the chip.
 
 Matmuls take bfloat16 operands and accumulate in float32; indexer scores,
@@ -100,13 +101,42 @@ def _bf16(x):
 _MASKED = -1e30  # a finite "minus infinity": a fully masked tile stays NaN-free
 
 
-def _attention_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref, *, scale):
+def _accumulate(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale, keep):
+    """One (chunk x chunk) tile into the online softmax of every query
+    head of the block; ``keep`` (chunk, chunk) bool, or ``None``: every key
+    of the tile."""
+    k, v = k_ref[...], v_ref[...]
+    for r in range(q_ref.shape[0]):
+        logits = jax.lax.dot_general(
+            q_ref[r], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale
+        if keep is not None:
+            logits = jnp.where(keep, logits, _MASKED)
+        m_prev = m_ref[r]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.exp(logits - m_new)
+        if keep is not None:
+            p = jnp.where(keep, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[r] = alpha * l_ref[r] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[r] = alpha * acc_ref[r] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        m_ref[r] = m_new
+
+
+def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale):
     """Grid (key-value head, query chunk i, key chunk j), j innermost.
     ``q_ref``/``o_ref``: the head's R query heads, (R, chunk, d);
-    ``k_ref``/``v_ref``: (chunk, d); ``mask_ref``: (chunk, chunk) int8,
-    shared by every head. Online softmax in the scratch: running max
-    ``m``, sum ``l`` (R, chunk, 1) and unnormalised output ``acc``."""
+    ``k_ref``/``v_ref``: (chunk, d); then, under a mask, ``mask_ref``:
+    (chunk, chunk) int8, shared by every head. Online softmax in the
+    scratch: running max ``m``, sum ``l`` (R, chunk, 1) and unnormalised
+    output ``acc``. Without a mask a tile below the diagonal keeps every
+    key and the diagonal tile the keys at or before each query, by the
+    tile's own row and column indices."""
+    mask_ref, (o_ref, m_ref, l_ref, acc_ref) = (refs[0], refs[1:]) if len(refs) == 5 else (None, refs)
     i, j = pl.program_id(1), pl.program_id(2)
+    accumulate = functools.partial(_accumulate, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale)
 
     @pl.when(j == 0)
     def _start():
@@ -114,48 +144,53 @@ def _attention_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_re
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(j <= i)  # a chunk sees the chunks up to its own; the mask is causal inside
-    def _tile():
-        keep = mask_ref[...] != 0
-        k, v = k_ref[...], v_ref[...]
-        for r in range(q_ref.shape[0]):
-            logits = jax.lax.dot_general(
-                q_ref[r], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale
-            logits = jnp.where(keep, logits, _MASKED)
-            m_prev = m_ref[r]
-            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-            p = jnp.where(keep, jnp.exp(logits - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[r] = alpha * l_ref[r] + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[r] = alpha * acc_ref[r] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32
-            )
-            m_ref[r] = m_new
+    if mask_ref is not None:
+
+        @pl.when(j <= i)  # a chunk sees the chunks up to its own; the mask is causal inside
+        def _tile():
+            accumulate(mask_ref[...] != 0)
+
+    else:
+
+        @pl.when(j < i)
+        def _below():
+            accumulate(None)
+
+        @pl.when(j == i)
+        def _diagonal():
+            shape = (q_ref.shape[1], k_ref.shape[0])
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            accumulate(col <= row)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         o_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
-def masked_attention(q, k, v, mask, chunk: int, interpret: bool = False):
+def masked_attention(q, k, v, mask: Optional[jnp.ndarray], chunk: int, interpret: bool = False):
     """softmax(q . k / sqrt(d), over the keys ``mask`` keeps) . v,
     grouped-query. ``q`` (G, R, T, d), ``k``/``v`` (G, T, d), bfloat16;
-    ``mask`` (T, T) int8, causal, at least one key kept a row; T a
-    multiple of ``chunk``. Returns (G, R, T, d) float32."""
+    ``mask`` (T, T) int8, causal, at least one key kept a row, or ``None``:
+    every causal key, and no mask is read; T a multiple of ``chunk``.
+    Returns (G, R, T, d) float32."""
     G, R, T, d = q.shape
     n = T // chunk
     seen = lambda i, j: jnp.minimum(i, j)  # a tile above the diagonal is not fetched again
+    in_specs = [
+        pl.BlockSpec((None, R, chunk, d), lambda g, i, j: (g, 0, i, 0)),
+        pl.BlockSpec((None, chunk, d), lambda g, i, j: (g, seen(i, j), 0)),
+        pl.BlockSpec((None, chunk, d), lambda g, i, j: (g, seen(i, j), 0)),
+    ]
+    operands = (q, k, v)
+    if mask is not None:
+        in_specs.append(pl.BlockSpec((chunk, chunk), lambda g, i, j: (i, seen(i, j))))
+        operands += (mask,)
     return pl.pallas_call(
         functools.partial(_attention_kernel, scale=1.0 / d ** 0.5),
         out_shape=jax.ShapeDtypeStruct((G, R, T, d), jnp.float32),
         grid=(G, n, n),
-        in_specs=[
-            pl.BlockSpec((None, R, chunk, d), lambda g, i, j: (g, 0, i, 0)),
-            pl.BlockSpec((None, chunk, d), lambda g, i, j: (g, seen(i, j), 0)),
-            pl.BlockSpec((None, chunk, d), lambda g, i, j: (g, seen(i, j), 0)),
-            pl.BlockSpec((chunk, chunk), lambda g, i, j: (i, seen(i, j))),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((None, R, chunk, d), lambda g, i, j: (g, 0, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((R, chunk, 1), jnp.float32),
@@ -167,7 +202,7 @@ def masked_attention(q, k, v, mask, chunk: int, interpret: bool = False):
         ),
         interpret=interpret,
         name="masked_attention",
-    )(q, k, v, mask)
+    )(*operands)
 
 
 def witness_of(selection: jnp.ndarray, stride: int) -> jnp.ndarray:

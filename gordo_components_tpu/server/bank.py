@@ -836,6 +836,8 @@ _SHARED_COUNTERS = {
     "held_pairs": "Those of them that fell on an expert held here",
     "held_tokens_busiest": "Pairs routed to each such layer's busiest held expert",
     "held_pair_blocks": "Blocks of held pairs such layers worked through, padding's pairs among them",
+    "ssm_layers": "State-space mixer layers the dispatches ran",
+    "ssm_chunks": "Chunks of valid rows those layers scanned, summed over the layers",
 }
 
 
@@ -2118,7 +2120,8 @@ class ModelBank:
         with this dispatch (executor thread, one dispatch at a time). Each
         array counts valid rows only and is stacked over the layers that
         observed it: a dense layer routes nothing, a layer without an
-        indexer selects no keys (it may attend under another's)."""
+        indexer selects no keys (it may attend under another's), only a
+        state-space mixer scans chunks."""
         counted = [("dispatches", 1), ("rows", run.routed_rows), ("tokens", run.total_rows)]
         if "expert_tokens" in observed:  # (layers, experts): every expert is held
             tokens = observed["expert_tokens"]
@@ -2135,6 +2138,9 @@ class ModelBank:
                         ("held_pairs", int(held.sum())),
                         ("held_tokens_busiest", int(held.max(axis=-1).sum())),
                         ("held_pair_blocks", int(observed["held_blocks"].sum()))]
+        if "ssm_chunks" in observed:  # (mixer layers, batch): chunks of each request's valid rows
+            counted += [("ssm_layers", observed["ssm_chunks"].shape[0]),
+                        ("ssm_chunks", int(observed["ssm_chunks"].sum()))]
         stats = self.shared_stats
         for name, value in counted:
             stats[name] = stats.get(name, 0) + value

@@ -496,6 +496,49 @@ def test_selected_latent_trunk_layer_programs_compile_at_published_widths(
     assert gigabytes[0] * 1e9 < weights < gigabytes[1] * 1e9
 
 
+@pytest.mark.parametrize("kind,layer_index,B,kernels,gigabytes", [
+    ("M", 0, 1, 1, (0.077, 0.078)), ("M", 0, 2, 1, (0.077, 0.078)), ("E", 1, 1, 2, (1.317, 1.319)),
+    ("*", 5, 1, 1, (0.046, 0.047)),
+])
+def test_hybrid_trunk_layer_programs_compile_at_published_widths(
+        v5e, kind, layer_index, B, kernels, gigabytes):
+    """The three kinds of layer of the hybrid trunk (``nemotron3_trunk300``:
+    a Mamba-2 mixer of 64 heads of 64 over 8 groups of 128, scanned in
+    chunks of 128; 64 of 128 squared-ReLU experts of 1856 beside a shared
+    one of 3712, top 6; attention of 32/2 heads of 128 over every causal
+    key) over B week-long requests of 10 240 padded rows, for one chip: the
+    scan, the two grouped matmuls and the mask-free attention are Pallas
+    kernels, and what each program needs beside its arguments stays under
+    the count the bank bounds its batch by, the mixer's (the widest)
+    within twice the compiler's analysis."""
+    from gordo_components_tpu.models.factories.trunk import HybridMoEDecoder
+
+    module = HybridMoEDecoder(n_features=300, num_hidden_layers=9, experts_held=64)
+    home = SingleDeviceSharding(v5e[0])
+    T = module.padded_rows(10080)
+    assert (T, module.kind(layer_index)) == (10240, kind)
+    layer = {
+        name: jax.ShapeDtypeStruct(
+            shape, jnp.float32 if len(shape) == 1 else jnp.bfloat16, sharding=home
+        )
+        for name, shape in module.layer_shapes(layer_index).items()
+    }
+    x = jax.ShapeDtypeStruct((B, T, module.hidden_size), jnp.float32, sharding=home)
+    n_valid = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=home)
+    compiled = jax.jit(
+        lambda w, x, n: module.layer(w, x, n, None, interpret=False)
+    ).lower(layer, x, n_valid).compile()
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"{kind} layer, {B} request(s): temp {temp / 1e9:.2f} GB, "
+          f"the bank's count {module.program_bytes(B, T) / 1e9:.2f} GB")
+    assert temp <= module.program_bytes(B, T), (temp, module.program_bytes(B, T))
+    if kind == "M":
+        assert module.program_bytes(B, T) <= 2 * temp
+    weights = sum(np.prod(s.shape) * s.dtype.itemsize for s in layer.values())
+    assert gigabytes[0] * 1e9 < weights < gigabytes[1] * 1e9
+
+
 # --------------------------------------------------------------------- #
 # the dense gang's training step (ops/dense_step.py) in its epoch program
 # --------------------------------------------------------------------- #
