@@ -52,6 +52,19 @@ of exact zeros, every pair held is ``pairs / block`` trips and about the
 one pass's work. A token's up-to-``top_k`` float32 additions happen in
 expert order there, in slot order in the one pass.
 
+Nothing is laid out again on the way, at any width. A stack whose last
+dimension is not a multiple of 128 and whose second-to-last is (the up
+stack (held, 2688, 1856) of a hidden width of 2688) lies in HBM with that
+second-to-last dimension on the lanes, the TPU's default for such a shape:
+the grouped matmul reads it swapped, as (held, n, k) with the kernel's
+``transpose_rhs``, which is that layout (read as it is shaped, it would be
+copied whole every call). The held range's output is added into
+(tokens, 8 * ceil(D / 1024), 128), whole (8, 128) tiles a token: a D that
+is not a multiple of 1024 is padded with zero columns, which the tiles
+hold anyway, and sliced back once after the loop (unpadded, 21 rows of
+128 at 2688, the block and the output would be relaid out twice a trip
+and twice a call). Every other shape is read and added as it is.
+
 Matmuls take bfloat16 operands and accumulate in float32; the router's
 logits (float32 operands at full precision), its scores, the group and
 expert top-k and the combine are float32.
@@ -107,10 +120,14 @@ def _grouped(lhs, rhs, group_sizes, interpret):
     m, k = lhs.shape
     n = rhs.shape[-1]
     rows = next(t for t in (_TILE_ROWS, 256, 128, 64, 32, 16, 8) if m % t == 0)
+    # a stack whose columns are not whole lanes and whose rows are lies in HBM with its rows
+    # on the lanes: read it as (experts, n, k), which is that layout, and not a copy of it
+    swapped = n % 128 != 0 and k % 128 == 0
     return gmm(
-        lhs, rhs, group_sizes, preferred_element_type=jnp.float32,
+        lhs, jnp.swapaxes(rhs, 1, 2) if swapped else rhs, group_sizes,
+        preferred_element_type=jnp.float32,
         tiling=(rows, min(k, _TILE_K), min(n, _TILE_N)),
-        interpret=interpret,
+        transpose_rhs=swapped, interpret=interpret,
     )
 
 
@@ -189,11 +206,13 @@ def _held_pairs_in_blocks(h, params, top_k, weights, order, sizes, interpret):
         h = h.astype(jnp.bfloat16)
         weights = weights.reshape(-1)
     with jax.named_scope("trunk/combine"):
-        # the output as (tokens, D / 128, 128): a token's row is whole (8, 128) tiles, contiguous, and
-        # a trip's scatter-add of 2048 rows of 7168 takes 1.8 ms less on a v5e than into (tokens, D)
+        # the output as (tokens, 8 * ceil(D / 1024), 128): a token's row is whole (8, 128) tiles,
+        # contiguous, and a trip's scatter-add of 2048 rows of 7168 takes 1.8 ms less on a v5e than
+        # into (tokens, D). A D that is not a multiple of 1024 is padded with zero columns, which
+        # the tiles hold anyway (at 21 rows of 128, unpadded, the block is relaid out twice a trip)
         wide = h.shape[1]
-        lanes = 128 if wide % 128 == 0 else wide
-        zeros = jnp.zeros((n_tokens, wide // lanes, lanes), jnp.float32)
+        padded = -(-wide // 1024) * 1024
+        zeros = jnp.zeros((n_tokens, padded // 128, 128), jnp.float32)
 
     def trip(b, out):
         first = b * block
@@ -206,10 +225,12 @@ def _held_pairs_in_blocks(h, params, top_k, weights, order, sizes, interpret):
             y = _experts(x, params, here, interpret)
         with jax.named_scope("trunk/combine"):
             live = first + jnp.arange(block) < n_held  # a row past the held pairs was never written
+            if padded != wide:  # before the weighing, which the pad then joins in one fusion
+                y = jnp.pad(y, ((0, 0), (0, padded - wide)))
             y = jnp.where(live[:, None], y * weights[at][:, None], 0.0)
             return out.at[tokens].add(y.reshape(block, *out.shape[1:]))
 
     blocks = (n_held + block - 1) // block
     out = jax.lax.fori_loop(0, blocks, trip, zeros)
     with jax.named_scope("trunk/combine"):
-        return out.reshape(n_tokens, wide), blocks
+        return out.reshape(n_tokens, padded)[:, :wide], blocks

@@ -558,14 +558,20 @@ async def test_watchman_slo_rollup_multi_replica(artifact_dir, monkeypatch):
                 "/gordo/v0/proj/gp-a/prediction", json=_x_payload()
             )
             assert resp.status == 200
+        # real 5xx pressure via tight deadlines + an engine.queue latency
+        # fault: the fault makes the 1 ms budgets expire however fast the
+        # host serves, so the burn does not hang on a scheduling race
+        resilience.arm("engine.queue", delay_s=0.05, exc=None)
+        statuses = []
         for i in range(6):
-            # hit a missing model: 404s are wasted (not availability
-            # errors); add real 5xx pressure via tight deadlines + fault
             resp = await burning.post(
                 "/gordo/v0/proj/gp-a/prediction",
                 json=_x_payload(),
                 headers={"X-Gordo-Deadline-Ms": "1"} if i % 2 == 0 else {},
             )
+            statuses.append(resp.status)
+        resilience.reset()
+        assert statuses.count(504) >= 3, statuses  # tight budgets expired
         await clean.get("/gordo/v0/proj/slo?refresh=1")
         await burning.get("/gordo/v0/proj/slo?refresh=1")
 

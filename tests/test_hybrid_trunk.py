@@ -186,13 +186,19 @@ def _by_loop(h, params, top_k, offset=0, gated=False):
     return out, experts
 
 
-@pytest.mark.parametrize("held,offset", [(8, 0), (4, 4), (2, 2)])
-def test_squared_relu_experts_are_a_plain_loop_over_the_experts(held, offset):
+@pytest.mark.parametrize("held,offset,n_experts,D,I", [
+    (8, 0, 8, 32, 24), (4, 4, 8, 32, 24), (2, 2, 8, 32, 24),
+    # D / 128 = 3 rows of 128, not whole (8, 128) tiles, and an I that is not whole lanes: the up
+    # stack is read swapped and the combine adds into padded rows; D 1024 takes neither
+    (16, 16, 32, 384, 192), (16, 8, 32, 1024, 256),
+], ids=["8-0", "4-4", "2-2", "16-16-D384-I192", "16-8-D1024-I256"])
+def test_squared_relu_experts_are_a_plain_loop_over_the_experts(held, offset, n_experts, D, I):
     """No ``gate`` among the leaves: every held expert is
     ``down(relu(up h)^2)``, in one pass where every expert is held and in
-    blocks of held pairs where a range is."""
-    h = jax.random.normal(jax.random.PRNGKey(7), (64, 32))
-    whole = _expert_params()
+    blocks of held pairs where a range is, at widths the combine pads and
+    at widths it does not."""
+    h = jax.random.normal(jax.random.PRNGKey(7), (64, D))
+    whole = _expert_params(n_experts, n_experts, D, I)
     params = {**whole, "up": whole["up"][offset:offset + held], "down": whole["down"][offset:offset + held]}
     valid = jnp.ones((64,), bool)
     with jax.default_matmul_precision("highest"):
@@ -201,7 +207,7 @@ def test_squared_relu_experts_are_a_plain_loop_over_the_experts(held, offset):
     np.testing.assert_array_equal(experts, want_experts)
     np.testing.assert_allclose(out, want, rtol=2e-2, atol=2e-2 * float(jnp.abs(want).max()))
     assert int(tokens.sum()) == int(((want_experts >= offset) & (want_experts < offset + held)).sum())
-    assert (int(blocks) == 0) == (held == 8)
+    assert (int(blocks) == 0) == (held == n_experts)
 
 
 def test_the_gated_experts_are_the_expressions_they_were():

@@ -348,6 +348,30 @@ def test_bucket_program_reads_only_the_members_it_scores(v5e, monkeypatch, bank,
 # a bucket with shared leaves: the trunk's layer program
 # --------------------------------------------------------------------- #
 
+_ITEM_BYTES = {"f32": 4, "s32": 4, "bf16": 2, "s8": 1, "u8": 1, "pred": 1}
+_COPY = re.compile(r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* copy\(%([\w.]+)\)")
+
+
+def _copies(text, floor=16e6):
+    """``(result, operand)`` of every ``copy`` in a compiled program, the
+    fusions' bodies included, that writes ``floor`` bytes or more: what
+    layout assignment moves whole, doing no arithmetic."""
+    found = []
+    for line in text.splitlines():
+        m = _COPY.match(line)
+        if m and np.prod([int(d) for d in m.group(2).split(",") if d]) * _ITEM_BYTES[m.group(1)] >= floor:
+            found.append((f"{m.group(1)}[{m.group(2)}]", m.group(3)))
+    return found
+
+
+def _routed_copies(text):
+    """A routed layer's large copies, split: those of an expert stack
+    entering the program (the ``w['up']``, ``w['gate']``, ``w['down']``
+    parameters), and the float32 ones (the combine's block and output)."""
+    copies = _copies(text)
+    stacks = [c for c in copies if re.match(r"w__(up|gate|down)__", c[1])]
+    return stacks, sorted(shape for shape, _ in copies if shape.startswith("f32"))
+
 
 @pytest.mark.parametrize("B", [1, 2])
 def test_trunk_layer_program_compiles_at_published_widths(v5e, B):
@@ -417,6 +441,10 @@ def test_latent_trunk_layer_programs_compile_at_published_widths(
         lambda w, x, n: module.layer(w, x, n, None, interpret=False)
     ).lower(layer, x, n_valid).compile()
     assert compiled.as_text().count("tpu_custom_call") == kernels
+    if kind == "routed":
+        # D 7168 is 56 rows of 128, whole (8, 128) tiles: the combine adds into (tokens, 56, 128)
+        # unpadded, and its one relayout is a trip's block into a token's tiles; no stack is copied
+        assert _routed_copies(compiled.as_text()) == ([], ["f32[256,8,56,128]"])
     temp = compiled.memory_analysis().temp_size_in_bytes
     before = {("dense", 1): 1.17, ("routed", 1): 2.07, ("routed", 2): 2.52}[kind, B]
     print(f"{kind} layer, {B} request(s): temp {temp / 1e9:.2f} GB ({before} GB before the held pairs "
@@ -481,6 +509,8 @@ def test_selected_latent_trunk_layer_programs_compile_at_published_widths(
         lambda w, x, n, s: module.layer(w, x, n, s, interpret=False)
     ).lower(layer, x, n_valid, selection).compile()
     assert compiled.as_text().count("tpu_custom_call") == kernels
+    if "routed" in kind:  # D 6144, 48 rows of 128: as the other latent trunk's
+        assert _routed_copies(compiled.as_text()) == ([], ["f32[256,8,48,128]"])
     memory = compiled.memory_analysis()
     temp = memory.temp_size_in_bytes
     before = {"dense+full": 1.96, "routed+shared": 1.85, "routed+full": 2.08}[kind]
@@ -510,7 +540,16 @@ def test_hybrid_trunk_layer_programs_compile_at_published_widths(
     scan, the two grouped matmuls and the mask-free attention are Pallas
     kernels, and what each program needs beside its arguments stays under
     the count the bank bounds its batch by, the mixer's (the widest)
-    within twice the compiler's analysis."""
+    within twice the compiler's analysis.
+
+    The routed layer relays out no expert stack and nothing at its width
+    of 2688 = 21 rows of 128, which is not whole (8, 128) tiles: the up
+    stack (64, 2688, 1856), whose 1856 columns are not whole lanes, lies
+    in HBM with its rows on the lanes and is read so (it was copied whole
+    every call, 638 MB), and the combine adds into (tokens, 24, 128) (its
+    block and its output were relaid out twice a trip and twice a call at
+    21). What is left is what the other trunks' combine has: a trip's
+    block into a token's tiles, and the output into the residual's."""
     from gordo_components_tpu.models.factories.trunk import HybridMoEDecoder
 
     module = HybridMoEDecoder(n_features=300, num_hidden_layers=9, experts_held=64)
@@ -535,6 +574,9 @@ def test_hybrid_trunk_layer_programs_compile_at_published_widths(
     assert temp <= module.program_bytes(B, T), (temp, module.program_bytes(B, T))
     if kind == "M":
         assert module.program_bytes(B, T) <= 2 * temp
+    if kind == "E":
+        assert _routed_copies(compiled.as_text()) == ([], ["f32[1280,8,24,128]", "f32[256,8,24,128]"])
+        assert temp < 0.4e9  # 0.83 GB while the up stack was copied, the copy among the temporaries
     weights = sum(np.prod(s.shape) * s.dtype.itemsize for s in layer.values())
     assert gigabytes[0] * 1e9 < weights < gigabytes[1] * 1e9
 
